@@ -4,13 +4,10 @@ Three sections, all emitted into one JSON report (``--out``):
 
 1. **Kernel sweep**: the paged mixed-attention kernel timed directly on
    synthetic pools, ``pr8`` launch shape (unpadded pool, one page DMA
-   per grid step, one-hot codec dequant) vs ``tuned`` (pool rows padded
-   to the 8-sublane tile, ``pages_per_step >= 2`` so the next grid
-   step's page DMAs overlap this step's compute, gathered codebook
-   lookup).  Swept over page size x decode/mixed Q x codec.  Outputs
-   are asserted numerically equivalent between variants (bit-identical
-   when only the layout padding differs; allclose when the page-group
-   size regroups the online softmax).
+   per grid step) vs ``tuned`` (pool rows padded to the 8-sublane tile,
+   ``pages_per_step >= 2`` so the next grid step's page DMAs overlap
+   this step's compute).  Swept over page size x decode/mixed Q x codec.
+   Outputs are asserted numerically equivalent between variants.
 
 2. **Autotune**: ``runtime.autotune.tune_kernel`` sweeping
    ``(q_block, pages_per_step)`` on a reduced minitron-8b geometry —
@@ -20,15 +17,12 @@ Three sections, all emitted into one JSON report (``--out``):
 3. **Serve identity**: the same request mix served end-to-end under
    the gathered oracle, the PR-8 kernel launch (``kernel_tune="off"``)
    and the tuned launch (``kernel_tune="0,2"``), under both KV codecs.
-   Tokens must be identical within each codec — the tiling padding,
-   multi-page DMAs and gather dequant are layout/engine changes, not
-   numerics changes.
+   Tokens must be identical within each codec — the tiling padding and
+   multi-page DMAs are layout changes, not numerics changes.
 
-On hosts without a TPU the kernel runs through the Pallas interpreter
-(same convention as the test suite): timings then compare the work each
-launch shape *performs*, not TPU-compiled speed — the one-hot dequant's
-O(page x 256) expansion and the per-grid-step overhead are both real in
-either mode.
+On the CPU the kernel runs through the Pallas interpreter
+(``repro.kernels.platform.interpret_mode``): timings are then CPU
+interpreter figures, not TPU-compiled speed.
 
 Run:  PYTHONPATH=src python benchmarks/kernel_bench.py
       PYTHONPATH=src python benchmarks/kernel_bench.py --smoke \
@@ -63,7 +57,7 @@ def make_case(rng, *, n_slots, pages_per_slot, page, kh, d, dv, q, h,
     pools hold int8 codes + per-(page, token) f32 scales, zero-padded
     rows decoding to exactly 0 by the codebook's ZERO_CODE convention.
     """
-    from repro.kernels.kv_codec import MAX_CODE, codebook
+    from repro.kernels.kv_codec import MAX_CODE
 
     rows = _round_up(page, TILE_SUBLANE) if padded else page
     n_pages = n_slots * pages_per_slot + 1          # page 0 = dummy
@@ -85,7 +79,6 @@ def make_case(rng, *, n_slots, pages_per_slot, page, kh, d, dv, q, h,
     if not codec:
         case.update(k_pages=pool(d), v_pages=pool(dv))
         return case
-    cb = np.asarray(codebook())
 
     def codes():
         out = np.zeros((n_pages, rows, kh, d), np.int8)
@@ -99,11 +92,11 @@ def make_case(rng, *, n_slots, pages_per_slot, page, kh, d, dv, q, h,
         return out
 
     case.update(k_pages=codes(), v_pages=codes(), k_scales=scales(),
-                v_scales=scales(), codebook=cb)
+                v_scales=scales())
     return case
 
 
-def run_case(case, *, pps, dequant, q_block, interpret):
+def run_case(case, *, pps, q_block, interpret):
     import jax
 
     from repro.kernels.paged_attention import paged_mixed_attention
@@ -112,8 +105,7 @@ def run_case(case, *, pps, dequant, q_block, interpret):
         case["q"], case["k_pages"], case["v_pages"], case["table"],
         case["lengths"], case["q_lens"],
         k_scales=case.get("k_scales"), v_scales=case.get("v_scales"),
-        codebook=case.get("codebook"), page_size=case["page_size"],
-        pages_per_step=pps, dequant=dequant, q_block=q_block,
+        page_size=case["page_size"], pages_per_step=pps, q_block=q_block,
         interpret=interpret))
 
 
@@ -132,19 +124,18 @@ def bench(fn, repeats):
 # ---------------------------------------------------------------------------
 
 VARIANTS = {
-    # PR-8 launch: physical page per grid step, no layout padding,
-    # one-hot codec dequant (codes x (page, LEVELS) masked matmul)
-    "pr8": dict(padded=False, pps=1, dequant="onehot"),
+    # PR-8 launch: physical page per grid step, no layout padding
+    "pr8": dict(padded=False, pps=1),
     # hardware-shaped launch: sublane-padded pool rows, two page DMAs
-    # per grid step (double buffering), gathered codebook lookup
-    "tuned": dict(padded=True, pps=2, dequant="gather"),
+    # per grid step (double buffering)
+    "tuned": dict(padded=True, pps=2),
 }
 
 
 def kernel_sweep(smoke: bool, seed: int, repeats: int) -> list:
-    import jax
+    from repro.kernels.platform import interpret_mode
 
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
     pages = (8,) if smoke else (4, 8, 16)
     qs = (1,) if smoke else (1, 8)
     pages_per_slot = 4 if smoke else 8
@@ -169,14 +160,12 @@ def kernel_sweep(smoke: bool, seed: int, repeats: int) -> list:
                         rng, n_slots=4, pages_per_slot=pages_per_slot,
                         page=page, kh=kh, d=d, dv=dv, q=q, h=h,
                         codec=codec, padded=v["padded"])
-                    kw = dict(pps=v["pps"], dequant=v["dequant"],
-                              q_block=0, interpret=interpret)
+                    kw = dict(pps=v["pps"], q_block=0, interpret=interpret)
                     outs[label] = run_case(case, **kw)
                     row[f"{label}_ms"] = bench(
                         lambda case=case, kw=kw: run_case(case, **kw),
                         repeats)
-                # layout + dequant changes must not change the math;
-                # pps regroups the online softmax, hence allclose
+                # layout changes must not change the math
                 np.testing.assert_allclose(
                     outs["tuned"], outs["pr8"], rtol=2e-6, atol=2e-6)
                 row["speedup"] = row["pr8_ms"] / row["tuned_ms"]
@@ -192,15 +181,14 @@ def kernel_sweep(smoke: bool, seed: int, repeats: int) -> list:
 # ---------------------------------------------------------------------------
 
 def autotune_report(smoke: bool) -> dict:
-    import jax
-
     from repro.configs.base import get_config
+    from repro.kernels.platform import interpret_mode
     from repro.runtime.autotune import tune_kernel
 
     cfg = get_config("minitron-8b").scaled(
         dtype="float32", vocab_size=128, num_layers=2, scan_repeats=2,
         d_model=64, num_heads=4, num_kv_heads=2, head_dim=16, d_ff=128)
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
     picks = {}
     for q in (1,) if smoke else (1, 8):
         r = tune_kernel(cfg, 8, q, codec=True, interpret=interpret,
@@ -281,6 +269,8 @@ def main():
     ap.add_argument("--out", type=str, default=None,
                     help="write the JSON report (e.g. BENCH_kernels.json)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     repeats = args.repeats or (2 if args.smoke else 5)
 
     rows = kernel_sweep(args.smoke, args.seed, repeats)

@@ -13,6 +13,9 @@ import time
 def main() -> None:
     from benchmarks import (ablation_nodes, compression_table, freq_table,
                             roofline, speedup)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     sections = {
         "freq_table": freq_table.run,            # paper Table II / Fig 3
@@ -23,6 +26,7 @@ def main() -> None:
         "roofline_multi": lambda: roofline.run("multi"),
     }
     want = sys.argv[1:] or list(sections)
+    failed = []
     for name in want:
         t0 = time.monotonic()
         print(f"\n== {name} ==")
@@ -30,8 +34,12 @@ def main() -> None:
             for row in sections[name]():
                 print(row)
         except Exception as e:  # noqa: BLE001
+            # report the section and keep going; the exit code says so
             print(f"# FAILED: {type(e).__name__}: {e}")
+            failed.append(name)
         print(f"# ({time.monotonic() - t0:.1f}s)")
+    if failed:
+        sys.exit(f"failed sections: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -1055,6 +1055,8 @@ def main():
                          "multipliers) as one JSON report — the checked-in "
                          "BENCH_serve.json is generated this way")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.autotune:
         if args.trace_file:

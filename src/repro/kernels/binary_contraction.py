@@ -75,7 +75,7 @@ def binary_contraction(
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x_words, w_words)

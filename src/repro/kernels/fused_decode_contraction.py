@@ -50,7 +50,7 @@ def _kernel(words_ref, x_ref, tables_ref, out_ref, acc_ref, w_scratch,
 
     def body(ci, bitpos):
         val, bitpos = decode_step(words, bitpos, tables, gather)
-        pl.store(w_scratch, (pl.dslice(ci, 1), slice(None)), val[None, :])
+        w_scratch[pl.ds(ci, 1), :] = val[None, :]
         return bitpos
 
     jax.lax.fori_loop(0, codes, body, jnp.zeros(SUB, jnp.int32))
@@ -122,7 +122,7 @@ def fused_decode_matmul(
             pltpu.VMEM((bm, bn), jnp.int32),
             pltpu.VMEM((codes, SUB), jnp.int32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(words, x_words, tables)

@@ -101,8 +101,7 @@ def _kernel(words_ref, tables_ref, out_ref, *, c: int, gather: str):
 
     def body(ci, bitpos):
         val, bitpos = decode_step(words, bitpos, tables, gather)
-        pl.store(out_ref, (pl.dslice(0, 1), pl.dslice(ci, 1), slice(None)),
-                 val[None, None, :])
+        out_ref[pl.ds(0, 1), pl.ds(ci, 1), :] = val[None, None, :]
         return bitpos
 
     jax.lax.fori_loop(0, c, body, jnp.zeros(words.shape[1], jnp.int32))

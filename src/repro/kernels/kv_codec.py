@@ -54,7 +54,15 @@ def codebook() -> jnp.ndarray:
     ``code`` in ``[-MAX_CODE, MAX_CODE]``; entry ``ZERO_CODE`` is 0.0,
     so zero codes decode to zero regardless of scale.
     """
-    return (jnp.arange(LEVELS, dtype=jnp.float32) - ZERO_CODE) / MAX_CODE
+    return jnp.asarray(_CODEBOOK)
+
+
+# computed once in numpy: under jit, XLA rewrites a division by the
+# constant MAX_CODE into a multiplication by its rounded reciprocal, which
+# moves 16 of the 256 entries by one ulp -- the table must not depend on
+# where it is built
+_CODEBOOK = (np.arange(LEVELS, dtype=np.float32) - ZERO_CODE) \
+    / np.float32(MAX_CODE)
 
 
 def encode(values, axes):

@@ -1,7 +1,8 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Every op has a pure-jnp oracle in :mod:`repro.kernels.ref`; the wrappers
-auto-select interpret mode off-TPU so the same call sites run everywhere.
+take interpret mode from :func:`repro.kernels.platform.interpret_mode`
+(interpreted on CPU, compiled on TPU) so the same call sites run on both.
 """
 
 from __future__ import annotations
@@ -18,10 +19,11 @@ from repro.kernels.binarize_pack import binarize_pack as binarize_pack_kernel
 from repro.kernels.binary_contraction import binary_contraction
 from repro.kernels.fused_decode_contraction import fused_decode_matmul
 from repro.kernels.huffman_decode import huffman_decode, pack_bitplane_tables
+from repro.kernels.platform import interpret_mode
 
 
 def _interpret(flag: bool | None) -> bool:
-    return jax.default_backend() != "tpu" if flag is None else flag
+    return interpret_mode() if flag is None else flag
 
 
 def binarize_pack(x: jax.Array, *, use_kernel: bool = False,

@@ -48,10 +48,17 @@ one BlockSpec per page, indexed ``table[s, j * c + i]``.  Pallas
 double-buffers every input BlockSpec across grid steps, so the ``c``
 page DMAs of step ``j + 1`` overlap the score/softmax compute of step
 ``j`` — the same async-copy overlap ``pltpu.make_async_copy`` expresses
-by hand, but driven by the pipeline.  Feature dims padded toward the
-(8, 128) sublane/lane tiles by ``SlotPool`` cost nothing here: zero
-key/value columns contribute exactly ``0.0`` to every f32 dot product,
-and padded page rows score ``NEG_INF`` and vanish in the exp.
+by hand, but driven by the pipeline.  The pages of a group are folded
+into the online softmax one after another, so ``c`` changes the DMA
+shape and not the arithmetic.  Feature dims padded toward the (8, 128)
+sublane/lane tiles by ``SlotPool`` are never read: the kernel slices
+each page to its logical rows and the query's width.
+
+Mosaic shape rules: the body uses only 2-D operations.  Each query head
+and each KV head is read out of its block by a strided load, scored as
+one (qb, D) x (D, page) product, and accumulated into per-head VMEM
+scratch; no value is reshaped across the sublane axis, which the TPU
+compiler refuses (``infer-vector-layout: unsupported shape cast``).
 
 The optional second score operand ``(q2, k2_pages)`` serves MLA absorbed
 decode: scores are ``q . k + q2 . k2`` (latent + rope parts) over a
@@ -62,11 +69,12 @@ operation order exactly.
 
 Compressed pages (``kv_codec="cluster"``): when ``k_scales``/``v_scales``
 are passed the pools hold int8 codebook indices and each page is decoded
-*in VMEM* right after its DMA — a 256-entry codebook gather times the
-per-(slot, token) scale row that rides its own scalar-prefetch
-BlockSpec — before the online-softmax score ever sees it.  The fp page
-never exists in HBM.  ``dequant="onehot"`` keeps the previous
-one-hot-matmul lookup as a bit-identity reference.
+*in VMEM* right after its DMA — a codebook lookup, done as one
+128-lane register gather per lane tile over the codebook's non-negative
+half (Mosaic lowers no wider gather), times the per-(slot, token) scale
+column that rides its own page-table-walked BlockSpec — before the
+online-softmax score ever sees it.  The fp page
+never exists in HBM.
 
 ``interpret=True`` runs the identical kernel through the Pallas
 interpreter on CPU — how CI exercises it (same convention as
@@ -83,7 +91,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.kv_codec import LEVELS, ZERO_CODE
+from repro.kernels import kv_codec
 
 NEG_INF = -1e30
 
@@ -98,39 +106,64 @@ def effective_q_block(qn: int, q_block: int) -> int:
     return math.gcd(qn, q_block) if q_block else qn
 
 
-def _dequant(codes, scale_row, cb, mode: str):
-    """Decode one int8 page in VMEM: codebook lookup * per-token scale.
+def _page_operand(ref, scale_ref, half, kv_head: int, rows: int,
+                  width: int):
+    """One KV head of one page as an f32 (rows, width) operand.
 
-    ``codes`` (page, KH, D) int8, ``scale_row`` (page,) f32, ``cb``
-    (LEVELS,) f32.  ``mode="gather"`` is the direct 256-entry gather;
-    ``mode="onehot"`` keeps the previous one-hot compare against an iota
-    (O(page * LEVELS) select+sum) as a bit-identity reference — a
-    one-hot sum of a single selected centroid is the centroid itself,
-    bit for bit."""
-    if mode == "gather":
-        vals = cb[codes.astype(jnp.int32) + ZERO_CODE]
-    else:
-        flat = codes.reshape(-1, 1).astype(jnp.int32) + ZERO_CODE
-        sel = flat == jax.lax.broadcasted_iota(
-            jnp.int32, (flat.shape[0], LEVELS), 1)
-        vals = jnp.where(sel, cb[None, :], 0.0).sum(-1).reshape(codes.shape)
-    return vals * scale_row.reshape(-1, 1, 1)
+    ``ref`` is a page block (1, page, KH, D); indexing the KV head is a
+    strided sublane load, so no in-kernel reshape or transpose is needed.
+    Only the first ``rows`` rows and ``width`` feature columns are read:
+    the rest of a tile-padded pool is layout padding.
+    Under the codec the int8 codes are looked up in ``half`` -- the
+    codebook's non-negative half broadcast to (page, 128), one lane per
+    magnitude, so each 128-lane slice of the page is one in-register
+    lane gather (the only gather Mosaic lowers) -- negated for negative
+    codes, which is exact because the codebook is symmetric, and scaled
+    by the per-token scale column ``scale_ref`` (1, page, 1).  That is
+    ``kv_codec.decode`` bit for bit."""
+    x = ref[0, :rows, kv_head, :width]
+    if scale_ref is None:
+        return x.astype(jnp.float32)
+    codes = x.astype(jnp.int32)
+    mag = jnp.abs(codes)
+    lanes = half.shape[-1]
+    vals = jnp.concatenate([
+        jnp.take_along_axis(half, mag[:, lo:lo + lanes], axis=1,
+                            mode="promise_in_bounds")
+        for lo in range(0, mag.shape[-1], lanes)], axis=1)
+    return jnp.where(codes < 0, -vals, vals) * scale_ref[0, :rows]
+
+
+def _dot(a, b, contract_b: int):
+    """a (m, k) contracted with axis ``contract_b`` of the 2-D ``b``, in
+    f32 at full precision.  It is written as a batch-1 batched product:
+    on the CPU the interpreter then sums each output in the same order
+    whether an operand was loaded or computed (a decoded codec page), so
+    the codec kernel stays bit-identical to the fp kernel over the
+    decoded pool; Mosaic lowers it as the same single MXU product."""
+    return jax.lax.dot_general(
+        a[None], b[None], (((2,), (contract_b + 1,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)[0]
 
 
 def _kernel(table_ref, len_ref, qlen_ref, q_ref, *rest,
-            page: int, logical: int, c: int, kh: int, g: int, qb: int,
-            window: int, softcap_val: float, scale: float, has_q2: bool,
-            has_codec: bool, dequant: str):
+            logical: int, c: int, kh: int, g: int, qb: int, widths: tuple,
+            window: int, softcap_val: float, scale: float,
+            has_q2: bool, has_codec: bool):
+    wk, wv, w2 = widths
     i = 0
     k_refs = rest[i:i + c]
     i += c
     v_refs = rest[i:i + c]
     i += c
+    q2_ref, k2_refs = None, (None,) * c
     if has_q2:
         q2_ref = rest[i]
         i += 1
         k2_refs = rest[i:i + c]
         i += c
+    ks_refs = vs_refs = k2s_refs = (None,) * c
     if has_codec:
         ks_refs = rest[i:i + c]
         i += c
@@ -139,8 +172,10 @@ def _kernel(table_ref, len_ref, qlen_ref, q_ref, *rest,
         if has_q2:
             k2s_refs = rest[i:i + c]
             i += c
-        cb_ref = rest[i]
+        half = jnp.broadcast_to(rest[i][...], (logical, rest[i].shape[-1]))
         i += 1
+    else:
+        half = None
     o_ref, m_ref, l_ref, acc_ref = rest[i:]
     s_idx = pl.program_id(0)
     qb_idx = pl.program_id(1)
@@ -152,90 +187,59 @@ def _kernel(table_ref, len_ref, qlen_ref, q_ref, *rest,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # ---- decode this step's pages (in-kernel, codec path) ----------------
-    # each of the c page refs was DMA'd by its own BlockSpec; concatenating
-    # them gives one (c * page, KH, D) operand so the score einsum runs
-    # once over the whole group.
-    if has_codec:
-        cb = cb_ref[0]
-        k = jnp.concatenate([_dequant(r[0], s[0], cb, dequant)
-                             for r, s in zip(k_refs, ks_refs)])
-        v = jnp.concatenate([_dequant(r[0], s[0], cb, dequant)
-                             for r, s in zip(v_refs, vs_refs)])
-    else:
-        k = jnp.concatenate([r[0].astype(jnp.float32) for r in k_refs])
-        v = jnp.concatenate([r[0].astype(jnp.float32) for r in v_refs])
-
-    # ---- one page group of scores: (KH, G, qb, c * page) f32 -------------
-    q = q_ref[0].astype(jnp.float32).reshape(qb, kh, g, q_ref.shape[-1])
-    s = jnp.einsum("qkgd,pkd->kgqp", q, k)
-    if has_q2:
-        q2 = q2_ref[0].astype(jnp.float32).reshape(
-            qb, kh, g, q2_ref.shape[-1])
-        if has_codec:
-            k2 = jnp.concatenate([_dequant(r[0], sc[0], cb, dequant)
-                                  for r, sc in zip(k2_refs, k2s_refs)])
-        else:
-            k2 = jnp.concatenate([r[0].astype(jnp.float32)
-                                  for r in k2_refs])
-        s = s + jnp.einsum("qkgd,pkd->kgqp", q2, k2)
-    if scale != 1.0:
-        s = s * scale
-    if softcap_val:
-        s = jnp.tanh(s / softcap_val) * softcap_val
-
-    # ---- per-token causal/position mask ----------------------------------
     # query token i of this block sits at absolute position
     # lengths[s] - q_lens[s] + (qb_idx * qb + i); tokens past q_lens[s]
-    # are ragged padding and attend nothing.  Key row r of this group
-    # lives on logical page j * c + r // page at in-page row r % page —
-    # rows at or past the logical page length are layout padding.
+    # are ragged padding and attend nothing.  Key row r of page i of this
+    # group is logical position (j * c + i) * logical + r; rows at or
+    # past the logical page length are layout padding and never read.
     length = len_ref[s_idx]
     qlen = qlen_ref[s_idx]
-    qi = qb_idx * qb + jax.lax.broadcasted_iota(
-        jnp.int32, (1, 1, qb, 1), 2)
+    qi = qb_idx * qb + jax.lax.broadcasted_iota(jnp.int32, (qb, logical), 0)
     qpos = (length - qlen) + qi
-    r = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, c * page), 3)
-    row = r % page
-    gpos = (j * c + r // page) * logical + row
-    valid = (gpos <= qpos) & (qi < qlen) & (row < logical)
-    if window:
-        valid &= gpos > qpos - window
-    s = jnp.where(valid, s, NEG_INF)
+    row = jax.lax.broadcasted_iota(jnp.int32, (qb, logical), 1)
 
-    # ---- online softmax accumulation across page groups ------------------
-    m_prev = m_ref[...].reshape(kh, g, qb)
-    m_new = jnp.maximum(m_prev, s.max(-1))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[..., None])
-    l_ref[...] = (l_ref[...].reshape(kh, g, qb) * alpha
-                  + p.sum(-1)).reshape(kh, g * qb)
-    pv = jnp.einsum("kgqp,pkv->kgqv", p, v)
-    acc_ref[...] = acc_ref[...] * alpha.reshape(kh * g * qb, 1) \
-        + pv.reshape(kh * g * qb, -1)
-    m_ref[...] = m_new.reshape(kh, g * qb)
+    # Every query head is its own 2-D (qb, D) x (D, page) product against
+    # its KV head's page, and each page of the group is folded into the
+    # online softmax in turn -- so the result does not depend on how many
+    # pages one grid step carries.
+    for pi in range(c):
+        gpos = (j * c + pi) * logical + row
+        valid = (gpos <= qpos) & (qi < qlen)
+        if window:
+            valid &= gpos > qpos - window
+        for kv in range(kh):
+            k = _page_operand(k_refs[pi], ks_refs[pi], half, kv, logical, wk)
+            v = _page_operand(v_refs[pi], vs_refs[pi], half, kv, logical, wv)
+            k2 = _page_operand(k2_refs[pi], k2s_refs[pi], half, kv, logical,
+                               w2) if has_q2 else None
+            for h in range(kv * g, (kv + 1) * g):
+                s = _dot(q_ref[0, :, h, :].astype(jnp.float32), k, 1)
+                if has_q2:
+                    s = s + _dot(q2_ref[0, :, h, :].astype(jnp.float32),
+                                 k2, 1)
+                if scale != 1.0:
+                    s = s * scale
+                if softcap_val:
+                    s = jnp.tanh(s / softcap_val) * softcap_val
+                s = jnp.where(valid, s, NEG_INF)
+                m_prev = m_ref[h]                              # (qb, 1)
+                m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new)
+                l_ref[h] = l_ref[h] * alpha + p.sum(-1, keepdims=True)
+                acc_ref[h] = acc_ref[h] * alpha + _dot(p, v, 0)
+                m_ref[h] = m_new
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _done():
-        l = jnp.maximum(l_ref[...].reshape(kh, g, qb), 1e-20)
-        out = acc_ref[...].reshape(kh, g, qb, -1) / l[..., None]
-        o_ref[0] = jnp.moveaxis(out, 2, 0).reshape(o_ref.shape[1:])
-
-
-def _pad_last(x, width):
-    """Zero-pad x's last dim to ``width`` (no-op when already there).
-    Zero query columns meet zero key columns: the dot product is
-    bit-identical to the unpadded one (x + 0.0 == x in f32)."""
-    if x.shape[-1] == width:
-        return x
-    pad = [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])]
-    return jnp.pad(x, pad)
+        for h in range(kh * g):
+            o_ref[0, :, h, :] = acc_ref[h] / jnp.maximum(l_ref[h], 1e-20)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "softcap_val",
                                              "scale", "q_block",
                                              "page_size", "pages_per_step",
-                                             "dequant", "interpret"))
+                                             "interpret"))
 def paged_mixed_attention(
     q: jax.Array,            # (S, Q, H, D)  padded per-slot query blocks
     k_pages: jax.Array,      # (n_pages, page, KH, D)   physical key pool
@@ -248,7 +252,6 @@ def paged_mixed_attention(
     k_scales: jax.Array | None = None,   # (n_pages, page) f32 codec scales
     v_scales: jax.Array | None = None,   # (n_pages, page) f32 codec scales
     k2_scales: jax.Array | None = None,  # (n_pages, page) f32 codec scales
-    codebook: jax.Array | None = None,   # (LEVELS,) f32 cluster centroids
     *,
     window: int = 0,
     softcap_val: float = 0.0,
@@ -259,7 +262,6 @@ def paged_mixed_attention(
     page_size: int = 0,      # logical tokens per page; 0 = the pools'
     #                          physical page dim (i.e. no row padding)
     pages_per_step: int = 1,  # physical pages DMA'd per grid step
-    dequant: str = "gather",  # codec lookup: "gather" | "onehot"
     interpret: bool = False,
 ) -> jax.Array:
     """out (S, Q, H, Dv) float32 — ragged mixed-step paged attention.
@@ -273,16 +275,17 @@ def paged_mixed_attention(
 
     When ``k_scales`` is given (``kv_codec="cluster"``) the K/V pools —
     and ``k2_pages`` if present — hold int8 ``kv_codec`` codes; each
-    page is decoded in VMEM against ``codebook`` and its per-token
-    scale row before scoring.  Equivalent to decoding the whole pool
+    page is decoded in VMEM (a codebook lookup times its per-token
+    scale) before scoring.  Equivalent to decoding the whole pool
     up front, without ever materialising the fp pool.
 
-    Tiled pools: ``q``/``q2`` narrower than the pools' feature dims are
-    zero-padded up to them here (the caller slices the output back to
-    its model width), and ``page_size < k_pages.shape[1]`` declares the
-    trailing physical rows of every page to be layout padding.  The
-    output value width is the *pool's* ``Dv`` — callers using padded
-    value pools slice ``out[..., :dv_model]``.
+    Tiled pools: the kernel reads only the first ``D`` (``q``'s width)
+    feature columns of the key pool and ``D2`` of ``k2_pages``; a value
+    pool as wide as the key pool is read at ``D`` too (pools padded
+    together), any other at its own width ``Dv``, which is the output's
+    width.  ``page_size < k_pages.shape[1]`` declares the trailing
+    physical rows of every page to be layout padding.  Padding is thus
+    never computed on, and a padded pool gives the unpadded result.
     """
     s_n, qn, h, d = q.shape
     n_pages, page, kh, dk = k_pages.shape
@@ -292,7 +295,7 @@ def paged_mixed_attention(
     assert dk >= d, (dk, d)
     assert h % kh == 0, (h, kh)
     g = h // kh
-    q = _pad_last(q, dk)
+    wv = d if dv == dk else dv        # value columns read and returned
     c = max(int(pages_per_step), 1)
     n_groups = -(-table.shape[1] // c)
     if n_groups * c != table.shape[1]:
@@ -312,57 +315,56 @@ def paged_mixed_attention(
             + (0,) * (len(block) - 1))
 
     in_specs = [
-        pl.BlockSpec((1, qb, h, dk),
+        pl.BlockSpec((1, qb, h, d),
                      lambda s, qi, j, t, ln, ql: (s, qi, 0, 0)),
         *[walk(i, (1, page, kh, dk)) for i in range(c)],
         *[walk(i, (1, page, kh, dv)) for i in range(c)],
     ]
     args = [q, *[k_pages] * c, *[v_pages] * c]
+    w2 = 0
     if q2 is not None:
-        d2 = k2_pages.shape[-1]
-        q2 = _pad_last(q2, d2)
+        d2, w2 = k2_pages.shape[-1], q2.shape[-1]
+        assert d2 >= w2, (d2, w2)
         in_specs += [
-            pl.BlockSpec((1, qb, h, d2),
+            pl.BlockSpec((1, qb, h, w2),
                          lambda s, qi, j, t, ln, ql: (s, qi, 0, 0)),
             *[walk(i, (1, page, kh, d2)) for i in range(c)],
         ]
         args += [q2, *[k2_pages] * c]
+    scratch = [
+        pltpu.VMEM((h, qb, 1), jnp.float32),      # running max
+        pltpu.VMEM((h, qb, 1), jnp.float32),      # running normaliser
+        pltpu.VMEM((h, qb, wv), jnp.float32),     # output accumulator
+    ]
     if k_scales is not None:
-        # one scale row per physical page, walked through the page table
-        # exactly like the pools themselves
-        in_specs += [walk(i, (1, page)) for i in range(c)]
-        args += [k_scales.astype(jnp.float32)] * c
-        in_specs += [walk(i, (1, page)) for i in range(c)]
-        args += [v_scales.astype(jnp.float32)] * c
-        if q2 is not None:
-            in_specs += [walk(i, (1, page)) for i in range(c)]
-            args += [k2_scales.astype(jnp.float32)] * c
-        in_specs += [pl.BlockSpec((1, LEVELS),
+        # one scale column (page, 1) per physical page, walked through the
+        # page table exactly like the pools themselves; a column scales
+        # the decoded page's rows without an in-kernel transpose
+        for sc in (k_scales, v_scales) + ((k2_scales,) if q2 is not None
+                                           else ()):
+            in_specs += [walk(i, (1, page, 1)) for i in range(c)]
+            args += [sc.astype(jnp.float32).reshape(n_pages, page, 1)] * c
+        in_specs += [pl.BlockSpec((1, kv_codec.ZERO_CODE),
                                   lambda s, qi, j, t, ln, ql: (0, 0))]
-        args += [jnp.asarray(codebook, jnp.float32).reshape(1, LEVELS)]
-        assert dequant in ("gather", "onehot"), dequant
+        args += [kv_codec.codebook()[None, kv_codec.ZERO_CODE:]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(s_n, nqb, n_groups),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, qb, h, dv),
+        out_specs=pl.BlockSpec((1, qb, h, wv),
                                lambda s, qi, j, t, ln, ql: (s, qi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((kh, g * qb), jnp.float32),    # running max
-            pltpu.VMEM((kh, g * qb), jnp.float32),    # running normaliser
-            pltpu.VMEM((h * qb, dv), jnp.float32),    # output accumulator
-        ],
+        scratch_shapes=scratch,
     )
     return pl.pallas_call(
-        functools.partial(_kernel, page=page, logical=logical, c=c, kh=kh,
-                          g=g, qb=qb, window=window,
+        functools.partial(_kernel, logical=logical, c=c, kh=kh,
+                          g=g, qb=qb, widths=(d, wv, w2), window=window,
                           softcap_val=softcap_val, scale=scale,
                           has_q2=q2 is not None,
-                          has_codec=k_scales is not None, dequant=dequant),
+                          has_codec=k_scales is not None),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_n, qn, h, dv), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
+        out_shape=jax.ShapeDtypeStruct((s_n, qn, h, wv), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(table.astype(jnp.int32), lengths.astype(jnp.int32),
@@ -380,14 +382,12 @@ def paged_decode_attention(
     k_scales: jax.Array | None = None,
     v_scales: jax.Array | None = None,
     k2_scales: jax.Array | None = None,
-    codebook: jax.Array | None = None,
     *,
     window: int = 0,
     softcap_val: float = 0.0,
     scale: float = 1.0,
     page_size: int = 0,
     pages_per_step: int = 1,
-    dequant: str = "gather",
     interpret: bool = False,
 ) -> jax.Array:
     """out (S, H, Dv) float32 — single-token decode, the ``Q == 1``
@@ -397,8 +397,8 @@ def paged_decode_attention(
         q[:, None], k_pages, v_pages, table, lengths,
         jnp.ones((q.shape[0],), jnp.int32),
         None if q2 is None else q2[:, None], k2_pages,
-        k_scales, v_scales, k2_scales, codebook,
+        k_scales, v_scales, k2_scales,
         window=window, softcap_val=softcap_val, scale=scale,
         page_size=page_size, pages_per_step=pages_per_step,
-        dequant=dequant, interpret=interpret)
+        interpret=interpret)
     return out[:, 0]

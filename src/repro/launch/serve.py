@@ -44,6 +44,13 @@ neither changes generated tokens.  ``--cache-mb auto`` sweeps the
 materialize access pattern over a capacity grid and serves with the
 recommended hit-rate-cliff knee capacity.
 
+One process serves on one device (``launch.mesh.make_serving_mesh``):
+on a TPU host that is the first chip, and nothing here starts a child
+process that would need another.  ``main(argv)`` takes the argv list
+in-process; ``build_engine`` + ``serve`` are the same two steps for a
+caller that serves several runs from one compressed engine
+(``chip_smoke.py``).
+
   PYTHONPATH=src python -m repro.launch.serve --scale tiny
   PYTHONPATH=src python -m repro.launch.serve --scale tiny \
       --trace-out /tmp/trace.json --metrics-out /tmp/metrics.prom \
@@ -64,15 +71,17 @@ import numpy as np
 
 from repro.configs import base as cfgs
 from repro.dist import sharding as shd
-from repro.launch.mesh import make_host_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_serving_mesh
 from repro.launch.train import tiny_config
 from repro.models.api import get_model
 from repro.runtime import (Scheduler, ServeEngine, Telemetry, parse_prom,
                            recommend_store_capacity)
 from repro.runtime.decode_cache import POLICIES
+from repro.runtime.metrics import ServeMetrics
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-2b", choices=cfgs.ARCH_NAMES)
     ap.add_argument("--batch", type=int, default=4)
@@ -186,12 +195,14 @@ def main():
     ap.add_argument("--metrics-out", type=str, default=None,
                     help="write every serving counter/gauge/histogram in "
                          "Prometheus text-exposition format to this path")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def build_engine(args: argparse.Namespace) -> ServeEngine:
+    """Random weights from seed 0 at ``args.scale``, compressed into the
+    weight store (unless ``--no-compress``) -> the serving engine."""
     cfg = tiny_config(args.arch) if args.scale == "tiny" \
         else cfgs.get_config(args.arch)
-    mesh = make_host_mesh()
-    n_requests = args.requests or args.batch
     cache_auto = args.cache_mb == "auto"
     cache_bytes = None if args.cache_mb is None or cache_auto \
         else int(float(args.cache_mb) * 2 ** 20)
@@ -202,13 +213,13 @@ def main():
         if (args.trace_out or args.trace_jsonl or args.metrics_out) \
         else None
 
-    with shd.use_mesh(mesh):
-        params = get_model(cfg).init_params(cfg, jax.random.PRNGKey(0))
-        engine = ServeEngine(cfg, params, compress=not args.no_compress,
-                             cache_bytes=cache_bytes,
-                             cache_policy=args.policy,
-                             prefetch=not args.no_prefetch,
-                             telemetry=telemetry)
+    with shd.use_mesh(make_serving_mesh()):
+        # the engine keeps what it serves from; the originals die here
+        engine = ServeEngine(
+            cfg, get_model(cfg).init_params(cfg, jax.random.PRNGKey(0)),
+            compress=not args.no_compress, cache_bytes=cache_bytes,
+            cache_policy=args.policy, prefetch=not args.no_prefetch,
+            telemetry=telemetry)
         if cache_auto:
             if not engine.compressed:
                 raise SystemExit("--cache-mb auto needs the compressed "
@@ -231,7 +242,18 @@ def main():
         else:
             print(f"weight store: no compressible MLPs in {args.arch}; "
                   "serving uncompressed")
+    return engine
 
+
+def serve(engine: ServeEngine, args: argparse.Namespace) -> dict:
+    """Serve ``args``'s synthetic request mix on ``engine`` and print the
+    summary -> {"scheduler", "completed", "wall_s"}.  Each call reports
+    its own run: the engine's serving metrics start from zero."""
+    cfg = engine.cfg
+    n_requests = args.requests or args.batch
+    telemetry = engine.telemetry
+    engine.metrics = ServeMetrics()
+    with shd.use_mesh(make_serving_mesh()):
         sched = Scheduler(engine, batch_size=args.batch, mode=args.mode,
                           prefill_chunk=args.prefill_chunk,
                           prefill_budget=args.prefill_budget,
@@ -353,7 +375,7 @@ def main():
               f"{m.decode_steps / max(total, 1):.2f} verify steps/token")
     print("sample token ids:", completed[0].generated[:16])
 
-    if telemetry is not None and telemetry.tracing:
+    if telemetry.tracing:
         tr = telemetry.tracer
         n_spans = sum(1 for e in tr.events
                       if e["ph"] == "X" and e["name"] == "request")
@@ -376,6 +398,13 @@ def main():
             f.write(text)
         print(f"metrics: {len(text.splitlines())} lines of Prometheus "
               f"text exposition -> {args.metrics_out}")
+    return {"scheduler": sched, "completed": completed, "wall_s": wall}
+
+
+def main(argv=None):
+    enable_compile_cache()
+    args = parse_args(argv)
+    serve(build_engine(args), args)
 
 
 if __name__ == "__main__":

@@ -94,7 +94,7 @@ def build_compressed_dp_train_step(loss_fn, mesh, oc: opt.OptConfig,
     loss_fn(params, batch) -> scalar local loss.
     state = {"params", "opt", "ef"}; returns (step_fn, state_shardings).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     axes = shd.batch_axes(mesh)
     repl = NamedSharding(mesh, P())
@@ -117,7 +117,7 @@ def build_compressed_dp_train_step(loss_fn, mesh, oc: opt.OptConfig,
             local, mesh=mesh,
             in_specs=(p_specs, o_specs, e_specs, b_specs),
             out_specs=(p_specs, o_specs, e_specs, P()),
-            check_rep=False,
+            check_vma=False,
         )(state["params"], state["opt"], state["ef"], batch)
         return {"params": new_p, "opt": new_o, "ef": new_e}, loss
 

@@ -21,6 +21,7 @@ from repro.data.pipeline import Prefetcher, SyntheticLM
 from repro.dist import sharding as shd
 from repro.dist.fault import FaultConfig, Supervisor
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.train.optimizer import OptConfig
 
@@ -73,6 +74,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = tiny_config(args.arch) if args.scale == "tiny" \
         else cfgs.get_config(args.arch)

@@ -442,8 +442,7 @@ def attn_apply(
             v, v_sc = kv_codec.encode(v, axes=(-2, -1))
             new_scales = {"k": paged.write(scales["k"], k_sc, pos, q_lens),
                           "v": paged.write(scales["v"], v_sc, pos, q_lens)}
-            kw = dict(k_scales=new_scales["k"], v_scales=new_scales["v"],
-                      codebook=kv_codec.codebook())
+            kw = dict(k_scales=new_scales["k"], v_scales=new_scales["v"])
         k_pool = paged.write(cache["k"], k, pos, q_lens)
         v_pool = paged.write(cache["v"], v, pos, q_lens)
         out = paged_mixed_attention(
@@ -658,8 +657,7 @@ def mla_apply(p, x, cfg, *, cache=None, pos=None, paged=None, q_lens=None,
                 "k_pe": paged.write(scales["k_pe"], pe_sc, pos, q_lens)}
             kw = dict(k_scales=new_scales["c_kv"],
                       v_scales=new_scales["c_kv"],
-                      k2_scales=new_scales["k_pe"],
-                      codebook=kv_codec.codebook())
+                      k2_scales=new_scales["k_pe"])
         c_pool = paged.write(cache["c_kv"], c_kv, pos, q_lens)
         pe_pool = paged.write(cache["k_pe"], k_pe, pos, q_lens)
         w_uk = p["w_uk"].reshape(r_kv, h, dn)

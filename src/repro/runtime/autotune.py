@@ -197,8 +197,7 @@ def tune_kernel(cfg, page_size: int, q: int, *, codec: bool = False,
     kw = {}
     if codec:
         codes, scales = kv_codec.encode(jnp.asarray(pool), axes=(-2, -1))
-        kw = dict(k_scales=scales, v_scales=scales,
-                  codebook=kv_codec.codebook())
+        kw = dict(k_scales=scales, v_scales=scales)
         pool = codes
     pool = jnp.asarray(pool)
 

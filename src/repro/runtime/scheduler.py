@@ -82,6 +82,7 @@ import numpy as np
 from repro.kernels import kv_codec as kv_codec_mod
 from repro.kernels.kv_codec import KV_CODECS
 from repro.kernels.paged_attention import effective_q_block
+from repro.kernels.platform import interpret_mode
 from repro.models.api import (ATTN_BACKENDS, cache_layout, get_model,
                               padded_page_dims, supports_chunked_prefill,
                               supports_paged_attention,
@@ -339,8 +340,8 @@ class ServeEngine:
         # pallas_paged backend: one compiled mixed step per (cache layout,
         # padded block width) — decode-only ticks compile at Q=1, chunked
         # ticks at Q=prefill_chunk (the pools are donated; the Pallas
-        # kernel runs interpreted on hosts without a TPU, compiled on TPU)
-        self.kernel_interpret = jax.default_backend() != "tpu"
+        # kernel runs interpreted on CPU, compiled on TPU)
+        self.kernel_interpret = interpret_mode()
         self._mixed_jits: dict = {}
 
     @property
@@ -381,6 +382,18 @@ class ServeEngine:
             # counts
             self.metrics.record_kernel_qblock_rounded()
             _warn_qblock_rounded(qn, q_block)
+        fn = self.mixed_step_fn(paged_flags, page_size, qn, codec, q_block,
+                                pages_per_step)
+        if codec:
+            return fn(params, kcache, table, toks, poss, q_lens, kv_scales)
+        return fn(params, kcache, table, toks, poss, q_lens)
+
+    def mixed_step_fn(self, paged_flags: tuple, page_size: int, qn: int,
+                      codec: bool, q_block: int = 0,
+                      pages_per_step: int = 1):
+        """The jitted mixed step for one (layout, block width, codec,
+        launch shape) key, built on first use.  Its arguments are
+        ``mixed_step``'s, with the scale tree last under the codec."""
         key = (paged_flags, page_size, qn, codec, q_block, pages_per_step)
         fn = self._mixed_jits.get(key)
         if fn is None:
@@ -400,9 +413,7 @@ class ServeEngine:
                         step(p, c, t, tok, pos, ql),
                     donate_argnums=(1,))
             self._mixed_jits[key] = fn
-        if codec:
-            return fn(params, kcache, table, toks, poss, q_lens, kv_scales)
-        return fn(params, kcache, table, toks, poss, q_lens)
+        return fn
 
     def step_params(self):
         """Per-step serving params (tile-cache-served when compressed)."""
@@ -1386,6 +1397,20 @@ class SlotPool:
                 page_size=self.page_size, q_block=self.q_block,
                 pages_per_step=self.pages_per_step)
         return logits
+
+    def lowered_mixed_step(self, params, width: int = 1):
+        """The engine's mixed step for this pool at block width ``width``,
+        lowered against the live pools without running it -- what a
+        caller inspects to see which kernels the step holds."""
+        assert self.backend == "pallas_paged"
+        fn = self.engine.mixed_step_fn(self.paged_flags, self.page_size,
+                                       width, self.codec, self.q_block,
+                                       self.pages_per_step)
+        blk = jnp.zeros((self.n_slots, width), jnp.int32)
+        lens = jnp.zeros((self.n_slots,), jnp.int32)
+        args = (params, self.kcache, jnp.asarray(self.table), blk, lens,
+                lens) + ((self.kscales,) if self.codec else ())
+        return fn.lower(*args)
 
     # -- decode -------------------------------------------------------------
     def decode(self, params) -> list[tuple[Slot, int, bool]]:

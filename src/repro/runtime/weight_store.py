@@ -55,6 +55,10 @@ from repro.runtime.telemetry import NULL_TELEMETRY
 # serving tiles reuse the offline layout default (C=8 -> 1024 sequences/
 # tile); the tile is also the cache's eviction granularity
 DEFAULT_CODES_PER_SUB = compression.DEFAULT_CODES_PER_SUB
+# tiles decoded per device call: a miss decodes the fixed-size batch of
+# consecutive tiles around it, so a full-width layer (thousands of tiles)
+# costs a handful of dispatches instead of one per tile
+DECODE_BATCH = 256
 
 
 def default_select(path: str, ndim: int) -> bool:
@@ -74,6 +78,10 @@ class StoredLayer:
     n: int                                # output channels (rows of bits)
     k: int                                # true contraction length
     dtype: np.dtype
+    # sequences the stream encodes, kept until first use re-tiles them
+    # (the scalar stream decoder is the tests' oracle, far too slow for a
+    # full-width layer)
+    seqs: np.ndarray | None = None
     # lazily materialised state
     tiled: compression.TiledStream | None = None
     tables: np.ndarray | None = None
@@ -81,11 +89,10 @@ class StoredLayer:
     freq_seeded: bool = False
 
     def ensure_tiled(self) -> compression.TiledStream:
-        """First-use re-tiling: stream -> substream-parallel layout."""
+        """First-use re-tiling: stream sequences -> substream-parallel
+        layout."""
         if self.tiled is None:
-            seqs = huffman.decode_stream(
-                self.ct.stream_words, self.ct.stream_bits, self.ct.assign,
-                count=self.ct.n_seqs)
+            seqs, self.seqs = self.seqs, None
             self.tiled = compression.tile_stream(seqs, self.ct.assign)
             self.tables = self.ct.decode_tables()
             # per-tile frequency mass: how much of the layer's skewed
@@ -124,8 +131,16 @@ class _ModelEntry:
 
 
 @functools.partial(jax.jit, static_argnames=("c",))
-def _decode_tile_jit(words, tables, c):
-    return ref.decode_tile(words, tables, c)
+def _decode_tiles_jit(words, tables, c):
+    return ref.decode_tiled(words, tables, c)
+
+
+def _decode_batch(ts: compression.TiledStream, tables, t: int):
+    """Dispatch the device decode of the ``DECODE_BATCH`` tiles starting at
+    tile ``t`` (indices past the end repeat the last tile) -> (T, C, S)
+    int32 future."""
+    idx = np.minimum(np.arange(t, t + DECODE_BATCH), ts.n_tiles - 1)
+    return _decode_tiles_jit(jnp.asarray(ts.words[idx]), tables, ts.c)
 
 
 class WeightStore:
@@ -195,9 +210,14 @@ class WeightStore:
         wt = np.ascontiguousarray(w2.T)                # (N=d_out, K=d_in)
         scale = np.abs(wt).mean(axis=1)                # binarize_weights alpha
         bits = (wt >= 0).astype(np.uint8)
-        ct = compression.compress_gemm(bits, cluster=cluster, tiled=False)
+        seqs = bitpack.gemm_to_sequences(bits)
+        ct = compression.compress_sequences(seqs, bits.shape, "gemm",
+                                            cluster=cluster, tiled=False)
+        if ct.replacement is not None:       # the clustered sequences
+            seqs = ct.replacement[seqs]
         return StoredLayer(name=name, ct=ct, scale=scale,
-                           n=wt.shape[0], k=wt.shape[1], dtype=w2.dtype)
+                           n=wt.shape[0], k=wt.shape[1], dtype=w2.dtype,
+                           seqs=seqs.ravel())
 
     # -- tile-level serving ------------------------------------------------
     def _seed_layer(self, model_id: str, layer: StoredLayer) -> None:
@@ -223,9 +243,12 @@ class WeightStore:
         with self.telemetry.timed("weights.prefetch", layer=layer.name,
                                   tiles=len(missing)):
             tables = jnp.asarray(layer.tables)
+            batches: dict = {}
             for t in missing:
-                pending[(model_id, layer.name, t)] = _decode_tile_jit(
-                    jnp.asarray(ts.words[t]), tables, ts.c)
+                b = t - t % DECODE_BATCH
+                if b not in batches:
+                    batches[b] = _decode_batch(ts, tables, b)
+                pending[(model_id, layer.name, t)] = (batches[b], t - b)
                 self.prefetch_dispatched += 1
 
     def _fetch_tiles(self, model_id: str, layer: StoredLayer,
@@ -240,6 +263,7 @@ class WeightStore:
         comp_bytes = layer.tile_compressed_bytes()
         tiles = []
         any_miss = False
+        decoded: dict = {}          # batch start -> host (T, C, S) tiles
         for t in range(ts.n_tiles):
             key = (model_id, layer.name, t)
             tile = self.cache.get(key)
@@ -247,12 +271,14 @@ class WeightStore:
                 fut = pending.pop(key, None) if pending else None
                 if fut is not None:
                     self.prefetch_used += 1
-                    tile = np.asarray(fut)
+                    tile = np.asarray(fut[0])[fut[1]]
                 else:
-                    with self.telemetry.timed("weights.decode_tile"):
-                        tile = np.asarray(_decode_tile_jit(
-                            jnp.asarray(ts.words[t]),
-                            jnp.asarray(layer.tables), ts.c))
+                    b = t - t % DECODE_BATCH
+                    if b not in decoded:
+                        with self.telemetry.timed("weights.decode_tile"):
+                            decoded[b] = np.asarray(_decode_batch(
+                                ts, jnp.asarray(layer.tables), b))
+                    tile = decoded[b][t - b]
                 self.cache.put(key, tile, streamed_bytes=comp_bytes)
                 any_miss = True
             tiles.append(tile)

@@ -1,5 +1,5 @@
 """Hardware-shaped kernel launch: tiled pools, multi-page grid steps,
-gathered dequant, and the launch-shape autotuner.
+in-kernel codec decode, and the launch-shape autotuner.
 
 The tiling-equivalence contract: a pool padded toward the TPU's
 (8, 128) sublane/lane register tiles, walked ``pages_per_step`` pages
@@ -11,9 +11,8 @@ kernel-vs-dense-oracle tests).  At ``pages_per_step=1`` the padded
 kernel output is **bit-identical** to the unpadded one; the serve-level
 suites assert token identity across the full launch-shape grid.
 
-Also here: the gathered codebook dequant vs the one-hot reference
-(bit-identity regression for the satellite that replaced the
-O(page*256) one-hot matmul), the ``kernel_qblock_rounded`` telemetry
+Also here: the in-kernel half-codebook gather vs the full table
+(bit-identity regression), the ``kernel_qblock_rounded`` telemetry
 for gcd-rounded q_blocks, and ``tune_kernel`` unit tests.
 """
 
@@ -144,9 +143,13 @@ class TestTilingEquivalenceKernel:
 
 
 class TestDequantGather:
-    """The gathered codebook lookup vs the one-hot reference path."""
+    """The in-kernel half-codebook lane gather vs the full table."""
 
     def test_gather_bitwise_matches_onehot(self):
+        """The codec kernel equals the fp kernel over the pool decoded
+        through the full 256-entry codebook, bit for bit: the kernel's
+        magnitude lookup in the non-negative half, negated for negative
+        codes, is the same table entry."""
         rng = np.random.default_rng(5)
         s, kh, d, dv, page, pages = 3, 2, 16, 16, 4, 4
         q_lens = np.array([2, 4, 1], np.int32)
@@ -156,12 +159,18 @@ class TestDequantGather:
         ck, ks = kv_codec.encode(jnp.asarray(k), axes=(-2, -1))
         cv, vs = kv_codec.encode(jnp.asarray(v), axes=(-2, -1))
         q = rng.normal(size=(s, 4, 4, d)).astype(np.float32)
-        kw = dict(k_scales=ks, v_scales=vs, codebook=kv_codec.codebook(),
-                  interpret=True)
+        cb = np.asarray(kv_codec.codebook())
+
+        def table_decode(c, sc):
+            return cb[np.asarray(c, np.int32) + kv_codec.ZERO_CODE] \
+                * np.asarray(sc)[:, :, None, None]
+
         a = np.asarray(paged_mixed_attention(
-            q, ck, cv, table, lengths, q_lens, dequant="gather", **kw))
+            q, ck, cv, table, lengths, q_lens, k_scales=ks, v_scales=vs,
+            interpret=True))
         b = np.asarray(paged_mixed_attention(
-            q, ck, cv, table, lengths, q_lens, dequant="onehot", **kw))
+            q, table_decode(ck, ks), table_decode(cv, vs), table, lengths,
+            q_lens, interpret=True))
         np.testing.assert_array_equal(a, b)
 
     def test_codec_padded_pool_matches_unpadded(self):
@@ -177,10 +186,9 @@ class TestDequantGather:
         ck, ks = kv_codec.encode(jnp.asarray(k), axes=(-2, -1))
         cv, vs = kv_codec.encode(jnp.asarray(v), axes=(-2, -1))
         q = rng.normal(size=(s, 3, 4, d)).astype(np.float32)
-        cb = kv_codec.codebook()
         base = np.asarray(paged_mixed_attention(
             q, ck, cv, table, lengths, q_lens, k_scales=ks, v_scales=vs,
-            codebook=cb, interpret=True))
+            interpret=True))
         rows, feat = TILE_SUBLANE, round_up(d, TILE_LANE)
         pad_s = np.zeros((ks.shape[0], rows), np.float32)
         pad_s[:, :page] = np.asarray(ks)
@@ -190,7 +198,7 @@ class TestDequantGather:
             q, pad_pool(np.asarray(ck), rows, feat),
             pad_pool(np.asarray(cv), rows, feat),
             table, lengths, q_lens, k_scales=pad_s, v_scales=pad_vs,
-            codebook=cb, page_size=page, interpret=True))[..., :dv]
+            page_size=page, interpret=True))[..., :dv]
         for i in range(s):
             np.testing.assert_array_equal(out[i, :q_lens[i]],
                                           base[i, :q_lens[i]])

@@ -214,8 +214,7 @@ class TestKernelCodecPath:
         vc, vs, vd = encode_pool(v_pages)
         out = paged_decode_attention(
             q, jnp.asarray(kc), jnp.asarray(vc), jnp.asarray(table),
-            jnp.asarray(lengths), k_scales=ks, v_scales=vs,
-            codebook=kv_codec.codebook(), interpret=True)
+            jnp.asarray(lengths), k_scales=ks, v_scales=vs, interpret=True)
         want = paged_decode_attention(
             q, kd, vd, jnp.asarray(table), jnp.asarray(lengths),
             interpret=True)
@@ -241,7 +240,7 @@ class TestKernelCodecPath:
             q1, jnp.asarray(cc)[:, :, None], jnp.asarray(cc)[:, :, None],
             jnp.asarray(table), jnp.asarray(lengths), q2,
             jnp.asarray(pc)[:, :, None], k_scales=cs, v_scales=cs,
-            k2_scales=ps, codebook=kv_codec.codebook(), **args)
+            k2_scales=ps, **args)
         want = paged_decode_attention(
             q1, cd[:, :, None], cd[:, :, None], jnp.asarray(table),
             jnp.asarray(lengths), q2, pd[:, :, None], **args)
@@ -267,7 +266,7 @@ class TestKernelCodecPath:
             return np.asarray(paged_decode_attention(
                 q, jnp.asarray(kcodes), jnp.asarray(vcodes),
                 jnp.asarray(table), jnp.asarray(lengths), k_scales=ks,
-                v_scales=vs, codebook=kv_codec.codebook(), interpret=True))
+                v_scales=vs, interpret=True))
 
         clean = run(kc, vc)
         kc2, vc2 = np.asarray(kc).copy(), np.asarray(vc).copy()

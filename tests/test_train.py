@@ -80,7 +80,7 @@ class TestGradCompression:
         g = {"w": jnp.asarray(np.random.default_rng(0)
                               .standard_normal((64, 32)).astype(np.float32))}
         ef = init_error_feedback(g)
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         def f(gr, e):
@@ -89,7 +89,7 @@ class TestGradCompression:
         specs = jax.tree_util.tree_map(lambda _: P(), g)
         out, new_ef = shard_map(f, mesh=mesh, in_specs=(specs, specs),
                                 out_specs=(specs, specs),
-                                check_rep=False)(g, ef)
+                                check_vma=False)(g, ef)
         return g, out, new_ef
 
     @pytest.mark.parametrize("mode", ["onebit", "int8"])
@@ -113,7 +113,7 @@ class TestGradCompression:
         acc = jnp.zeros_like(g)
         from repro.dist.compression_comm import onebit_allreduce
         mesh = make_host_mesh()
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         def step(e):
@@ -122,7 +122,7 @@ class TestGradCompression:
         for _ in range(60):
             out, ef = shard_map(step, mesh=mesh, in_specs=(P(),),
                                 out_specs=(P(), P()),
-                                check_rep=False)(ef)
+                                check_vma=False)(ef)
             acc = acc + out
         # per-step emission magnitude is the mean |g| scale, so EF can
         # de-bias everything whose magnitude fits under it; the tail above
