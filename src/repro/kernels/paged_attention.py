@@ -364,6 +364,7 @@ def paged_mixed_attention(
                           has_codec=k_scales is not None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_n, qn, h, wv), jnp.float32),
+        name="paged_mixed_attention",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,

@@ -134,6 +134,9 @@ class ServeMetrics:
     #                                    with (emitted beyond the 1/step
     #                                    baseline — the speculation win)
     spec_rejected_tokens: int = 0      # drafts rolled back
+    weight_walks: int = 0              # step_params() walks of the
+    #                                    compressed weight store
+    weight_walk_tiles: int = 0         # tile lookups those walks made
     _t0: float = dataclasses.field(default_factory=time.monotonic)
     # latency distributions (log-bucket histograms; seconds).  Lifetime
     # averages hide tails — the paper's wins are distribution claims, so
@@ -271,6 +274,13 @@ class ServeMetrics:
         """Fraction of proposed draft tokens the verifier accepted."""
         return self.spec_accepted_tokens / self.spec_draft_tokens \
             if self.spec_draft_tokens else 0.0
+
+    def record_weight_walk(self, tiles: int) -> None:
+        """One walk of the weight store that looked up ``tiles`` tiles
+        (the store's own lifetime counters are ``WeightStore.walks`` and
+        ``.walk_tiles``; these count what this metrics window saw)."""
+        self.weight_walks += 1
+        self.weight_walk_tiles += tiles
 
     def record_completed(self, n_requests: int) -> None:
         self.requests_completed += n_requests

@@ -418,7 +418,10 @@ class ServeEngine:
     def step_params(self):
         """Per-step serving params (tile-cache-served when compressed)."""
         if self.compressed:
-            return self.store.materialize(self.model_id)
+            tiles = self.store.walk_tiles
+            params = self.store.materialize(self.model_id)
+            self.metrics.record_weight_walk(self.store.walk_tiles - tiles)
+            return params
         return self._raw_params
 
     # stubbed multimodal frontends, matching the launcher conventions
@@ -1722,8 +1725,7 @@ class Scheduler:
                 with tel.timed("admit"):
                     self._admit(pool, completed)
             if self._mixed_path(pool):
-                with tel.timed("mixed_step"):
-                    self._mixed_tick(pool, completed)
+                self._mixed_tick(pool, completed)
             else:
                 if pool.prefilling():
                     with tel.timed("prefill"):
@@ -1734,8 +1736,7 @@ class Scheduler:
                             # single-phase in-kernel speculation: the
                             # mixed tick verifies drafts even with no
                             # chunks in flight
-                            with tel.timed("mixed_step"):
-                                self._mixed_tick(pool, completed)
+                            self._mixed_tick(pool, completed)
                         else:
                             self._spec_step(pool, completed)
                     else:
@@ -1897,7 +1898,10 @@ class Scheduler:
                     self._queue = group + self._queue
                 return
             (group or self._queue).pop(0)
-            params = self.engine.step_params()
+            # its own phase, so the phase histograms tell an admission's
+            # weight walk from a step's
+            with self.engine.telemetry.timed("admit.walk"):
+                params = self.engine.step_params()
             self._start_or_admit(pool, req, params, completed)
 
     def _prefill_tick(self, pool: SlotPool, completed: list[Request]) -> None:
@@ -1975,156 +1979,179 @@ class Scheduler:
         K/V lands straight in the slot's pages (lane leaves are written
         in the same trace with ragged masks) — so per-iteration KV gather
         bytes are zero on the prefill and decode paths alike, which the
-        metrics record and tests assert."""
+        metrics record and tests assert.
+
+        The ``mixed_step`` phase (args: block width Q, active slots,
+        chunk tokens) holds four children in order -- ``.prepare``
+        (token blocks, page tables), the weight walk, ``.dispatch`` (the
+        uploads and the enqueue), ``.wait`` (the host blocked on the
+        device's logits) and ``.commit`` (tokens, retirements,
+        metrics)."""
+        with self.engine.telemetry.timed("mixed_step") as step:
+            self._mixed_tick_phases(pool, completed, step)
+
+    def _mixed_tick_phases(self, pool: SlotPool, completed: list[Request],
+                           step) -> None:
+        """:meth:`_mixed_tick`'s body, inside its ``mixed_step`` phase
+        ``step``."""
         m = self.engine.metrics
-        active = pool.active()
-        chunks: list[tuple[Slot, int]] = []
-        spent = 0
-        for slot in pool.prefilling():
-            if spent >= self.prefill_budget and chunks:
-                break
-            c = min(self.prefill_chunk,
-                    slot.req.prompt_len - slot.prefill_cursor)
-            chunks.append((slot, c))
-            spent += c
-        if not active and not chunks:
-            return
-        drafts: dict[int, np.ndarray] = {}
-        if self.drafter is not None and active:
-            # rolling-window lanes are snapshot/restored around the
-            # trace; the snapshot depth caps how deep a draft may write
-            cap = None if pool.lane_min_rows is None \
-                else pool.lane_min_rows - 1
-            with self.engine.telemetry.timed("spec_draft"):
-                drafts = self._propose_drafts(pool, active, cap=cap)
-        # pad every chunk-carrying tick to one block width so compiled
-        # mixed-step shapes stay bounded: Q = prefill_chunk while chunks
-        # are in flight (remainders ride padded; drafts fold into the
-        # same padding), Q = 1 + draft_k on speculative decode ticks,
-        # Q = 1 for plain decode
-        width = min(self.prefill_chunk, pool.slot_len) if chunks else 1
-        if chunks:
-            drafts = {i: d[:width - 1] for i, d in drafts.items()}
-        drafts = {i: d for i, d in drafts.items() if len(d)}
-        if drafts and not chunks:
-            width = 1 + self.draft_k
-        toks = np.zeros((pool.n_slots, width), np.int32)
-        poss = np.zeros(pool.n_slots, np.int32)
-        q_lens = np.zeros(pool.n_slots, np.int32)
-        for slot in active:
-            d = drafts.get(slot.index)
-            nd = 0 if d is None else len(d)
-            toks[slot.index, 0] = slot.tok
-            if nd:
-                toks[slot.index, 1:1 + nd] = d
-            poss[slot.index] = slot.pos
-            q_lens[slot.index] = 1 + nd
-            pool._prepare_write(slot, slot.pos, slot.pos + nd)
-            pool._ensure_pages(slot, slot.pos + nd)
-        for slot, c in chunks:
-            cur = slot.prefill_cursor
-            toks[slot.index, :c] = slot.req.prompt[cur:cur + c]
-            poss[slot.index] = cur
-            q_lens[slot.index] = c
-            # chunk K/V lands in the pool in place: shared pages under
-            # the write range must be copy-on-write'd first
-            pool._prepare_write(slot, cur, cur + c - 1)
-            pool._ensure_pages(slot, cur + c - 1)
+        tel = self.engine.telemetry
+        with tel.timed("mixed_step.prepare"):
+            active = pool.active()
+            chunks: list[tuple[Slot, int]] = []
+            spent = 0
+            for slot in pool.prefilling():
+                if spent >= self.prefill_budget and chunks:
+                    break
+                c = min(self.prefill_chunk,
+                        slot.req.prompt_len - slot.prefill_cursor)
+                chunks.append((slot, c))
+                spent += c
+            if not active and not chunks:
+                return
+            drafts: dict[int, np.ndarray] = {}
+            if self.drafter is not None and active:
+                # rolling-window lanes are snapshot/restored around the
+                # trace; the snapshot depth caps how deep a draft may
+                # write
+                cap = None if pool.lane_min_rows is None \
+                    else pool.lane_min_rows - 1
+                with tel.timed("spec_draft"):
+                    drafts = self._propose_drafts(pool, active, cap=cap)
+            # pad every chunk-carrying tick to one block width so compiled
+            # mixed-step shapes stay bounded: Q = prefill_chunk while
+            # chunks are in flight (remainders ride padded; drafts fold
+            # into the same padding), Q = 1 + draft_k on speculative
+            # decode ticks, Q = 1 for plain decode
+            width = min(self.prefill_chunk, pool.slot_len) if chunks else 1
+            if chunks:
+                drafts = {i: d[:width - 1] for i, d in drafts.items()}
+            drafts = {i: d for i, d in drafts.items() if len(d)}
+            if drafts and not chunks:
+                width = 1 + self.draft_k
+            toks = np.zeros((pool.n_slots, width), np.int32)
+            poss = np.zeros(pool.n_slots, np.int32)
+            q_lens = np.zeros(pool.n_slots, np.int32)
+            for slot in active:
+                d = drafts.get(slot.index)
+                nd = 0 if d is None else len(d)
+                toks[slot.index, 0] = slot.tok
+                if nd:
+                    toks[slot.index, 1:1 + nd] = d
+                poss[slot.index] = slot.pos
+                q_lens[slot.index] = 1 + nd
+                pool._prepare_write(slot, slot.pos, slot.pos + nd)
+                pool._ensure_pages(slot, slot.pos + nd)
+            for slot, c in chunks:
+                cur = slot.prefill_cursor
+                toks[slot.index, :c] = slot.req.prompt[cur:cur + c]
+                poss[slot.index] = cur
+                q_lens[slot.index] = c
+                # chunk K/V lands in the pool in place: shared pages under
+                # the write range must be copy-on-write'd first
+                pool._prepare_write(slot, cur, cur + c - 1)
+                pool._ensure_pages(slot, cur + c - 1)
+        n_chunk_toks = sum(c for _, c in chunks)
+        step.annotate(width=width, active=len(active),
+                      chunk_tokens=n_chunk_toks)
         t0 = time.monotonic()
         params = self.engine.step_params()
-        snaps = kk = None
-        if drafts and pool.lane_min_rows is not None:
-            # rolling-window lanes have no rewind: snapshot the rows the
-            # drafts will overwrite so rejected writes can be undone
-            kk = max(len(d) for d in drafts.values())
-            snaps = pool.spec_snapshot(poss, kk)
-        logits = pool.mixed_step(params, toks, poss, q_lens)
-        g = np.asarray(jnp.argmax(logits, axis=-1))              # (S, Q)
-        ok_rows = np.asarray(jnp.isfinite(logits).all(axis=-1))  # (S, Q)
-        lanes = np.arange(pool.n_slots)
-        nxt = g[lanes, np.maximum(q_lens - 1, 0)].astype(np.int32)
-        finite = ok_rows[lanes, np.maximum(q_lens - 1, 0)]
+        with tel.timed("mixed_step.dispatch"):
+            snaps = kk = None
+            if drafts and pool.lane_min_rows is not None:
+                # rolling-window lanes have no rewind: snapshot the rows
+                # the drafts will overwrite so rejected writes can be
+                # undone
+                kk = max(len(d) for d in drafts.values())
+                snaps = pool.spec_snapshot(poss, kk)
+            logits = pool.mixed_step(params, toks, poss, q_lens)
+        with tel.timed("mixed_step.wait"):
+            g = np.asarray(jnp.argmax(logits, axis=-1))              # (S, Q)
+            ok_rows = np.asarray(jnp.isfinite(logits).all(axis=-1))  # (S, Q)
+            lanes = np.arange(pool.n_slots)
+            nxt = g[lanes, np.maximum(q_lens - 1, 0)].astype(np.int32)
+            finite = ok_rows[lanes, np.maximum(q_lens - 1, 0)]
         dt = time.monotonic() - t0
-        # wall time attributed to decode vs prefill by token share
-        n_chunk_toks = sum(c for _, c in chunks)
-        n_dec_toks = int(sum(q_lens[s.index] for s in active))
-        total = n_dec_toks + n_chunk_toks
-        dt_decode = dt * n_dec_toks / total if total else 0.0
-        emitted = 0
-        acc: dict[int, int] = {}
-        for slot in active:
-            d = drafts.get(slot.index)
-            nd = 0 if d is None else len(d)
-            a = 0
-            while a < nd and int(d[a]) == int(g[slot.index, a]):
-                a += 1
-            acc[slot.index] = a
-            if not ok_rows[slot.index, :a + 1].all():
-                raise RuntimeError(
-                    f"non-finite logits in mixed step for request "
-                    f"{slot.req.rid} (compressed reconstruction or model "
-                    f"numerics are broken)")
-            for t in g[slot.index, :a + 1]:
-                slot.req.generated.append(int(t))
-            emitted += a + 1
-            slot.pos += a + 1
-            slot.tok = int(g[slot.index, a])
-            if nd:
-                m.record_spec(nd, a)
-            self._maybe_finish(pool, slot, completed)
-        if snaps is not None:
-            with self.engine.telemetry.timed("spec_rollback"):
-                keep = np.zeros((pool.n_slots, kk), bool)
-                for slot in active:
-                    d = drafts.get(slot.index)
-                    if d is not None:
-                        keep[slot.index, acc[slot.index]:len(d)] = True
-                pool.spec_restore(snaps, poss, keep)
-        tr = self.engine.telemetry.tracer
-        for slot, c in chunks:
-            m.record_prefill_chunk(c, (dt - dt_decode) / len(chunks),
-                                   stalled=bool(active))
-            if tr.enabled:
-                # chunks share one ragged trace; each request's span
-                # covers the tick's prefill share
-                tr.complete(PID_REQUEST, slot.req.rid, "prefill_chunk",
-                            t0, t0 + (dt - dt_decode), slot=slot.index,
-                            tokens=c, cursor=slot.prefill_cursor)
-            slot.prefill_cursor += c
-            if slot.prefill_cursor >= slot.req.prompt_len:
-                if not finite[slot.index]:
+        with tel.timed("mixed_step.commit"):
+            # wall time attributed to decode vs prefill by token share
+            n_dec_toks = int(sum(q_lens[s.index] for s in active))
+            total = n_dec_toks + n_chunk_toks
+            dt_decode = dt * n_dec_toks / total if total else 0.0
+            emitted = 0
+            acc: dict[int, int] = {}
+            for slot in active:
+                d = drafts.get(slot.index)
+                nd = 0 if d is None else len(d)
+                a = 0
+                while a < nd and int(d[a]) == int(g[slot.index, a]):
+                    a += 1
+                acc[slot.index] = a
+                if not ok_rows[slot.index, :a + 1].all():
                     raise RuntimeError(
-                        "non-finite prefill logits (compressed "
-                        "reconstruction or model numerics are broken)")
-                req = slot.req
-                slot.prefilling = False
-                slot.pcache = None
-                slot.tok = int(nxt[slot.index])
-                slot.pos = self.engine.pos_offset(req.prompt_len)
-                # mixed-step pages hold the kernel-written (possibly
-                # codec-encoded) K/V; the index shares them in place —
-                # per-(page, token) encoding keeps a future hit
-                # bit-identical to the sharing-off run
-                pool.register_prefix(slot)
-                self._record_first_token(req, slot.tok)
-                m.record_admit(1, 0.0, tokens=1)
-                # the install copy the gathered oracle performs at the
-                # end of every prefill never happened here
-                m.record_prefill_gather(0, pool.install_bytes)
+                        f"non-finite logits in mixed step for request "
+                        f"{slot.req.rid} (compressed reconstruction or "
+                        f"model numerics are broken)")
+                for t in g[slot.index, :a + 1]:
+                    slot.req.generated.append(int(t))
+                emitted += a + 1
+                slot.pos += a + 1
+                slot.tok = int(g[slot.index, a])
+                if nd:
+                    m.record_spec(nd, a)
                 self._maybe_finish(pool, slot, completed)
-        if active:
-            m.record_decode_step(emitted, dt_decode,
-                                 n_slots=pool.n_slots)
-            m.record_pages(pool.pages_in_use(), pool.allocator.total)
-            if pool.prefix is not None:
-                m.record_shared_pages(pool.allocator.shared_pages())
-            m.record_kv_gather(0, pool.gather_bytes_avoided_per_step)
-            if pool.codec:
-                m.record_kv_codec(pool.pages_in_use() * pool.page_bytes_fp,
-                                  pool.pages_in_use() *
-                                  pool.page_bytes_resident)
-            if self.log_every and m.decode_steps % self.log_every == 0:
-                self.emit(self.engine.stats_line())
+            if snaps is not None:
+                with tel.timed("spec_rollback"):
+                    keep = np.zeros((pool.n_slots, kk), bool)
+                    for slot in active:
+                        d = drafts.get(slot.index)
+                        if d is not None:
+                            keep[slot.index, acc[slot.index]:len(d)] = True
+                    pool.spec_restore(snaps, poss, keep)
+            tr = tel.tracer
+            for slot, c in chunks:
+                m.record_prefill_chunk(c, (dt - dt_decode) / len(chunks),
+                                       stalled=bool(active))
+                if tr.enabled:
+                    # chunks share one ragged trace; each request's span
+                    # covers the tick's prefill share
+                    tr.complete(PID_REQUEST, slot.req.rid, "prefill_chunk",
+                                t0, t0 + (dt - dt_decode), slot=slot.index,
+                                tokens=c, cursor=slot.prefill_cursor)
+                slot.prefill_cursor += c
+                if slot.prefill_cursor >= slot.req.prompt_len:
+                    if not finite[slot.index]:
+                        raise RuntimeError(
+                            "non-finite prefill logits (compressed "
+                            "reconstruction or model numerics are broken)")
+                    req = slot.req
+                    slot.prefilling = False
+                    slot.pcache = None
+                    slot.tok = int(nxt[slot.index])
+                    slot.pos = self.engine.pos_offset(req.prompt_len)
+                    # mixed-step pages hold the kernel-written (possibly
+                    # codec-encoded) K/V; the index shares them in place —
+                    # per-(page, token) encoding keeps a future hit
+                    # bit-identical to the sharing-off run
+                    pool.register_prefix(slot)
+                    self._record_first_token(req, slot.tok)
+                    m.record_admit(1, 0.0, tokens=1)
+                    # the install copy the gathered oracle performs at the
+                    # end of every prefill never happened here
+                    m.record_prefill_gather(0, pool.install_bytes)
+                    self._maybe_finish(pool, slot, completed)
+            if active:
+                m.record_decode_step(emitted, dt_decode,
+                                     n_slots=pool.n_slots)
+                m.record_pages(pool.pages_in_use(), pool.allocator.total)
+                if pool.prefix is not None:
+                    m.record_shared_pages(pool.allocator.shared_pages())
+                m.record_kv_gather(0, pool.gather_bytes_avoided_per_step)
+                if pool.codec:
+                    m.record_kv_codec(
+                        pool.pages_in_use() * pool.page_bytes_fp,
+                        pool.pages_in_use() * pool.page_bytes_resident)
+                if self.log_every and m.decode_steps % self.log_every == 0:
+                    self.emit(self.engine.stats_line())
 
     def _propose_drafts(self, pool: SlotPool, active: list[Slot],
                         cap: int | None = None) -> dict[int, np.ndarray]:

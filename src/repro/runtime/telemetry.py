@@ -20,8 +20,11 @@ layer the serving runtime records into:
     (loadable in ``chrome://tracing`` / Perfetto) and as JSONL events;
   * :class:`Telemetry` — the facade the runtime threads around: a
     lightweight ``timed(phase)`` context manager that records a phase
-    histogram and (when tracing) a span, so the trace shows where an
-    iteration's wall clock actually went.
+    histogram, (when tracing) a span, and a
+    ``jax.profiler.TraceAnnotation`` of the same name and arguments, so
+    a JAX profiler trace shows the phases on the device's clock and
+    each idle gap of the chip can be put down to the phase the host
+    was in.
 
 Cost discipline: the default recorder is :data:`NULL_TELEMETRY`, whose
 ``timed`` returns one shared no-op context manager and whose tracer
@@ -38,6 +41,8 @@ import json
 import math
 import re
 import time
+
+from jax.profiler import TraceAnnotation
 
 # Chrome-trace "process" ids: one per track family so Perfetto groups
 # request lifecycles separately from engine phases.
@@ -326,8 +331,22 @@ class NullTracer:
         pass
 
 
+class _NullTimed:
+    """The shared no-op context of :class:`NullTelemetry` and
+    :class:`NullTracer`."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def annotate(self, **args) -> None:
+        pass
+
+
 NULL_TRACER = NullTracer()
-_NULL_CTX = contextlib.nullcontext()
+_NULL_CTX = _NullTimed()
 
 
 # ---------------------------------------------------------------------------
@@ -335,9 +354,11 @@ _NULL_CTX = contextlib.nullcontext()
 # ---------------------------------------------------------------------------
 
 class _Timed:
-    """``timed(phase)`` context: phase histogram + (if tracing) a span."""
+    """``timed(phase)`` context: phase histogram + (if tracing) a span +
+    a profiler annotation, which costs about a microsecond while no
+    profiler session is active."""
 
-    __slots__ = ("tel", "phase", "args", "t0")
+    __slots__ = ("tel", "phase", "args", "t0", "ann")
 
     def __init__(self, tel: "Telemetry", phase: str, args: dict):
         self.tel = tel
@@ -345,11 +366,19 @@ class _Timed:
         self.args = args
 
     def __enter__(self):
+        self.ann = TraceAnnotation(self.phase, **self.args)
+        self.ann.__enter__()
         self.t0 = time.monotonic()
         return self
 
+    def annotate(self, **args) -> None:
+        """Add arguments known only once the phase is under way."""
+        self.args.update(args)
+        self.ann.set_metadata(**args)
+
     def __exit__(self, *exc):
         t1 = time.monotonic()
+        self.ann.__exit__(*exc)
         tel = self.tel
         hist = tel.phases.get(self.phase)
         if hist is None:
@@ -381,8 +410,9 @@ class Telemetry:
         return self.tracer.enabled
 
     def timed(self, phase: str, **args) -> _Timed:
-        """Time a phase: records into ``phases[phase]`` and, when
-        tracing, emits an engine-track span."""
+        """Time a phase: records into ``phases[phase]``, annotates the
+        JAX profiler's trace and, when tracing, emits an engine-track
+        span.  Phases nest by containment on the one host thread."""
         return _Timed(self, phase, args)
 
 

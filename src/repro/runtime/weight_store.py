@@ -157,6 +157,8 @@ class WeightStore:
         self.prefetch = prefetch
         self.prefetch_dispatched = 0
         self.prefetch_used = 0
+        self.walks = 0              # materialize calls
+        self.walk_tiles = 0         # tile lookups those calls made
         self.telemetry = telemetry if telemetry is not None \
             else NULL_TELEMETRY
         self._models: dict[str, _ModelEntry] = {}
@@ -189,10 +191,12 @@ class WeightStore:
                 stack = w
             else:
                 return leaf
-            layers[name] = [
-                self._compress_tensor(f"{name}[{r}]", stack[r],
-                                      cluster=cluster)
-                for r in range(stack.shape[0])]
+            layers[name] = []
+            for r in range(stack.shape[0]):
+                with self.telemetry.timed("weights.compress",
+                                          layer=f"{name}[{r}]"):
+                    layers[name].append(self._compress_tensor(
+                        f"{name}[{r}]", stack[r], cluster=cluster))
             stacked[name] = w.ndim == 3
             # the uncompressed original is NOT retained: only its
             # shape/dtype stub stays in the serving tree skeleton
@@ -317,11 +321,13 @@ class WeightStore:
         names = list(entry.layers)
         pending: dict = {}
         rebuilt: dict = {}
+        self.walks += 1
         with self.telemetry.timed("weights.materialize", model=model_id):
             for i, name in enumerate(names):
                 stack = entry.layers[name]
                 fetched = [self._fetch_tiles(model_id, l, pending)
                            for l in stack]
+                self.walk_tiles += sum(len(tiles) for tiles, _ in fetched)
                 if self.prefetch and i + 1 < len(names):
                     for nxt in entry.layers[names[i + 1]]:
                         self._prefetch_layer(model_id, nxt, pending)
@@ -329,17 +335,18 @@ class WeightStore:
                         and name in entry.memo:
                     rebuilt[name] = entry.memo[name]
                     continue
-                arrs = [self._to_weights(l, tiles)
-                        for l, (tiles, _) in zip(stack, fetched)]
-                out = jnp.asarray(np.stack(arrs) if entry.stacked[name]
-                                  else arrs[0])
+                with self.telemetry.timed("weights.rebuild", layer=name):
+                    arrs = [self._to_weights(l, tiles)
+                            for l, (tiles, _) in zip(stack, fetched)]
+                    out = jnp.asarray(np.stack(arrs) if entry.stacked[name]
+                                      else arrs[0])
                 entry.memo[name] = out
                 rebuilt[name] = out
 
-        def sub(path, leaf):
-            return rebuilt.get(path_name(path), leaf)
+            def sub(path, leaf):
+                return rebuilt.get(path_name(path), leaf)
 
-        return jax.tree_util.tree_map_with_path(sub, entry.params)
+            return jax.tree_util.tree_map_with_path(sub, entry.params)
 
     def fused_operands(self, model_id: str, path: str, repeat: int = 0,
                        *, gather: str = "onehot", codes: int | None = None):
@@ -399,6 +406,10 @@ class WeightStore:
             ("prefetch_used_total", "counter",
              lambda: self.prefetch_used,
              "prefetched tile decodes consumed by a miss"),
+            ("walks_total", "counter", lambda: self.walks,
+             "materialize calls (weight walks)"),
+            ("walk_tiles_total", "counter", lambda: self.walk_tiles,
+             "tile lookups made by weight walks"),
         ]
 
     def report(self, model_id: str) -> dict:

@@ -4,10 +4,13 @@ span trees (every admitted request retires exactly once, spans nest,
 timestamps monotone), windowed stats-line semantics, token-identity
 with telemetry on vs off, and the capacity-autotune knee.
 
-Serving tests run the gathered backend (the pure-jnp oracle), so the
-whole file is tier-1 — no pallas marker.
+Serving tests run the gathered backend (the pure-jnp oracle), except
+``TestProfilerSpans``, which serves the ``pallas_paged`` mixed step
+(interpret mode) under the JAX profiler and reads the phase spans back
+from its host plane.
 """
 
+import glob
 import json
 import math
 
@@ -216,6 +219,15 @@ class TestNullPaths:
         assert ev["args"] == {"detail": 2}
         assert tel.phases["work"].n == 1
 
+    def test_annotate_adds_args_once_under_way(self):
+        tel = Telemetry(trace=True)
+        with tel.timed("work", detail=2) as span:
+            span.annotate(width=64)
+        (ev,) = tel.tracer.events
+        assert ev["args"] == {"detail": 2, "width": 64}
+        with NULL_TELEMETRY.timed("work") as span:
+            span.annotate(width=64)            # the null context ignores it
+
 
 # ---------------------------------------------------------------------------
 # windowed stats-line semantics
@@ -394,6 +406,113 @@ class TestServingSpans:
         assert "repro_cache_hits_total" in fams
         assert "repro_store_prefetch_dispatched_total" in fams
         assert any(f.startswith("repro_phase_") for f in fams)
+
+
+# ---------------------------------------------------------------------------
+# phase spans in a JAX profiler trace (pallas_paged mixed step)
+# ---------------------------------------------------------------------------
+
+STEP_CHILDREN = ("mixed_step.prepare", "mixed_step.dispatch",
+                 "mixed_step.wait", "mixed_step.commit")
+PROGRAM_SPANS = ("admit", "admit.walk", "mixed_step",
+                 "weights.materialize") \
+    + STEP_CHILDREN
+
+
+@pytest.fixture(scope="module")
+def profiled(reqs, tmp_path_factory):
+    """The mixed-step path served twice -- telemetry off, then telemetry
+    on under the JAX profiler -> (off tokens, on tokens, engine, tel,
+    the program's host spans [(name, start, end)] from the profile)."""
+    from jax.profiler import ProfileData
+    kw = dict(prefill_chunk=4, kv_page_size=8, attn_backend="pallas_paged")
+    _, off = serve(make_engine(), reqs, **kw)
+    tel = Telemetry()
+    engine = make_engine(telemetry=tel)
+    log_dir = tmp_path_factory.mktemp("profile")
+    with jax.profiler.trace(str(log_dir)):
+        _, on = serve(engine, reqs, **kw)
+    (path,) = glob.glob(str(log_dir / "**" / "*.xplane.pb"), recursive=True)
+    pd = ProfileData.from_file(path)
+    spans = [(ev.name, ev.start_ns, ev.end_ns)
+             for plane in pd.planes if plane.name == "/host:CPU"
+             for line in plane.lines for ev in line.events
+             if ev.name in PROGRAM_SPANS]
+    return off, on, engine, tel, spans
+
+
+def _inside(span, outers):
+    _, a, b = span
+    return any(oa <= a and b <= ob for _, oa, ob in outers)
+
+
+def test_store_walk_counters_count_every_tile():
+    """One add per walk and per layer, yet every tile of every walk is
+    counted: tensors of many tiles each."""
+    rng = np.random.default_rng(0)
+    params = {"mlp": {"up": rng.standard_normal((96, 256), np.float32),
+                      "down": rng.standard_normal((256, 96), np.float32)}}
+    store = WeightStore()
+    store.register_model("m", params)
+    n_tiles = store.n_tiles("m")
+    assert n_tiles > 2 * len(store.layers("m"))
+    for walks in (1, 2, 3):
+        store.materialize("m")
+        assert (store.walks, store.walk_tiles) == (walks, walks * n_tiles)
+
+
+@pytest.mark.pallas
+class TestProfilerSpans:
+    def test_tokens_identical_under_profiler(self, profiled):
+        off, on, _, _, _ = profiled
+        assert on == off
+
+    def test_step_children_nest_in_mixed_step(self, profiled):
+        _, _, _, tel, spans = profiled
+        steps = [s for s in spans if s[0] == "mixed_step"]
+        assert len(steps) == tel.phases["mixed_step"].n > 0
+        for name in STEP_CHILDREN:
+            mine = [s for s in spans if s[0] == name]
+            assert len(mine) == len(steps), name
+            assert all(_inside(s, steps) for s in mine), name
+        # each step's children run in order: prepare, dispatch, wait,
+        # commit
+        for _, a, b in steps:
+            kids = sorted((s for s in spans if s[0] in STEP_CHILDREN
+                           and a <= s[1] and s[2] <= b),
+                          key=lambda s: s[1])
+            assert tuple(k[0] for k in kids) == STEP_CHILDREN
+
+    def test_weight_walks_under_admit_and_mixed_step(self, profiled, reqs):
+        _, _, _, _, spans = profiled
+        walks = [s for s in spans if s[0] == "weights.materialize"]
+        admits = [s for s in spans if s[0] == "admit"]
+        steps = [s for s in spans if s[0] == "mixed_step"]
+        under_admit = [w for w in walks if _inside(w, admits)]
+        # each admission's walk also sits in its own admit.walk phase
+        admit_walks = [s for s in spans if s[0] == "admit.walk"]
+        assert len(admit_walks) == len(reqs)
+        assert all(_inside(w, admit_walks) for w in under_admit)
+        under_step = [w for w in walks if _inside(w, steps)]
+        assert len(under_admit) == len(reqs)      # one per admission
+        assert len(under_step) == len(steps)      # one per step
+        assert len(under_admit) + len(under_step) == len(walks)
+
+    def test_store_counts_walks_and_tiles(self, profiled, reqs):
+        _, _, engine, tel, _ = profiled
+        store = engine.store
+        assert store.walks == len(reqs) + tel.phases["mixed_step"].n
+        assert store.walk_tiles == store.walks * store.n_tiles(
+            engine.model_id)
+        # the engine's metrics window saw every walk (the store was
+        # built with this engine, so no walk predates the window)
+        assert (engine.metrics.weight_walks,
+                engine.metrics.weight_walk_tiles) == (store.walks,
+                                                      store.walk_tiles)
+        prom = parse_prom(engine.render_prom())
+        assert prom[("repro_store_walks_total", "")] == store.walks
+        assert prom[("repro_store_walk_tiles_total", "")] == \
+            store.walk_tiles
 
 
 # ---------------------------------------------------------------------------
