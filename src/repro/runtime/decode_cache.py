@@ -298,6 +298,17 @@ class DecodeTileCache:
     ``capacity_bytes=None`` means unbounded (serve everything from cache
     after first decode); ``0`` disables caching entirely (every access is a
     miss — the paper's no-cache baseline).
+
+    ``version`` counts mutations of the resident set: it goes up on every
+    ``put`` (eviction included) and ``clear``, never on a hit, so a caller
+    that saw the same ``version`` before knows every tile it found then
+    is still resident and unchanged.  ``WeightStore.materialize`` uses it
+    to serve an unchanged model without looking its tiles up again and
+    credits those hits in bulk (:meth:`record_hits`); the policy's
+    ``on_hit`` is then not called, so on an unbounded cache policy order
+    is not maintained across such memoised walks.  An unbounded cache
+    never asks its policy for a victim, so only ``keys()`` introspection
+    reads that order there.
     """
 
     def __init__(self, capacity_bytes: int | None = None,
@@ -311,6 +322,7 @@ class DecodeTileCache:
         self.bytes_streamed = 0
         self.bytes_avoided = 0
         self.resident_bytes = 0
+        self.version = 0
 
     # -- core --------------------------------------------------------------
     def get(self, key: TileKey):
@@ -333,6 +345,7 @@ class DecodeTileCache:
         are released before the new are charged, so updates never inflate
         ``resident_bytes`` (regression-tested)."""
         nbytes = int(getattr(value, "nbytes", 0) if nbytes is None else nbytes)
+        self.version += 1
         self.bytes_streamed += streamed_bytes
         old = self._entries.pop(key, None)
         if old is not None:
@@ -360,6 +373,13 @@ class DecodeTileCache:
         value = decode()
         self.put(key, value, nbytes=nbytes, streamed_bytes=streamed_bytes)
         return value, False
+
+    def record_hits(self, hits: int, bytes_avoided: int) -> None:
+        """Credit ``hits`` lookups that found their tiles resident, worth
+        ``bytes_avoided`` compressed bytes, without touching the policy
+        (the bulk form of that many hitting :meth:`get` calls)."""
+        self.hits += hits
+        self.bytes_avoided += bytes_avoided
 
     def seed_frequency(self, key: TileKey, weight: float) -> None:
         """Record a static frequency prior (``core.frequency`` occurrence
@@ -426,3 +446,4 @@ class DecodeTileCache:
         self._entries.clear()
         self.policy.clear()
         self.resident_bytes = 0
+        self.version += 1

@@ -12,9 +12,12 @@ Serving paths offered per registered layer:
   * :meth:`materialize` — rebuild the model's parameter pytree with every
     compressed tensor reconstructed as sign * per-channel-scale.  Tiles are
     fetched through the DecodeTileCache, so consecutive decode steps reuse
-    decoded tiles instead of re-decoding (the acceptance metric of PR 1).
-    The assembled device array is memoised and only rebuilt when at least
-    one of its tiles missed the cache.
+    decoded tiles instead of re-decoding.  The assembled device array is
+    memoised and only rebuilt when at least one of its tiles missed the
+    cache.  On an unbounded cache whose ``version`` has not moved since
+    the model's last full walk, the whole walk is memoised too: the
+    recorded params tree is returned with no tile lookup, and the hits
+    the walk would have made are credited to the cache in bulk.
   * :meth:`fused_operands` — device operands (words, tables, meta) for the
     fused decode+GEMM Pallas path (``kernels.ops.compressed_binary_matmul``),
     built from the *same* cached tiles so both paths are bit-identical.
@@ -121,6 +124,18 @@ class StoredLayer:
         return self.ct.n_seqs * huffman.SEQ_BITS // 8
 
 
+@dataclasses.dataclass(frozen=True)
+class _Walk:
+    """What a full walk of one model left: the cache ``version`` after it,
+    the params tree it returned, and the tiles it looked up with the
+    compressed bytes they stand for (what a walk of all hits credits)."""
+
+    version: int
+    tree: object
+    tiles: int
+    bytes: int
+
+
 @dataclasses.dataclass
 class _ModelEntry:
     params: dict
@@ -128,6 +143,7 @@ class _ModelEntry:
     stacked: dict[str, bool]              # tree path -> 3-d scan-stacked leaf
     memo: dict = dataclasses.field(default_factory=dict)
     fused_memo: dict = dataclasses.field(default_factory=dict)
+    walk: _Walk | None = None           # the last full walk
 
 
 @functools.partial(jax.jit, static_argnames=("c",))
@@ -159,6 +175,7 @@ class WeightStore:
         self.prefetch_used = 0
         self.walks = 0              # materialize calls
         self.walk_tiles = 0         # tile lookups those calls made
+        self.memo_walks = 0         # calls served by the memoised walk
         self.telemetry = telemetry if telemetry is not None \
             else NULL_TELEMETRY
         self._models: dict[str, _ModelEntry] = {}
@@ -307,10 +324,19 @@ class WeightStore:
     def materialize(self, model_id: str):
         """Serving params: compressed leaves rebuilt from cached tiles.
 
-        Call once per decode step; after the first step every tile is a
-        cache hit and the memoised device arrays are returned as-is (the
-        hit path only touches the cache for accounting — no bit unpack,
-        reconstruction, or host->device transfer is repeated).
+        Call once per decode step.  A full walk looks every tile up in
+        the cache; a layer whose tiles all hit returns its memoised
+        device array, so no bit unpack, reconstruction or host->device
+        transfer is repeated.
+
+        On an unbounded cache (``capacity_bytes is None``) whose
+        ``version`` is the one the model's last full walk left, every
+        tile of that walk is still resident and every layer memoised, so
+        the walk is memoised whole: the recorded tree is returned as-is
+        and the cache is credited the walk's hits and avoided bytes in
+        one call (``memo_walks`` counts these; they add nothing to
+        ``walk_tiles``).  A bounded cache always takes the full walk,
+        since its per-tile hits order the eviction policy.
 
         Layers are processed in registration order; with ``prefetch`` on,
         layer i+1's missing tile decodes are dispatched right after layer
@@ -318,11 +344,19 @@ class WeightStore:
         weights are reconstructed host-side.
         """
         entry = self._models[model_id]
+        walk = entry.walk
+        memo = (walk is not None and self.cache.capacity_bytes is None
+                and walk.version == self.cache.version)
         names = list(entry.layers)
         pending: dict = {}
         rebuilt: dict = {}
         self.walks += 1
-        with self.telemetry.timed("weights.materialize", model=model_id):
+        with self.telemetry.timed("weights.materialize", model=model_id,
+                                  memo=memo):
+            if memo:
+                self.memo_walks += 1
+                self.cache.record_hits(walk.tiles, walk.bytes)
+                return walk.tree
             for i, name in enumerate(names):
                 stack = entry.layers[name]
                 fetched = [self._fetch_tiles(model_id, l, pending)
@@ -346,7 +380,14 @@ class WeightStore:
             def sub(path, leaf):
                 return rebuilt.get(path_name(path), leaf)
 
-            return jax.tree_util.tree_map_with_path(sub, entry.params)
+            tree = jax.tree_util.tree_map_with_path(sub, entry.params)
+            if self.cache.capacity_bytes is None:
+                # an unbounded cache kept every tile this walk looked up
+                entry.walk = _Walk(
+                    self.cache.version, tree, self.n_tiles(model_id),
+                    sum(l.tiled.n_tiles * l.tile_compressed_bytes()
+                        for ls in entry.layers.values() for l in ls))
+            return tree
 
     def fused_operands(self, model_id: str, path: str, repeat: int = 0,
                        *, gather: str = "onehot", codes: int | None = None):
@@ -410,6 +451,8 @@ class WeightStore:
              "materialize calls (weight walks)"),
             ("walk_tiles_total", "counter", lambda: self.walk_tiles,
              "tile lookups made by weight walks"),
+            ("memo_walks_total", "counter", lambda: self.memo_walks,
+             "weight walks served whole from the memoised tree"),
         ]
 
     def report(self, model_id: str) -> dict:

@@ -10,8 +10,8 @@ import pytest
 from repro.core import compression
 from repro.kernels import ops
 from repro.runtime import (DecodeTileCache, Scheduler, ServeEngine,
-                           WeightStore)
-from repro.runtime.decode_cache import POLICIES
+                           Telemetry, WeightStore)
+from repro.runtime.decode_cache import POLICIES, LRUPolicy
 from tests.test_models import reduced
 
 
@@ -224,6 +224,122 @@ class TestWeightStore:
         store.materialize("b")
         assert cache.misses == store.n_tiles("a") + store.n_tiles("b")
         assert cache.hits == 0
+
+
+class _CountingLRU(LRUPolicy):
+    """LRU that counts the hits it is told of (the per-tile touches that
+    order a bounded cache's evictions)."""
+
+    def __init__(self):
+        super().__init__()
+        self.touches = 0
+
+    def on_hit(self, key):
+        self.touches += 1
+        super().on_hit(key)
+
+
+class TestMemoisedWalk:
+    """``materialize`` on an unbounded cache whose ``version`` has not
+    moved since the model's last full walk returns that walk's tree and
+    credits its hits in bulk; every other walk looks every tile up."""
+
+    def test_unchanged_unbounded_cache_serves_the_recorded_tree(self, rng):
+        tel = Telemetry(trace=True)
+        cache = DecodeTileCache()
+        store, _ = make_store(rng, layers=2, cache=cache)
+        store.telemetry = tel
+        store.materialize("m")                 # every tile misses once
+        n, version = store.n_tiles("m"), cache.version
+        first = store.materialize("m")
+        second = store.materialize("m")
+        assert second is first and cache.version == version
+        assert (store.walks, store.walk_tiles, store.memo_walks) == \
+            (3, n, 2)
+        assert (cache.hits, cache.misses) == (2 * n, n)
+        assert cache.bytes_avoided == 2 * cache.bytes_streamed > 0
+        assert [e["args"]["memo"] for e in tel.tracer.events
+                if e["name"] == "weights.materialize"] == [False, True, True]
+
+    @pytest.mark.parametrize("mutation", ["other_model_miss", "clear",
+                                          "put"])
+    def test_any_mutation_forces_a_full_walk(self, rng, mutation):
+        cache = DecodeTileCache()
+        store, _ = make_store(rng, layers=2, cache=cache)
+        store.register_model("other", {"mlp": {"up": rng.standard_normal(
+            (36, 64)).astype(np.float32)}})
+        n = store.n_tiles("m")
+        store.materialize("m")
+        memo = store.materialize("m")
+        assert store.memo_walks == 1
+        if mutation == "other_model_miss":
+            store.materialize("other")
+        elif mutation == "clear":
+            cache.clear()
+        else:
+            cache.put(("m", "stray", 0), np.zeros(4, np.int32))
+        tiles = store.walk_tiles
+        full = store.materialize("m")
+        assert store.walk_tiles - tiles == n and store.memo_walks == 1
+        assert full is not memo                # the tree is built anew
+        for a, b in zip(jax.tree_util.tree_leaves(memo),
+                        jax.tree_util.tree_leaves(full)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            # after clear() every tile missed and every array is rebuilt
+            assert (a is b) == (mutation != "clear")
+        assert store.materialize("m") is full  # memoised again
+        assert store.memo_walks == 2
+
+    @pytest.mark.parametrize("fraction", [0.0, 0.5, 2.0])
+    def test_bounded_cache_walks_every_tile(self, rng, fraction):
+        """Capacity 0, a thrashing bound and one above the working set:
+        every walk looks every tile up and tells the policy of each hit,
+        so eviction order is what the per-tile walk makes."""
+        policy = _CountingLRU()
+        cache = DecodeTileCache(policy=policy)
+        store, _ = make_store(rng, layers=2, cache=cache)
+        cache.capacity_bytes = int(store.decoded_bytes("m") * fraction)
+        n = store.n_tiles("m")
+        for walks in (1, 2, 3):
+            store.materialize("m")
+            assert cache.accesses == walks * n
+            assert store.walk_tiles == walks * n
+        assert store.memo_walks == 0
+        assert policy.touches == cache.hits
+        if fraction > 1:
+            assert cache.hits == 2 * n and cache.evictions == 0
+
+    def test_served_tokens_and_cache_stats_match_a_bounded_cache(self):
+        """The same requests through an unbounded cache (memoised walks)
+        and a bounded one larger than the working set (per-tile walks):
+        identical tokens and identical hit, miss and byte accounting."""
+        cfg = reduced("minitron-8b")
+        params = jax.tree_util.tree_map(
+            np.asarray,
+            __import__("repro.models.api", fromlist=["get_model"])
+            .get_model(cfg).init_params(cfg, jax.random.PRNGKey(0)))
+        rng = np.random.default_rng(7)
+        reqs = [(rng.integers(0, cfg.vocab_size, L), g)
+                for L, g in [(5, 6), (9, 3), (4, 8)]]
+        served = {}
+        cap = None
+        for bound in (False, True):
+            engine = ServeEngine(cfg, params, compress=True,
+                                 cache_bytes=cap)
+            cap = 2 * engine.store.decoded_bytes(engine.model_id)
+            sched = Scheduler(engine, batch_size=2, buckets=(16,))
+            rids = [sched.submit(p, g).rid for p, g in reqs]
+            done = {r.rid: tuple(r.generated) for r in sched.run()}
+            stats = engine.cache.stats()
+            served[bound] = ([done[r] for r in rids],
+                             {k: stats[k] for k in ("hits", "misses",
+                                                    "bytes_streamed",
+                                                    "bytes_avoided")},
+                             engine.store.memo_walks)
+        (toks, stats, memo), (btoks, bstats, bmemo) = \
+            served[False], served[True]
+        assert toks == btoks and stats == bstats
+        assert memo > 0 and bmemo == 0
 
 
 class TestScheduler:

@@ -447,8 +447,10 @@ def _inside(span, outers):
 
 
 def test_store_walk_counters_count_every_tile():
-    """One add per walk and per layer, yet every tile of every walk is
-    counted: tensors of many tiles each."""
+    """One add per walk and per layer, yet every tile the first walk
+    looks up is counted (tensors of many tiles each); on the unchanged
+    unbounded cache the later walks are memoised, look nothing up and
+    count in ``memo_walks``.  Prometheus reads the same counters."""
     rng = np.random.default_rng(0)
     params = {"mlp": {"up": rng.standard_normal((96, 256), np.float32),
                       "down": rng.standard_normal((256, 96), np.float32)}}
@@ -458,7 +460,12 @@ def test_store_walk_counters_count_every_tile():
     assert n_tiles > 2 * len(store.layers("m"))
     for walks in (1, 2, 3):
         store.materialize("m")
-        assert (store.walks, store.walk_tiles) == (walks, walks * n_tiles)
+        assert (store.walks, store.walk_tiles, store.memo_walks) == \
+            (walks, n_tiles, walks - 1)
+    prom = parse_prom(ServeMetrics().render_prom(store=store))
+    assert prom[("repro_store_walks_total", "")] == 3
+    assert prom[("repro_store_walk_tiles_total", "")] == n_tiles
+    assert prom[("repro_store_memo_walks_total", "")] == 2
 
 
 @pytest.mark.pallas
@@ -502,8 +509,11 @@ class TestProfilerSpans:
         _, _, engine, tel, _ = profiled
         store = engine.store
         assert store.walks == len(reqs) + tel.phases["mixed_step"].n
-        assert store.walk_tiles == store.walks * store.n_tiles(
-            engine.model_id)
+        # the unbounded cache changes only in the first walk (all
+        # misses): that walk looks every tile up, every later one is
+        # memoised and looks up none
+        assert store.walk_tiles == store.n_tiles(engine.model_id)
+        assert store.memo_walks == store.walks - 1
         # the engine's metrics window saw every walk (the store was
         # built with this engine, so no walk predates the window)
         assert (engine.metrics.weight_walks,
@@ -513,6 +523,8 @@ class TestProfilerSpans:
         assert prom[("repro_store_walks_total", "")] == store.walks
         assert prom[("repro_store_walk_tiles_total", "")] == \
             store.walk_tiles
+        assert prom[("repro_store_memo_walks_total", "")] == \
+            store.memo_walks
 
 
 # ---------------------------------------------------------------------------
