@@ -54,30 +54,50 @@ shape and not the arithmetic.  Feature dims padded toward the (8, 128)
 sublane/lane tiles by ``SlotPool`` are never read: the kernel slices
 each page to its logical rows and the query's width.
 
-Mosaic shape rules: the body uses only 2-D operations.  Each query head
-and each KV head is read out of its block by a strided load, scored as
-one (qb, D) x (D, page) product, and accumulated into per-head VMEM
-scratch; no value is reshaped across the sublane axis, which the TPU
-compiler refuses (``infer-vector-layout: unsupported shape cast``).
+Query heads as rows: the ``g = H / KH`` query heads of one KV head share
+one product per page.  The queries are laid out before the call as a 2-D
+``(Q * g, D)`` operand per slot and KV head -- row ``t * g + i`` is head
+``kv * g + i`` of token ``t`` -- so a q block is ``qb * g`` rows, scored
+in one ``(qb * g, D) x (D, page)`` product and folded into the online
+softmax with one ``(qb * g, page) x (page, Dv)`` product, ``KH`` of each
+per page.  For GQA ``qb`` is sized from the shapes (:func:`gqa_q_block`):
+all of Q while ``Q * g`` rows fit about one MXU pass, else the most
+tokens whose rows do and are a multiple of the 8-row sublane tile; the
+last q block may then run past Q, and its rows are padding.
 
-The optional second score operand ``(q2, k2_pages)`` serves MLA absorbed
-attention: scores are ``q . k + q2 . k2`` (latent + rope parts) over a
-single shared KV head, and the latent key pool is the value pool too
-(``v_pages=None``, one DMA per page).  ``scale`` is applied to the summed
-scores (MLA) — GQA callers pre-scale ``q`` and leave it at 1.0, matching
+Shared latent head (MLA): the optional second score operand ``(q2,
+k2_pages)`` serves absorbed attention over one KV head that all ``H``
+query heads share: scores are ``q . k + q2 . k2`` (latent + rope parts)
+and the latent key pool is the value pool too (``v_pages=None``, one DMA
+per page).  ``qb`` is the largest divisor of Q whose ``qb * H`` rows fit
+:data:`VMEM_BUDGET` (:func:`latent_q_block`; 128 heads x 576 wide: 4 at
+a 16-token chunk, 1 at decode).  ``scale`` is applied to the summed
+scores (MLA) -- GQA callers pre-scale ``q`` and leave it at 1.0, matching
 ``attention.decode_attention``'s operation order exactly.
 
-Shared latent head (MLA: one KV head, the second score operand): every
-query head of a q block is scored in ONE product per page.  The queries
-arrive as a 2-D ``(Q * H, D)`` operand per slot -- row ``t * H + h`` is
-head ``h`` of token ``t``, laid out before the call so no reshape crosses
-sublanes in the kernel -- and a q block is ``qb * H`` rows against the
-page's ``(page, D)`` latent rows.  ``qb`` is the largest divisor of Q
-whose blocks and scratch fit :data:`LATENT_VMEM_BUDGET` (128 heads x 576
-wide: ``qb`` 4 at a 16-token chunk, 1 at decode).  A q block or a page
-that holds no real query or key of its slot skips its arithmetic (the
-grid step still runs): a page past ``lengths[s]`` adds exactly zero to a
-real row's softmax once the slot's first page has been folded in.
+Mosaic shape rules: the body uses only 2-D operations.  Each KV head is
+read out of its page block by a strided load and each KV head's query
+rows out of the q block by a leading-axis index; softmax state is kept
+per KV head in VMEM scratch.  No value is reshaped across the sublane
+axis, which the TPU compiler refuses (``infer-vector-layout:
+unsupported shape cast``).
+
+Dead grid steps: a grid step whose q block holds no real query
+(``qb_idx * qb >= q_lens[s]``), or whose pages hold no key a real query
+of the block may see (a page starting at or past ``lengths[s]``, or,
+under a window, one that ends before the block's first query's window)
+does no arithmetic; :func:`live_grid_steps` counts the others on the
+host, for the launch each call records on its kernel
+(:func:`kernel_launches` reads them back from a traced program).  The
+grid step still runs, and its page index maps read the table as it
+stands: past a slot's length every entry names the dummy sink, one
+block for all of the slot's dead pages, so Pallas starts no DMA for
+them; a dead q block, and under a window a page before it, still fetch
+their pages, which costs less than the scalar work of clamping the
+maps to the live pages.  Skipping is exact: a page every row of the
+block masks adds exactly zero to a real row's online softmax once a page
+holding one of its keys has been folded in, and a real row's own
+position always lies in a live page.
 
 Compressed pages (``kv_codec="cluster"``): when ``k_scales``/``v_scales``
 are passed the pools hold int8 codebook indices and each page is decoded
@@ -95,21 +115,27 @@ interpreter on CPU — how CI exercises it (same convention as
 
 from __future__ import annotations
 
+import collections
 import functools
 import math
+from typing import NamedTuple
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import kv_codec
 
 NEG_INF = -1e30
-# scoped VMEM the shared-latent kernel's per-row blocks and scratch may
-# take: v5e's default scoped limit is 16 MiB, and the page blocks and the
-# compiler's own scratch need the rest
-LATENT_VMEM_BUDGET = 8 * 2**20
+# scoped VMEM a q block's per-row blocks and scratch may take: v5e's
+# default scoped limit is 16 MiB, and the page blocks and the compiler's
+# own scratch need the rest
+VMEM_BUDGET = 8 * 2**20
+# query rows a GQA q block aims at: one pass of v5e's 128 x 128 MXU
+GQA_BLOCK_ROWS = 128
 
 
 def effective_q_block(qn: int, q_block: int) -> int:
@@ -122,26 +148,144 @@ def effective_q_block(qn: int, q_block: int) -> int:
     return math.gcd(qn, q_block) if q_block else qn
 
 
-def latent_row_bytes(wq: int, w2: int, page: int) -> int:
-    """VMEM bytes one query row of the shared-latent kernel takes: its
-    f32 query and rope-query rows and output row (as wide as the query:
-    the latent is the value), double-buffered, the accumulator row, the
-    running max and normaliser (a lane tile each), and a score and a
-    probability row of the page."""
+def q_row_bytes(kh: int, d: int, wv: int, w2: int, page: int) -> int:
+    """VMEM bytes one query row of a q block takes: per KV head its f32
+    query row (and, under MLA, rope-query row ``w2`` wide) and output
+    row, double-buffered, its accumulator row and its running max and
+    normaliser (a lane tile each); and a score and a probability row of
+    the page."""
     lanes = -(-page // 128) * 128
-    return 4 * (2 * (wq + w2) + 2 * wq + wq + 2 * 128 + 2 * lanes)
+    return 4 * (kh * (2 * (d + w2) + 2 * wv + wv + 2 * 128) + 2 * lanes)
 
 
 def latent_q_block(qn: int, q_block: int, h: int, wq: int, w2: int,
                    page: int) -> int:
-    """Query tokens per grid step of the shared-latent kernel: the
+    """Query tokens per grid step over the shared latent head (MLA): the
     largest divisor of ``Q`` at or below the requested block (0 = all of
-    Q) whose ``qb * h`` rows fit :data:`LATENT_VMEM_BUDGET`."""
+    Q) whose ``qb * h`` rows fit :data:`VMEM_BUDGET`; the latent is the
+    value, so the output rows are ``wq`` wide."""
     qb = effective_q_block(qn, q_block)
-    per_token = h * latent_row_bytes(wq, w2, page)
-    while qb > 1 and qb * per_token > LATENT_VMEM_BUDGET:
+    per_token = h * q_row_bytes(1, wq, wq, w2, page)
+    while qb > 1 and qb * per_token > VMEM_BUDGET:
         qb = max(d for d in range(1, qb) if qn % d == 0)
     return qb
+
+
+def gqa_q_block(qn: int, q_block: int, g: int, kh: int, d: int, wv: int,
+                page: int) -> int:
+    """Query tokens per grid step of the GQA kernel, whose q block is
+    ``qb * g`` rows per KV head.  An explicit ``q_block`` keeps
+    :func:`effective_q_block`'s meaning where the chip takes its rows (a
+    multiple of the 8-row sublane tile, or all of ``Q * g``); a block it
+    would refuse falls back to the one sized from the shapes.  That is
+    all of Q while ``Q * g`` rows fit :data:`GQA_BLOCK_ROWS` and
+    :data:`VMEM_BUDGET`; else the most tokens whose rows fit both and
+    are a multiple of the sublane tile, which need not divide Q."""
+    if q_block:
+        qb = effective_q_block(qn, q_block)
+        if qb == qn or qb * g % 8 == 0:
+            return qb
+    rows = min(GQA_BLOCK_ROWS,
+               VMEM_BUDGET // q_row_bytes(kh, d, wv, 0, page))
+    if qn * g <= rows:
+        return qn
+    step = 8 // math.gcd(8, g)
+    return max(step, rows // g // step * step)
+
+
+def _live_pages(length, qlen, first, *, logical: int, window: int,
+                maximum=jnp.maximum):
+    """The logical pages ``[lo, hi]`` that hold a key some real query of
+    a q block may see, the block's first token being token ``first`` of
+    the slot's ``qlen``: none at or past ``length``, and under a window
+    none that ends before the first query's window opens.  The one rule
+    of the kernel's skips and :func:`live_grid_steps`; ``maximum`` is
+    ``np.maximum`` on the host."""
+    hi = (length + logical - 1) // logical - 1
+    if not window:
+        return 0, hi
+    opens = length - qlen + first - window + 1      # earliest key it sees
+    return maximum(opens, 0) // logical, hi
+
+
+def live_grid_steps(lengths, q_lens, *, qn: int, qb: int, n_pages: int,
+                    logical: int, window: int = 0,
+                    pages_per_step: int = 1) -> tuple[int, int]:
+    """(walked, live) grid steps of one :func:`paged_mixed_attention`
+    call over slots of ``lengths`` and ``q_lens`` (host arrays), ``Q =
+    qn`` tokens in q blocks of ``qb``, page tables of ``n_pages``
+    logical pages of ``logical`` tokens: all the steps its grid runs, and
+    those that compute -- a q block holding a real query and a group of
+    ``pages_per_step`` pages of which one is live by :func:`_live_pages`.
+    """
+    lengths = np.asarray(lengths, np.int64)[:, None]
+    q_lens = np.asarray(q_lens, np.int64)[:, None]
+    c = max(int(pages_per_step), 1)
+    nqb = -(-qn // qb)
+    first = np.arange(nqb)[None] * qb
+    lo, hi = _live_pages(lengths, q_lens, first, logical=logical,
+                         window=window, maximum=np.maximum)
+    groups = np.maximum(hi // c - np.asarray(lo) // c + 1, 0)
+    live = int(np.where(first < q_lens, groups, 0).sum())
+    return lengths.shape[0] * nqb * -(-n_pages // c), live
+
+
+class Launch(NamedTuple):
+    """One :func:`paged_mixed_attention` call as the kernel resolved it:
+    :func:`live_grid_steps`'s keywords, and the ``q_block`` the caller
+    asked for (0 = sized from the shapes)."""
+    qn: int
+    qb: int
+    n_pages: int
+    logical: int
+    window: int
+    pages_per_step: int
+    q_block: int
+
+    @property
+    def rounded(self) -> bool:
+        """The asked block did not run as asked: a non-divisor rounded
+        down to ``gcd(Q, q_block)`` below Q, or a block the chip refuses
+        replaced by the one sized from the shapes."""
+        if not self.q_block:
+            return False
+        eff = effective_q_block(self.qn, self.q_block)
+        return eff not in (self.q_block, self.qn) or self.qb != eff
+
+    def grid_steps(self, lengths, q_lens) -> tuple[int, int]:
+        """(walked, live) grid steps of this call over slots of
+        ``lengths`` and ``q_lens`` (:func:`live_grid_steps`)."""
+        kw = self._asdict()
+        del kw["q_block"]
+        return live_grid_steps(lengths, q_lens, **kw)
+
+
+def kernel_launches(jaxpr) -> collections.Counter:
+    """The :func:`paged_mixed_attention` calls a traced program makes: a
+    Counter of :class:`Launch` over the times each runs per execution,
+    read from the launch each call records on its kernel.  A call under
+    a ``scan`` counts once per iteration; one under a ``cond`` once per
+    branch."""
+    out: collections.Counter = collections.Counter()
+
+    def walk(jx, times):
+        for eqn in jx.eqns:
+            if eqn.primitive.name == "pallas_call":
+                if eqn.params["name"] == "paged_mixed_attention":
+                    out[Launch(**{k: int(v) for k, v in
+                                  eqn.params["metadata"].items()})] += times
+                continue
+            n = times * eqn.params["length"] \
+                if eqn.primitive.name == "scan" else times
+            for v in eqn.params.values():
+                for sub in v if isinstance(v, (tuple, list)) else (v,):
+                    if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                        walk(sub.jaxpr, n)
+                    elif isinstance(sub, jax.extend.core.Jaxpr):
+                        walk(sub, n)
+
+    walk(getattr(jaxpr, "jaxpr", jaxpr), 1)
+    return out
 
 
 def _page_operand(ref, scale_ref, half, kv_head: int, rows: int,
@@ -185,110 +329,48 @@ def _dot(a, b, contract_b: int):
         preferred_element_type=jnp.float32)[0]
 
 
+def _row_token(r, g: int, n: int):
+    """``r // g`` for the row indices ``0 <= r < n * g``, without a vector
+    division: a shift when ``g`` is a power of two, else ``n - 1``
+    compares."""
+    if g & (g - 1) == 0:
+        return r >> (g.bit_length() - 1)
+    tok = jnp.zeros_like(r)
+    for t in range(1, n):
+        tok = tok + (r >= t * g).astype(jnp.int32)
+    return tok
+
+
 def _kernel(table_ref, len_ref, qlen_ref, q_ref, *rest,
             logical: int, c: int, kh: int, g: int, qb: int, widths: tuple,
             window: int, softcap_val: float, scale: float,
-            has_codec: bool):
-    wk, wv = widths
-    i = 0
-    k_refs = rest[i:i + c]
-    i += c
-    v_refs = rest[i:i + c]
-    i += c
-    ks_refs = vs_refs = (None,) * c
+            has_codec: bool, latent: bool):
+    """One grid step: per KV head, its ``qb * g`` query rows (token-major,
+    row ``t * g + i`` is head ``kv * g + i``) against ``c`` pages, one
+    score product and one value product per KV head and page; no
+    arithmetic on a dead q block or page (:func:`_live_pages`).  Under
+    ``latent`` (MLA) the second operand adds ``q2 . k2`` to the scores
+    and the key page is the value page too."""
+    wk, wv, w2 = widths
+    refs = iter(rest)
+
+    def take(n):
+        return tuple(next(refs) for _ in range(n))
+
+    k_refs = take(c)
+    q2_ref = next(refs) if latent else None
+    # the k2 pages under ``latent``, else the value pages
+    x_refs = take(c)
+    ks_refs = xs_refs = (None,) * c
+    half_ref = None
     if has_codec:
-        ks_refs = rest[i:i + c]
-        i += c
-        vs_refs = rest[i:i + c]
-        i += c
-        half = jnp.broadcast_to(rest[i][...], (logical, rest[i].shape[-1]))
-        i += 1
-    else:
-        half = None
-    o_ref, m_ref, l_ref, acc_ref = rest[i:]
+        ks_refs, xs_refs = take(c), take(c)
+        half_ref = next(refs)
+    o_ref, m_ref, l_ref, acc_ref = take(4)
     s_idx = pl.program_id(0)
     qb_idx = pl.program_id(1)
     j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # query token i of this block sits at absolute position
-    # lengths[s] - q_lens[s] + (qb_idx * qb + i); tokens past q_lens[s]
-    # are ragged padding and attend nothing.  Key row r of page i of this
-    # group is logical position (j * c + i) * logical + r; rows at or
-    # past the logical page length are layout padding and never read.
-    length = len_ref[s_idx]
-    qlen = qlen_ref[s_idx]
-    qi = qb_idx * qb + jax.lax.broadcasted_iota(jnp.int32, (qb, logical), 0)
-    qpos = (length - qlen) + qi
-    row = jax.lax.broadcasted_iota(jnp.int32, (qb, logical), 1)
-
-    # Every query head is its own 2-D (qb, D) x (D, page) product against
-    # its KV head's page, and each page of the group is folded into the
-    # online softmax in turn -- so the result does not depend on how many
-    # pages one grid step carries.
-    for pi in range(c):
-        gpos = (j * c + pi) * logical + row
-        valid = (gpos <= qpos) & (qi < qlen)
-        if window:
-            valid &= gpos > qpos - window
-        for kv in range(kh):
-            k = _page_operand(k_refs[pi], ks_refs[pi], half, kv, logical, wk)
-            v = _page_operand(v_refs[pi], vs_refs[pi], half, kv, logical, wv)
-            for h in range(kv * g, (kv + 1) * g):
-                s = _dot(q_ref[0, :, h, :].astype(jnp.float32), k, 1)
-                if scale != 1.0:
-                    s = s * scale
-                if softcap_val:
-                    s = jnp.tanh(s / softcap_val) * softcap_val
-                s = jnp.where(valid, s, NEG_INF)
-                m_prev = m_ref[h]                              # (qb, 1)
-                m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-                alpha = jnp.exp(m_prev - m_new)
-                p = jnp.exp(s - m_new)
-                l_ref[h] = l_ref[h] * alpha + p.sum(-1, keepdims=True)
-                acc_ref[h] = acc_ref[h] * alpha + _dot(p, v, 0)
-                m_ref[h] = m_new
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _done():
-        for h in range(kh * g):
-            o_ref[0, :, h, :] = acc_ref[h] / jnp.maximum(l_ref[h], 1e-20)
-
-
-def _latent_kernel(table_ref, len_ref, qlen_ref, q_ref, *rest,
-                   logical: int, c: int, h: int, qb: int, widths: tuple,
-                   scale: float, has_codec: bool):
-    """The shared-latent grid step: ``qb * h`` query rows (token-major,
-    ``t * h + head``) against ``c`` pages of the one KV head, one score
-    product and one value product per page for every head at once.  The
-    latent page is the value operand too."""
-    wk, w2 = widths
-    i = 0
-    k_refs = rest[i:i + c]
-    i += c
-    q2_ref = rest[i]
-    i += 1
-    k2_refs = rest[i:i + c]
-    i += c
-    ks_refs = k2s_refs = (None,) * c
-    half = None
-    if has_codec:
-        ks_refs = rest[i:i + c]
-        i += c
-        k2s_refs = rest[i:i + c]
-        i += c
-        half = jnp.broadcast_to(rest[i][...], (logical, rest[i].shape[-1]))
-        i += 1
-    o_ref, m_ref, l_ref, acc_ref = rest[i:]
-    s_idx = pl.program_id(0)
-    qb_idx = pl.program_id(1)
-    j = pl.program_id(2)
-    rows = qb * h
+    rows = qb * g
 
     @pl.when(j == 0)
     def _init():
@@ -298,42 +380,59 @@ def _latent_kernel(table_ref, len_ref, qlen_ref, q_ref, *rest,
 
     length = len_ref[s_idx]
     qlen = qlen_ref[s_idx]
-    # row r is head r % h of token qb_idx * qb + r // h; the token index
-    # is counted with compares, not a vector division
-    r = jax.lax.broadcasted_iota(jnp.int32, (rows, logical), 0)
-    tok = jnp.zeros_like(r)
-    for t in range(1, qb):
-        tok = tok + (r >= t * h).astype(jnp.int32)
-    qi = qb_idx * qb + tok
-    qpos = (length - qlen) + qi
-    col = jax.lax.broadcasted_iota(jnp.int32, (rows, logical), 1)
+    first = qb_idx * qb
+    lo, hi = _live_pages(length, qlen, first, logical=logical, window=window)
 
-    @pl.when(qb_idx * qb < qlen)
+    @pl.when(first < qlen)
     def _block():
-        q = q_ref[0].astype(jnp.float32)                    # (rows, wk)
-        q2 = q2_ref[0].astype(jnp.float32)                  # (rows, w2)
+        # row r holds token first + r // g of the block, at absolute
+        # position lengths[s] - q_lens[s] + that; tokens past q_lens[s]
+        # are ragged padding and attend nothing.  Key row r of page i of
+        # this group is logical position (j * c + i) * logical + r; rows
+        # at or past the logical page length are layout padding and never
+        # read.
+        r = jax.lax.broadcasted_iota(jnp.int32, (rows, logical), 0)
+        qi = first + _row_token(r, g, qb)
+        qpos = (length - qlen) + qi
+        col = jax.lax.broadcasted_iota(jnp.int32, (rows, logical), 1)
+        half = None if half_ref is None else jnp.broadcast_to(
+            half_ref[...], (logical, half_ref.shape[-1]))
+        # each page of the group is folded into the online softmax in
+        # turn, so the result does not depend on how many pages one grid
+        # step carries
         for pi in range(c):
-            start = (j * c + pi) * logical
+            lp = j * c + pi
 
-            @pl.when(start < length)
-            def _page(pi=pi, start=start):
-                gpos = start + col
+            @pl.when((lp >= lo) & (lp <= hi))
+            def _page(pi=pi, lp=lp):
+                gpos = lp * logical + col
                 valid = (gpos <= qpos) & (qi < qlen)
-                k = _page_operand(k_refs[pi], ks_refs[pi], half, 0,
-                                  logical, wk)
-                k2 = _page_operand(k2_refs[pi], k2s_refs[pi], half, 0,
-                                   logical, w2)
-                s = _dot(q, k, 1) + _dot(q2, k2, 1)
-                if scale != 1.0:
-                    s = s * scale
-                s = jnp.where(valid, s, NEG_INF)
-                m_prev = m_ref[...]                          # (rows, 1)
-                m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-                alpha = jnp.exp(m_prev - m_new)
-                p = jnp.exp(s - m_new)
-                l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
-                acc_ref[...] = acc_ref[...] * alpha + _dot(p, k, 0)
-                m_ref[...] = m_new
+                if window:
+                    valid &= gpos > qpos - window
+                for kv in range(kh):
+                    k = _page_operand(k_refs[pi], ks_refs[pi], half, kv,
+                                      logical, wk)
+                    s = _dot(q_ref[0, kv].astype(jnp.float32), k, 1)
+                    if latent:
+                        k2 = _page_operand(x_refs[pi], xs_refs[pi], half,
+                                           0, logical, w2)
+                        s = s + _dot(q2_ref[0, 0].astype(jnp.float32), k2, 1)
+                        v = k
+                    else:
+                        v = _page_operand(x_refs[pi], xs_refs[pi], half, kv,
+                                          logical, wv)
+                    if scale != 1.0:
+                        s = s * scale
+                    if softcap_val:
+                        s = jnp.tanh(s / softcap_val) * softcap_val
+                    s = jnp.where(valid, s, NEG_INF)
+                    m_prev = m_ref[kv]                       # (rows, 1)
+                    m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+                    alpha = jnp.exp(m_prev - m_new)
+                    p = jnp.exp(s - m_new)
+                    l_ref[kv] = l_ref[kv] * alpha + p.sum(-1, keepdims=True)
+                    acc_ref[kv] = acc_ref[kv] * alpha + _dot(p, v, 0)
+                    m_ref[kv] = m_new
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _done():
@@ -361,9 +460,10 @@ def paged_mixed_attention(
     window: int = 0,
     softcap_val: float = 0.0,
     scale: float = 1.0,
-    q_block: int = 0,        # 0 = whole Q per grid step; non-divisors
-    #                          round down to gcd(Q, q_block), same
-    #                          convention as flash_attention's q_chunk
+    q_block: int = 0,        # 0 = sized from the shapes (gqa_q_block /
+    #                          latent_q_block); else gcd(Q, q_block), the
+    #                          convention of flash_attention's q_chunk,
+    #                          where the chip takes its rows
     page_size: int = 0,      # logical tokens per page; 0 = the pools'
     #                          physical page dim (i.e. no row padding)
     pages_per_step: int = 1,  # physical pages DMA'd per grid step
@@ -394,21 +494,23 @@ def paged_mixed_attention(
     """
     s_n, qn, h, d = q.shape
     n_pages, page, kh, dk = k_pages.shape
-    if q2 is not None:
+    latent = q2 is not None
+    if latent:
         # MLA: one shared latent head, which is the value pool too
         assert kh == 1 and v_pages is None and v_scales is None, (kh,)
         assert not window and not softcap_val, (window, softcap_val)
-        return _latent_attention(
-            q, k_pages, table, lengths, q_lens, q2, k2_pages, k_scales,
-            k2_scales, scale=scale, q_block=q_block, page_size=page_size,
-            pages_per_step=pages_per_step, interpret=interpret)
-    dv = v_pages.shape[-1]
+        w2, d2 = q2.shape[-1], k2_pages.shape[-1]
+        assert d2 >= w2, (d2, w2)
+        wv = d
+    else:
+        dv = v_pages.shape[-1]
+        w2 = 0
+        wv = d if dv == dk else dv    # value columns read and returned
     logical = page_size or page
     assert 0 < logical <= page, (logical, page)
     assert dk >= d, (dk, d)
     assert h % kh == 0, (h, kh)
     g = h // kh
-    wv = d if dv == dk else dv        # value columns read and returned
     c = max(int(pages_per_step), 1)
     n_groups = -(-table.shape[1] // c)
     if n_groups * c != table.shape[1]:
@@ -416,34 +518,49 @@ def paged_mixed_attention(
         # exactly c pages; the extra logical pages sit past the slot
         # capacity, so every row of them is masked.
         table = jnp.pad(table, ((0, 0), (0, n_groups * c - table.shape[1])))
-    qb = effective_q_block(qn, q_block)
-    nqb = qn // qb
+    if latent:
+        qb = latent_q_block(qn, q_block, h, d, w2, logical)
+    else:
+        qb = gqa_q_block(qn, q_block, g, kh, d, wv, logical)
+    rows = qb * g
 
     def walk(i, block):
         # one BlockSpec per page of the group: page i of grid step j is
         # physical page table[s, j * c + i]; Pallas pipelines the next
-        # step's c DMAs behind this step's compute.
+        # step's c DMAs behind this step's compute.  The table is read as
+        # it stands: a clamp to the q block's live pages cost 6-10 % of a
+        # call in scalar work on a v5e, more than the DMAs it saved.
         return pl.BlockSpec(
-            block, lambda s, qi, j, t, ln, ql, i=i: (t[s, j * c + i],)
+            block, lambda s, qi, j, t, ln, ql: (t[s, j * c + i],)
             + (0,) * (len(block) - 1))
 
-    in_specs = [
-        pl.BlockSpec((1, qb, h, d),
-                     lambda s, qi, j, t, ln, ql: (s, qi, 0, 0)),
-        *[walk(i, (1, page, kh, dk)) for i in range(c)],
-        *[walk(i, (1, page, kh, dv)) for i in range(c)],
-    ]
-    args = [q, *[k_pages] * c, *[v_pages] * c]
-    scratch = [
-        pltpu.VMEM((h, qb, 1), jnp.float32),      # running max
-        pltpu.VMEM((h, qb, 1), jnp.float32),      # running normaliser
-        pltpu.VMEM((h, qb, wv), jnp.float32),     # output accumulator
-    ]
+    def rows_spec(width):
+        return pl.BlockSpec((1, kh, rows, width),
+                            lambda s, qi, j, t, ln, ql: (s, 0, qi, 0))
+
+    def by_kv_head(x):
+        # (S, Q, H, w) -> (S, KH, Q * g, w): the g query heads of a KV
+        # head become rows of one operand, token-major
+        w = x.shape[-1]
+        return x.reshape(s_n, qn, kh, g, w).transpose(0, 2, 1, 3, 4) \
+            .reshape(s_n, kh, qn * g, w)
+
+    in_specs = [rows_spec(d), *[walk(i, (1, page, kh, dk)) for i in range(c)]]
+    args = [by_kv_head(q), *[k_pages] * c]
+    if latent:
+        in_specs += [rows_spec(w2),
+                     *[walk(i, (1, page, 1, d2)) for i in range(c)]]
+        args += [by_kv_head(q2), *[k2_pages] * c]
+        scales = (k_scales, k2_scales)
+    else:
+        in_specs += [walk(i, (1, page, kh, dv)) for i in range(c)]
+        args += [v_pages] * c
+        scales = (k_scales, v_scales)
     if k_scales is not None:
         # one scale column (page, 1) per physical page, walked through the
         # page table exactly like the pools themselves; a column scales
         # the decoded page's rows without an in-kernel transpose
-        for sc in (k_scales, v_scales):
+        for sc in scales:
             in_specs += [walk(i, (1, page, 1)) for i in range(c)]
             args += [sc.astype(jnp.float32).reshape(n_pages, page, 1)] * c
         in_specs += [pl.BlockSpec((1, kv_codec.ZERO_CODE),
@@ -452,90 +569,35 @@ def paged_mixed_attention(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(s_n, nqb, n_groups),
+        grid=(s_n, -(-qn // qb), n_groups),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, qb, h, wv),
-                               lambda s, qi, j, t, ln, ql: (s, qi, 0, 0)),
-        scratch_shapes=scratch,
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel, logical=logical, c=c, kh=kh,
-                          g=g, qb=qb, widths=(d, wv), window=window,
-                          softcap_val=softcap_val, scale=scale,
-                          has_codec=k_scales is not None),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_n, qn, h, wv), jnp.float32),
-        name="paged_mixed_attention",
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(table.astype(jnp.int32), lengths.astype(jnp.int32),
-      jnp.asarray(q_lens, jnp.int32), *args)
-
-
-def _latent_attention(q, k_pages, table, lengths, q_lens, q2, k2_pages,
-                      k_scales, k2_scales, *, scale, q_block, page_size,
-                      pages_per_step, interpret):
-    """:func:`paged_mixed_attention` over one shared latent KV head with
-    a second score operand (MLA): every query head of a q block scored in
-    one product per page (:func:`_latent_kernel`), the latent pool read
-    as the values too."""
-    s_n, qn, h, d = q.shape
-    n_pages, page, _, dk = k_pages.shape
-    w2, d2 = q2.shape[-1], k2_pages.shape[-1]
-    assert dk >= d and d2 >= w2, (dk, d, d2, w2)
-    logical = page_size or page
-    assert 0 < logical <= page, (logical, page)
-    c = max(int(pages_per_step), 1)
-    n_groups = -(-table.shape[1] // c)
-    if n_groups * c != table.shape[1]:
-        table = jnp.pad(table, ((0, 0), (0, n_groups * c - table.shape[1])))
-    qb = latent_q_block(qn, q_block, h, d, w2, logical)
-    rows = qb * h
-
-    def walk(i, block):
-        return pl.BlockSpec(
-            block, lambda s, qi, j, t, ln, ql, i=i: (t[s, j * c + i],)
-            + (0,) * (len(block) - 1))
-
-    def rows_spec(width):
-        return pl.BlockSpec((1, rows, width),
-                            lambda s, qi, j, t, ln, ql: (s, qi, 0))
-
-    in_specs = [rows_spec(d), *[walk(i, (1, page, 1, dk)) for i in range(c)]]
-    args = [q.reshape(s_n, qn * h, d), *[k_pages] * c]
-    in_specs += [rows_spec(w2),
-                 *[walk(i, (1, page, 1, d2)) for i in range(c)]]
-    args += [q2.reshape(s_n, qn * h, w2), *[k2_pages] * c]
-    if k_scales is not None:
-        for sc in (k_scales, k2_scales):
-            in_specs += [walk(i, (1, page, 1)) for i in range(c)]
-            args += [sc.astype(jnp.float32).reshape(n_pages, page, 1)] * c
-        in_specs += [pl.BlockSpec((1, kv_codec.ZERO_CODE),
-                                  lambda s, qi, j, t, ln, ql: (0, 0))]
-        args += [kv_codec.codebook()[None, kv_codec.ZERO_CODE:]]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(s_n, qn // qb, n_groups),
-        in_specs=in_specs,
-        out_specs=rows_spec(d),
-        scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
-                        pltpu.VMEM((rows, 1), jnp.float32),
-                        pltpu.VMEM((rows, d), jnp.float32)],
+        out_specs=rows_spec(wv),
+        scratch_shapes=[
+            pltpu.VMEM((kh, rows, 1), jnp.float32),      # running max
+            pltpu.VMEM((kh, rows, 1), jnp.float32),      # normaliser
+            pltpu.VMEM((kh, rows, wv), jnp.float32),     # accumulator
+        ],
     )
     out = pl.pallas_call(
-        functools.partial(_latent_kernel, logical=logical, c=c, h=h, qb=qb,
-                          widths=(d, w2), scale=scale,
-                          has_codec=k_scales is not None),
+        functools.partial(_kernel, logical=logical, c=c, kh=kh,
+                          g=g, qb=qb, widths=(d, wv, w2), window=window,
+                          softcap_val=softcap_val, scale=scale,
+                          has_codec=k_scales is not None, latent=latent),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_n, qn * h, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((s_n, kh, qn * g, wv), jnp.float32),
         name="paged_mixed_attention",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        # what kernel_launches reads back from a traced program
+        metadata={k: str(v) for k, v in Launch(
+            qn=qn, qb=qb, n_pages=n_groups * c, logical=logical,
+            window=window, pages_per_step=c, q_block=q_block)
+            ._asdict().items()},
         interpret=interpret,
     )(table.astype(jnp.int32), lengths.astype(jnp.int32),
       jnp.asarray(q_lens, jnp.int32), *args)
-    return out.reshape(s_n, qn, h, d)
+    return out.reshape(s_n, kh, qn, g, wv).transpose(0, 2, 1, 3, 4).reshape(
+        s_n, qn, h, wv)
 
 
 def paged_decode_attention(

@@ -322,7 +322,7 @@ def serve(engine: ServeEngine, args: argparse.Namespace) -> dict:
     if sched.kernel_tune != "off" and sched._pool is not None:
         pool = sched._pool
         print(f"kernel tune ({sched.kernel_tune}): q_block="
-              f"{pool.q_block or 'whole-Q'} pages_per_step="
+              f"{pool.q_block or 'from shapes'} pages_per_step="
               f"{pool.pages_per_step}, hardware-tiled pools "
               f"({pool.page_size}-token pages padded to "
               f"{pool.page_rows} rows), {m.kernel_qblock_rounded} "

@@ -47,7 +47,7 @@ class PagedContext:
     table: jax.Array         # (S, pages_per_slot) int32
     page_size: int
     interpret: bool = False
-    q_block: int = 0         # kernel query-block width (0 = whole Q)
+    q_block: int = 0         # kernel query-block width (0 = from shapes)
     pages_per_step: int = 1  # physical pages per kernel grid step
 
     def write(self, pool: jax.Array, values: jax.Array, pos,

@@ -158,8 +158,10 @@ def tune_kernel(cfg, page_size: int, q: int, *, codec: bool = False,
     real shapes, stand-in values — and returns the fastest launch
     shape.  ``q_blocks`` defaults to the divisors of ``q`` (the kernel
     rounds non-divisors down to a gcd, so sweeping them would double
-    count) and candidates are timed best-of-``repeats`` after a warmup
-    call that eats the compile.
+    count) whose ``qb * H / KH`` query rows the chip's block rule takes:
+    a multiple of the 8-row sublane tile, or all of Q.  Candidates are
+    timed best-of-``repeats`` after a warmup call that eats the
+    compile.
 
     Returns ``q_block`` / ``pages_per_step`` (the winner), ``best_ms``,
     the full ``timings`` list of ``(q_block, pages_per_step, ms)``,
@@ -209,7 +211,10 @@ def tune_kernel(cfg, page_size: int, q: int, *, codec: bool = False,
         out.block_until_ready()
 
     timings = []
-    for qb in (q_blocks if q_blocks is not None else _divisors(q)):
+    if q_blocks is None:
+        g = h // kh
+        q_blocks = [qb for qb in _divisors(q) if qb == q or qb * g % 8 == 0]
+    for qb in q_blocks:
         for pps in pages_per_step:
             run(qb, pps)                       # warmup: compile
             best = math.inf

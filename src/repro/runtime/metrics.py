@@ -114,8 +114,10 @@ class ServeMetrics:
     kv_codec_error_bound: float = 0.0  # worst elementwise reconstruction
     #                                    error bound seen (max scale / 254)
     kernel_qblock_rounded: int = 0     # mixed steps whose tuned q_block
-    #                                    did not divide the step's Q and
-    #                                    silently rounded to gcd(Q, qb)
+    #                                    the kernel could not run as
+    #                                    asked: a non-divisor of the
+    #                                    step's Q rounded to gcd(Q, qb),
+    #                                    or rows the chip refuses
     prefix_hits: int = 0               # admissions that mapped a cached
     #                                    prefix (prefix_share only)
     prefix_tokens_reused: int = 0      # prompt tokens served straight
@@ -147,6 +149,12 @@ class ServeMetrics:
     #                                    of its busiest held expert, summed
     expert_busiest_share_sum: float = 0.0  # per step, its busiest pairs
     #                                    over its pairs, summed over steps
+    attn_grid_steps: int = 0           # paged-attention kernel grid steps
+    #                                    walked, every paged layer call,
+    #                                    while the engine keeps telemetry
+    attn_grid_steps_live: int = 0      # ... of them that computed: a q
+    #                                    block with a real query and a page
+    #                                    with a key one of them may see
     _t0: float = dataclasses.field(default_factory=time.monotonic)
     # latency distributions (log-bucket histograms; seconds).  Lifetime
     # averages hide tails — the paper's wins are distribution claims, so
@@ -223,8 +231,9 @@ class ServeMetrics:
         self.kv_bytes_avoided += fp_bytes - resident_bytes
 
     def record_kernel_qblock_rounded(self) -> None:
-        """One mixed step served with a gcd-rounded ``q_block`` (the
-        tuned block width did not divide this step's ``Q``)."""
+        """One mixed step served with a rounded ``q_block`` (the tuned
+        block width did not divide this step's ``Q``, or its rows were
+        a block the chip refuses)."""
         self.kernel_qblock_rounded += 1
 
     def record_prefix_hit(self, tokens: int, chunks_avoided: int) -> None:
@@ -305,6 +314,12 @@ class ServeMetrics:
         if pairs:
             self.expert_busiest_share_sum += busiest / pairs
         return pairs
+
+    def record_attn_grid_steps(self, walked: int, live: int) -> None:
+        """One mixed step's paged-attention grid steps, over all its
+        paged layer calls: ``walked`` run, ``live`` of them computed."""
+        self.attn_grid_steps += walked
+        self.attn_grid_steps_live += live
 
     def record_completed(self, n_requests: int) -> None:
         self.requests_completed += n_requests
@@ -478,7 +493,7 @@ class ServeMetrics:
                 ("kv_bytes_avoided",
                  "KV pool bytes the codec kept out of HBM"),
                 ("kernel_qblock_rounded",
-                 "mixed steps run with a gcd-rounded q_block"),
+                 "mixed steps run with a rounded q_block"),
                 ("prefix_hits",
                  "admissions that mapped a cached prefix"),
                 ("prefix_tokens_reused",
@@ -505,7 +520,11 @@ class ServeMetrics:
                 ("expert_pairs",
                  "token-expert pairs the held experts computed"),
                 ("expert_busiest_pairs",
-                 "pairs of each MoE block's busiest held expert")):
+                 "pairs of each MoE block's busiest held expert"),
+                ("attn_grid_steps",
+                 "paged-attention kernel grid steps walked"),
+                ("attn_grid_steps_live",
+                 "paged-attention grid steps that computed")):
             reg.counter(f"{field}_total",
                         (lambda f=field: getattr(self, f)), help_)
         reg.counter("prefill_seconds_total", lambda: self.prefill_s,

@@ -81,7 +81,7 @@ import numpy as np
 
 from repro.kernels import kv_codec as kv_codec_mod
 from repro.kernels.kv_codec import KV_CODECS
-from repro.kernels.paged_attention import effective_q_block
+from repro.kernels.paged_attention import kernel_launches
 from repro.kernels.platform import interpret_mode
 from repro.models.api import (ATTN_BACKENDS, cache_layout, get_model,
                               padded_page_dims, supports_chunked_prefill,
@@ -114,20 +114,21 @@ def _warn_fallback(family: str, capability: str, message: str) -> None:
 
 
 # the kernel rounds a q_block that does not divide this step's Q down to
-# gcd(Q, q_block); every rounded step bumps kernel_qblock_rounded, the
-# first one per (Q, q_block) also warns so the degraded launch shape is
-# impossible to miss
+# gcd(Q, q_block), and replaces one whose rows the chip refuses with the
+# block sized from the shapes; every rounded step bumps
+# kernel_qblock_rounded, the first one per (Q, q_block) also warns so the
+# degraded launch shape is impossible to miss
 _QBLOCK_WARNED: set = set()
 
 
-def _warn_qblock_rounded(qn: int, q_block: int) -> None:
+def _warn_qblock_rounded(qn: int, q_block: int, qb: int) -> None:
     key = (qn, q_block)
     if key in _QBLOCK_WARNED:
         return
     _QBLOCK_WARNED.add(key)
     warnings.warn(
-        f"kernel q_block={q_block} does not divide this step's Q={qn}; "
-        f"rounding down to gcd={effective_q_block(qn, q_block)} "
+        f"kernel q_block={q_block} does not divide this step's Q={qn} "
+        f"into blocks the kernel takes; running {qb}-token blocks "
         "(counted in kernel_qblock_rounded)", RuntimeWarning,
         stacklevel=3)
 
@@ -343,6 +344,8 @@ class ServeEngine:
         # kernel runs interpreted on CPU, compiled on TPU)
         self.kernel_interpret = interpret_mode()
         self._mixed_jits: dict = {}
+        # mixed-step key -> Counter of its paged-attention kernel launches
+        self._mixed_launches: dict = {}
 
     @property
     def supports_chunked_prefill(self) -> bool:
@@ -366,9 +369,11 @@ class ServeEngine:
 
         ``q_block`` / ``pages_per_step`` are the tuned kernel launch
         parameters (``runtime.autotune.tune_kernel``); a ``q_block``
-        that does not divide this step's ``Q`` silently rounds down to
-        ``gcd(Q, q_block)`` inside the kernel, so the rounding is
-        counted (``kernel_qblock_rounded``) and warned once here.
+        the kernel cannot run at this step's ``Q`` (a non-divisor, or
+        rows the chip refuses) is rounded inside the kernel, so the
+        rounding is counted (``kernel_qblock_rounded``) and warned once
+        here, from the launches the traced step records
+        (:meth:`paged_launches`).
 
         ``kv_scales`` (``kv_codec="cluster"``): the scale-pool tree
         riding alongside int8 code pools; it is donated too and the
@@ -377,18 +382,31 @@ class ServeEngine:
         expert, (n_moe_blocks, n_held) int32, last."""
         codec = kv_scales is not None
         qn = int(toks.shape[1])
-        eff = effective_q_block(qn, q_block)
-        if q_block and eff not in (q_block, qn):
-            # eff == qn (e.g. decode's Q=1) still runs one whole-Q block
-            # — nothing degraded; only a genuinely fragmented launch
-            # counts
+        key = (paged_flags, page_size, qn, codec, q_block, pages_per_step)
+        fn = self.mixed_step_fn(*key)
+        args = (params, kcache, table, toks, poss, q_lens) + \
+            ((kv_scales,) if codec else ())
+        launches = self._mixed_launches.get(key)
+        if launches is None:
+            # the step's one trace, which the call below reuses
+            launches = self._mixed_launches[key] = kernel_launches(
+                fn.trace(*args).jaxpr)
+        rounded = [ln for ln in launches if ln.rounded]
+        if rounded:
+            # a whole-Q block (e.g. decode's Q=1) is nothing degraded;
+            # only a launch that ran another block than asked counts
             self.metrics.record_kernel_qblock_rounded()
-            _warn_qblock_rounded(qn, q_block)
-        fn = self.mixed_step_fn(paged_flags, page_size, qn, codec, q_block,
-                                pages_per_step)
-        if codec:
-            return fn(params, kcache, table, toks, poss, q_lens, kv_scales)
-        return fn(params, kcache, table, toks, poss, q_lens)
+            _warn_qblock_rounded(qn, q_block, rounded[0].qb)
+        return fn(*args)
+
+    def paged_launches(self, paged_flags: tuple, page_size: int, qn: int,
+                       codec: bool, q_block: int = 0,
+                       pages_per_step: int = 1):
+        """Counter of the paged-attention kernel launches one mixed step
+        of this key makes (``kernels.paged_attention.kernel_launches``),
+        read from its trace; None before the step has first run."""
+        return self._mixed_launches.get(
+            (paged_flags, page_size, qn, codec, q_block, pages_per_step))
 
     def mixed_step_fn(self, paged_flags: tuple, page_size: int, qn: int,
                       codec: bool, q_block: int = 0,
@@ -1401,6 +1419,22 @@ class SlotPool:
             self.kscales = out[2]
         return logits, load
 
+    def attn_grid_steps(self, poss, q_lens, width: int) -> tuple[int, int]:
+        """(walked, live) paged-attention grid steps of the mixed step of
+        block width ``width`` just run over slots starting at ``poss``
+        with ``q_lens`` real tokens, summed over the kernel calls its
+        trace records (``ServeEngine.paged_launches``)."""
+        launches = self.engine.paged_launches(
+            self.paged_flags, self.page_size, width, self.codec,
+            self.q_block, self.pages_per_step)
+        lengths = np.asarray(poss) + np.asarray(q_lens)
+        walked = live = 0
+        for launch, n in launches.items():
+            w, lv = launch.grid_steps(lengths, q_lens)
+            walked += n * w
+            live += n * lv
+        return walked, live
+
     def lowered_mixed_step(self, params, width: int = 1):
         """The engine's mixed step for this pool at block width ``width``,
         lowered against the live pools without running it -- what a
@@ -1696,9 +1730,10 @@ class Scheduler:
         pool about to be built.
 
         ``"off"`` keeps the identity layout (no padding, one page per
-        grid step, whole-Q blocks); any other value turns hardware
-        tiling on.  ``"auto"`` sweeps the live ``(arch, page, Q)`` point
-        through :func:`runtime.autotune.tune_kernel` (memoised per key);
+        grid step, q blocks sized from the shapes); any other value
+        turns hardware tiling on.  ``"auto"`` sweeps the live ``(arch,
+        page, Q)`` point through :func:`runtime.autotune.tune_kernel`
+        (memoised per key);
         ``"QB[,PPS]"`` pins the launch shape explicitly."""
         if self.kernel_tune == "off" or self.attn_backend != "pallas_paged":
             return 0, 1, False
@@ -1987,7 +2022,8 @@ class Scheduler:
         metrics record and tests assert.
 
         The ``mixed_step`` phase (args: block width Q, active slots,
-        chunk tokens) holds four children in order -- ``.prepare``
+        chunk tokens, the paged-attention grid steps that compute, counted
+        in ``.commit``) holds four children in order -- ``.prepare``
         (token blocks, page tables), the weight walk, ``.dispatch`` (the
         uploads and the enqueue), ``.wait`` (the host blocked on the
         device's logits) and ``.commit`` (tokens, retirements,
@@ -2084,6 +2120,12 @@ class Scheduler:
             finite = ok_rows[lanes, np.maximum(q_lens - 1, 0)]
         dt = time.monotonic() - t0
         with tel.timed("mixed_step.commit"):
+            if tel is not NULL_TELEMETRY:
+                # host work that only feeds a metric: counted where the
+                # engine keeps telemetry
+                walked, live = pool.attn_grid_steps(poss, q_lens, width)
+                m.record_attn_grid_steps(walked, live)
+                step.annotate(attn_grid_steps_live=live)
             # wall time attributed to decode vs prefill by token share
             n_dec_toks = int(sum(q_lens[s.index] for s in active))
             total = n_dec_toks + n_chunk_toks

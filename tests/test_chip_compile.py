@@ -6,8 +6,11 @@ shapes off the (8, 128) tiling, reshapes Mosaic cannot lay out, a step
 that does not fit the device -- the interpret-mode tests cannot see.
 These cases compile the serving path's paged-attention kernel at
 gemma2-2b's published widths for decode (Q = 1), a 128-token prefill
-chunk and a 1 + 4 speculative verify, with the KV codec off and on, and
-one whole mixed step of the full 26-layer model.  Nothing runs; results
+chunk and a 1 + 4 speculative verify, and at phi3-medium's for decode
+and a 64-token chunk, each with the KV codec off and on; at both
+widths for a verify under a q block pinned for the chunk; one whole mixed
+step of the full 26-layer gemma2-2b; and the shared-latent kernel at
+DeepSeek-V2's widths.  Nothing runs; results
 and times need the chip (``chip_smoke.py``).
 
 The topology is described inside a module fixture, never at import: only
@@ -81,6 +84,62 @@ def test_paged_kernel_compiles_at_gemma2_widths(one_chip, qn, codec):
     else:
         def fn(q, k, v, t, ln, ql):
             return paged_mixed_attention(q, k, v, t, ln, ql, **kw)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("codec", ["none", "cluster"])
+@pytest.mark.parametrize("qn", [1, 64])
+def test_paged_kernel_compiles_at_phi3_medium_widths(one_chip, qn, codec):
+    """The GQA kernel at phi3-medium's widths: 40 query heads over 10 KV
+    heads of 128, so a KV head's 4 query heads are the rows of one
+    product per page; 16-token pages, 32 slots of 32 pages; decode and a
+    64-token chunk (two q blocks of 128 rows)."""
+    cfg = get_config("phi3-medium-14b")
+    h, kh, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    slots, pages = 32, 32
+    n_pages = slots * pages + 1
+    pool_dtype = jnp.int8 if codec == "cluster" else jnp.bfloat16
+    args = [_sds((slots, qn, h, d), jnp.float32, one_chip),
+            _sds((n_pages, PAGE, kh, d), pool_dtype, one_chip),
+            _sds((n_pages, PAGE, kh, d), pool_dtype, one_chip),
+            _sds((slots, pages), jnp.int32, one_chip),
+            _sds((slots,), jnp.int32, one_chip),
+            _sds((slots,), jnp.int32, one_chip)]
+    if codec == "cluster":
+        args += [_sds((n_pages, PAGE), jnp.float32, one_chip)] * 2
+
+        def fn(q, k, v, t, ln, ql, ks, vs):
+            return paged_mixed_attention(q, k, v, t, ln, ql, k_scales=ks,
+                                         v_scales=vs, page_size=PAGE)
+    else:
+        def fn(q, k, v, t, ln, ql):
+            return paged_mixed_attention(q, k, v, t, ln, ql, page_size=PAGE)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("q_block", [4, 32])
+@pytest.mark.parametrize("arch", ["phi3-medium-14b", "gemma2-2b"])
+def test_explicit_q_block_compiles_at_verify_width(one_chip, arch, q_block):
+    """A q block pinned for the chunk width (``--kernel-tune``) applied
+    at a 1 + 4 speculative verify: ``gcd(5, q_block) = 1`` token is 4 or
+    2 query rows a KV head, which the chip refuses, so the kernel runs
+    the block sized from the shapes instead."""
+    cfg = get_config(arch)
+    h, kh, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    qn = 5
+    args = [_sds((SLOTS, qn, h, d), jnp.float32, one_chip),
+            _sds((N_PAGES, PAGE, kh, d), jnp.bfloat16, one_chip),
+            _sds((N_PAGES, PAGE, kh, d), jnp.bfloat16, one_chip),
+            _sds((SLOTS, PAGES_PER_SLOT), jnp.int32, one_chip),
+            _sds((SLOTS,), jnp.int32, one_chip),
+            _sds((SLOTS,), jnp.int32, one_chip)]
+
+    def fn(q, k, v, t, ln, ql):
+        return paged_mixed_attention(
+            q, k, v, t, ln, ql, softcap_val=cfg.attn_logit_softcap,
+            window=cfg.window, q_block=q_block, page_size=PAGE)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
 

@@ -17,8 +17,8 @@ import numpy as np
 import pytest
 
 from repro.configs.base import get_config
-from repro.kernels.paged_attention import (LATENT_VMEM_BUDGET,
-                                           latent_q_block, latent_row_bytes)
+from repro.kernels.paged_attention import (VMEM_BUDGET, latent_q_block,
+                                           q_row_bytes)
 from repro.models import moe as moe_mod
 from repro.models.api import get_model
 from repro.models.attention import mla_softmax_scale
@@ -277,8 +277,7 @@ class TestLatentQBlock:
     def test_sized_from_vmem_at_published_widths(self, qn, want):
         qb = latent_q_block(qn, 0, 128, 512, 64, 64)
         assert qb == want and qn % qb == 0
-        assert qb * 128 * latent_row_bytes(512, 64, 64) <= \
-            LATENT_VMEM_BUDGET
+        assert qb * 128 * q_row_bytes(1, 512, 512, 64, 64) <= VMEM_BUDGET
 
     def test_a_tuned_block_is_kept_when_it_fits(self):
         assert latent_q_block(16, 2, 128, 512, 64, 64) == 2

@@ -13,7 +13,7 @@ suites assert token identity across the full launch-shape grid.
 
 Also here: the in-kernel half-codebook gather vs the full table
 (bit-identity regression), the ``kernel_qblock_rounded`` telemetry
-for gcd-rounded q_blocks, and ``tune_kernel`` unit tests.
+for rounded q_blocks, and ``tune_kernel`` unit tests.
 """
 
 import numpy as np
@@ -284,24 +284,41 @@ class TestQblockRounding:
         assert engine.metrics.kernel_qblock_rounded > 0
 
     def test_dividing_qblock_not_counted(self, engine):
+        # 4 tokens of 2 query heads a KV head: 8 rows, which the chip
+        # takes
         engine.metrics.kernel_qblock_rounded = 0
         reqs = mixed_requests(engine, MIXED[:2])
-        run_trace(engine, reqs, prefill_chunk=4,
+        run_trace(engine, reqs, prefill_chunk=8,
                   attn_backend="pallas_paged", kv_page_size=4,
-                  kernel_tune="2,1")
+                  kernel_tune="4,1")
         assert engine.metrics.kernel_qblock_rounded == 0
+
+    def test_refused_rows_counted_and_warned(self, engine):
+        """A dividing q_block whose rows the chip refuses (2 tokens of 2
+        query heads: 4 rows) runs the block sized from the shapes, and
+        that counts as a rounding too."""
+        engine.metrics.kernel_qblock_rounded = 0
+        sched_mod._QBLOCK_WARNED.clear()
+        reqs = mixed_requests(engine, MIXED[:2])
+        with pytest.warns(RuntimeWarning, match="running 4-token blocks"):
+            run_trace(engine, reqs, prefill_chunk=4,
+                      attn_backend="pallas_paged", kv_page_size=4,
+                      kernel_tune="2,1")
+        assert engine.metrics.kernel_qblock_rounded > 0
 
 
 class TestTuneKernel:
     def test_returns_candidate_winner(self, engine):
         _KERNEL_TUNE_CACHE.clear()
-        res = tune_kernel(engine.cfg, 4, 4, interpret=True, repeats=1,
+        res = tune_kernel(engine.cfg, 4, 8, interpret=True, repeats=1,
                           pages_per_step=(1, 2))
-        assert res["q_block"] in (1, 2, 4)
+        # 2 query heads a KV head: blocks of 4 and 8 tokens give 8 and 16
+        # rows, the chip's sublane tile; 1 and 2 tokens would not
+        assert res["q_block"] in (4, 8)
         assert res["pages_per_step"] in (1, 2)
         assert not res["cached"]
         assert res["best_ms"] == min(t[2] for t in res["timings"])
-        assert len(res["timings"]) == 6      # divisors(4) x pps(2)
+        assert len(res["timings"]) == 4      # q blocks (4, 8) x pps(2)
 
     def test_memoised_per_key(self, engine):
         res1 = tune_kernel(engine.cfg, 4, 4, interpret=True, repeats=1,

@@ -158,6 +158,66 @@ class TestMixedStepHotPath:
         assert m.kv_gather_bytes_avoided == 0
         assert m.kv_prefill_gather_bytes_avoided == 0
 
+    @pytest.mark.parametrize("arch,paged_layers", [("minitron-8b", 2),
+                                                   ("gemma2-2b", 2)])
+    def test_attn_grid_step_counters(self, arch, paged_layers):
+        """Every mixed step counts the paged-attention grid steps of each
+        paged layer's kernel call (gemma2: the two global layers; its
+        local layers are lanes): all those walked, and those live by the
+        kernel's rule, brute-forced here from the blocks the scheduler
+        sent.  The live count rides the ``mixed_step`` span and both
+        reach Prometheus."""
+        from repro.runtime.telemetry import Telemetry
+        from tests.test_paged_attention import _brute_live_steps
+        tel = Telemetry(trace=True)
+        engine = make_engine(arch, telemetry=tel)
+        page = 4
+        sched = Scheduler(engine, batch_size=2, buckets=(32,),
+                          kv_page_size=page, prefill_chunk=3,
+                          attn_backend="pallas_paged")
+        for r in mixed_requests(engine, MIXED[:4]):
+            sched.submit(*r)
+        pool = sched._ensure_pool()
+        blocks = []
+        orig = pool.mixed_step
+
+        def recorded(params, toks, poss, q_lens):
+            blocks.append((toks.shape[1], poss.copy(), q_lens.copy()))
+            return orig(params, toks, poss, q_lens)
+
+        pool.mixed_step = recorded
+        sched.run()
+        assert sched._pool is pool
+        assert {qn for qn, _, _ in blocks} == {1, 3}
+        walked = live = 0
+        for qn, poss, q_lens in blocks:
+            walked += paged_layers * 2 * pool.pages_per_slot
+            live += paged_layers * _brute_live_steps(
+                poss + q_lens, q_lens, qn=qn, qb=qn,
+                n_pages=pool.pages_per_slot, logical=page, window=0,
+                pages_per_step=1)
+        m = engine.metrics
+        assert (m.attn_grid_steps, m.attn_grid_steps_live) == (walked, live)
+        assert 0 < live < walked
+        spans = [e for e in tel.tracer.chrome()["traceEvents"]
+                 if e.get("name") == "mixed_step"]
+        assert len(spans) == len(blocks)
+        assert sum(e["args"]["attn_grid_steps_live"] for e in spans) == live
+        prom = engine.render_prom()
+        assert f"repro_attn_grid_steps_total {walked}" in prom
+        assert f"repro_attn_grid_steps_live_total {live}" in prom
+
+    def test_attn_grid_steps_counted_only_with_telemetry(self, engine):
+        """The grid-step count is host work that only feeds a metric: an
+        engine without telemetry does not make it."""
+        m = engine.metrics
+        m.attn_grid_steps = m.attn_grid_steps_live = 0
+        steps = m.decode_steps
+        serve(engine, mixed_requests(engine, MIXED[:2]), kv_page_size=4,
+              prefill_chunk=3, attn_backend="pallas_paged")
+        assert m.decode_steps > steps
+        assert (m.attn_grid_steps, m.attn_grid_steps_live) == (0, 0)
+
     def test_no_standalone_prefill_cache(self, engine, baseline):
         """Mixed-step admissions never allocate the batch-1 prefill cache
         — the slot's pcache stays None through its whole lifecycle."""

@@ -12,7 +12,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.paged_attention import paged_decode_attention
+from repro.kernels.paged_attention import (Launch, gqa_q_block,
+                                           kernel_launches, live_grid_steps,
+                                           paged_decode_attention,
+                                           paged_mixed_attention)
 from repro.models.api import supports_paged_attention
 from repro.models.attention import decode_attention
 from repro.runtime import Scheduler
@@ -122,6 +125,73 @@ class TestKernelVsOracle:
             np.testing.assert_allclose(np.asarray(out[i]), p @ c,
                                        rtol=2e-5, atol=2e-5)
 
+    @pytest.mark.parametrize("g,qn,window,softcap,codec,pps", [
+        (1, 1, 0, 0.0, False, 1),        # decode, one query head a KV head
+        (2, 6, 0, 0.0, False, 1),        # chunk program, whole-Q block
+        (4, 40, 0, 0.0, False, 1),       # 160 rows: blocks of 32, Q % 32
+        (8, 20, 0, 0.0, False, 2),       # 160 rows: blocks of 16, 2 pages
+        (2, 6, 5, 3.0, False, 1),        # gemma2-like window and softcap
+        (4, 40, 9, 0.0, False, 3),       # window over two q blocks
+        (4, 6, 0, 0.0, True, 1),         # KV codec
+        (8, 20, 7, 2.0, True, 2),        # everything at once
+    ])
+    def test_gqa_blocks_vs_gathered_reference(self, g, qn, window, softcap,
+                                              codec, pps):
+        """Every KV head's query heads in one product per page, against
+        the gathered reference token by token: a full chunk beside decode
+        slots (q_lens 1), a free slot (q_lens 0), a partial chunk, and
+        lengths ending mid-page and on a page boundary."""
+        rng = np.random.default_rng(g * 100 + qn)
+        kh, d, page, pages_per_slot = 2, 8, 4, 20
+        h = g * kh
+        q_lens = np.array([qn, 1, 0, 1, max(qn - 1, 1)], np.int32)
+        on_boundary = -(-(qn + 13) // page) * page
+        lengths = np.array([on_boundary, 24, 0, 9, qn + 31], np.int32)
+        s_n = len(q_lens)
+        n_pages = s_n * pages_per_slot + 1
+        k_pages = rng.standard_normal((n_pages, page, kh, d)).astype(
+            np.float32)
+        v_pages = rng.standard_normal((n_pages, page, kh, d)).astype(
+            np.float32)
+        ids = iter(rng.permutation(np.arange(1, n_pages)))
+        table = np.zeros((s_n, pages_per_slot), np.int32)
+        for s in range(s_n):
+            for j in range(-(-int(lengths[s]) // page)):
+                table[s, j] = next(ids)
+        q = rng.standard_normal((s_n, qn, h, d)).astype(np.float32)
+        kw = {}
+        if codec:
+            from repro.kernels import kv_codec
+            k_in, ks = kv_codec.encode(jnp.asarray(k_pages), axes=(-2, -1))
+            v_in, vs = kv_codec.encode(jnp.asarray(v_pages), axes=(-2, -1))
+            k_pages = np.asarray(kv_codec.decode(k_in, ks[..., None, None]))
+            v_pages = np.asarray(kv_codec.decode(v_in, vs[..., None, None]))
+            kw = dict(k_scales=ks, v_scales=vs)
+        else:
+            k_in, v_in = jnp.asarray(k_pages), jnp.asarray(v_pages)
+        out = np.asarray(paged_mixed_attention(
+            jnp.asarray(q) * d ** -0.5, k_in, v_in, jnp.asarray(table),
+            jnp.asarray(lengths), jnp.asarray(q_lens), window=window,
+            softcap_val=softcap, pages_per_step=pps, interpret=True, **kw))
+        assert np.isfinite(out).all()
+        smax = pages_per_slot * page
+        k_view = jnp.asarray(k_pages[table].reshape(s_n, smax, kh, d))
+        v_view = jnp.asarray(v_pages[table].reshape(s_n, smax, kh, d))
+        for i in range(qn):
+            want = np.asarray(decode_attention(
+                jnp.asarray(q[:, i:i + 1]), k_view, v_view,
+                jnp.asarray(lengths - q_lens + i), window=window,
+                attn_softcap=softcap))[:, 0]
+            for s in np.flatnonzero(q_lens > i):
+                np.testing.assert_allclose(out[s, i], want[s],
+                                           rtol=2e-5, atol=2e-5)
+        # a q block with no real query does no arithmetic: its rows keep
+        # the zero accumulator (the free slot's, and past a decode token)
+        qb = gqa_q_block(qn, 0, g, kh, d, d, page)
+        for s in range(s_n):
+            dead = -(-int(q_lens[s]) // qb) * qb
+            assert not out[s, dead:].any()
+
     def test_dummy_sink_never_contaminates(self):
         """Poisoning the page-0 dummy sink with huge values must not
         change any output: every position the mask admits has a real
@@ -144,6 +214,166 @@ class TestKernelVsOracle:
         assert np.isfinite(poisoned).all()
         np.testing.assert_array_equal(clean, poisoned)
 
+
+
+def _brute_live_steps(lengths, q_lens, *, qn, qb, n_pages, logical, window,
+                      pages_per_step):
+    """Grid steps that compute, cell by cell: a q block holding a real
+    query, and a page of its group holding a key at a position below
+    the slot's length that the block's first query's window (if any)
+    reaches."""
+    c = pages_per_step
+    live = 0
+    for length, qlen in zip(lengths, q_lens):
+        for qi in range(-(-qn // qb)):
+            if qi * qb >= qlen:
+                continue
+            first = length - qlen + qi * qb
+            for j in range(-(-n_pages // c)):
+                keys = [p for lp in range(j * c, j * c + c)
+                        for p in range(lp * logical, (lp + 1) * logical)
+                        if p < length and (not window or p > first - window)]
+                live += bool(keys)
+    return live
+
+
+class TestLiveGridSteps:
+    @pytest.mark.parametrize("window,pps,qb", [(0, 1, 1), (0, 3, 4),
+                                               (5, 1, 2), (9, 2, 3),
+                                               (4, 4, 8)])
+    def test_matches_brute_force(self, window, pps, qb):
+        rng = np.random.default_rng(window * 10 + pps)
+        qn, logical, n_pages = 8, 4, 10
+        q_lens = rng.integers(0, qn + 1, 64)
+        q_lens[:4] = [0, 1, qn, 1]
+        lengths = q_lens + rng.integers(0, n_pages * logical - qn + 1, 64)
+        lengths[:4] = [0, 12, 40, 1]
+        kw = dict(qn=qn, qb=qb, n_pages=n_pages, logical=logical,
+                  window=window, pages_per_step=pps)
+        walked, live = live_grid_steps(lengths, q_lens, **kw)
+        assert walked == 64 * -(-qn // qb) * -(-n_pages // pps)
+        assert live == _brute_live_steps(lengths, q_lens, **kw)
+        assert 0 < live < walked
+
+    @pytest.mark.parametrize("window,pps", [(0, 1), (6, 1), (6, 2)])
+    def test_kernel_folds_exactly_the_live_pages(self, window, pps):
+        """The kernel computes on the pages the rule calls live and on no
+        other.  Two readings show it.  A padding row of a live q block
+        masks every key, so each page folded into it adds weight one to
+        every key row: its output is the mean value row over exactly the
+        pages computed.  And NaN values in every page the rule leaves
+        dead for all of a slot's q blocks (the dummy sink, pages a window
+        has passed) reach no output: neither read nor computed on."""
+        rng = np.random.default_rng(window + pps)
+        kh, g, d, page, pages_per_slot, qn = 2, 2, 8, 4, 8, 3
+        q_lens = np.array([1, 2, 0, 1, 3], np.int32)
+        lengths = np.array([30, 8, 0, 17, 21], np.int32)
+        s_n = len(q_lens)
+        n_pages = s_n * pages_per_slot + 1
+        k_pages = rng.standard_normal((n_pages, page, kh, d)).astype(
+            np.float32)
+        v_pages = rng.standard_normal((n_pages, page, kh, d)).astype(
+            np.float32)
+        table = np.zeros((s_n, pages_per_slot), np.int32)
+        ids = iter(range(1, n_pages))
+        for s in range(s_n):
+            for j in range(-(-int(lengths[s]) // page)):
+                table[s, j] = next(ids)
+        q = jnp.asarray(rng.standard_normal((s_n, qn, kh * g, d)),
+                        jnp.float32)
+
+        def run(vp):
+            return np.asarray(paged_mixed_attention(
+                q, jnp.asarray(k_pages), jnp.asarray(vp),
+                jnp.asarray(table), jnp.asarray(lengths),
+                jnp.asarray(q_lens), window=window, pages_per_step=pps,
+                interpret=True))
+
+        clean = run(v_pages)
+        poisoned = v_pages.copy()
+        poisoned[0] = np.nan                          # the dummy sink
+        for s in range(s_n):
+            opens = lengths[s] - q_lens[s] - window + 1
+            for j in range(pages_per_slot):
+                if table[s, j] and window and (j + 1) * page <= opens:
+                    poisoned[table[s, j]] = np.nan
+        assert np.isnan(poisoned).any(axis=(1, 2, 3)).sum() == \
+            (12 if window else 1)
+        np.testing.assert_array_equal(run(poisoned), clean)
+        checked = 0
+        for s in np.flatnonzero((q_lens > 0) & (q_lens < qn)):
+            first = lengths[s] - q_lens[s]
+            lo = max(first - window + 1, 0) // page if window else 0
+            hi = (lengths[s] - 1) // page
+            live = v_pages[table[s, lo:hi + 1]].reshape(-1, kh, d)
+            for t in range(q_lens[s], qn):
+                for hh in range(kh * g):
+                    np.testing.assert_allclose(
+                        clean[s, t, hh], live[:, hh // g].mean(0),
+                        rtol=1e-5, atol=1e-5)
+                    checked += 1
+        assert checked == 5 * kh * g         # 2 + 1 + 2 padding rows
+
+    def test_q_block_from_shapes(self):
+        """About one MXU pass of rows, a multiple of the sublane tile or
+        all of Q; an explicit block keeps its gcd meaning."""
+        phi3 = dict(g=4, kh=10, d=128, wv=128, page=16)
+        assert gqa_q_block(1, 0, **phi3) == 1
+        assert gqa_q_block(64, 0, **phi3) == 32
+        assert gqa_q_block(24, 0, **phi3) == 24
+        assert gqa_q_block(64, 16, **phi3) == 16
+        assert gqa_q_block(64, 24, **phi3) == 8
+        assert gqa_q_block(128, 0, g=2, kh=4, d=256, wv=256, page=16) == 64
+        assert gqa_q_block(100, 0, g=3, kh=1, d=64, wv=64, page=16) == 40
+
+    def test_refused_explicit_block_falls_back(self):
+        """An explicit block whose rows the chip refuses (neither a
+        multiple of 8 nor all of ``Q * g``) runs the block sized from the
+        shapes: a chunk's block at a 1 + 4 verify, or 2 tokens at g 2."""
+        phi3 = dict(g=4, kh=10, d=128, wv=128, page=16)
+        gemma2 = dict(g=2, kh=4, d=256, wv=256, page=16)
+        for q_block in (4, 32):
+            assert gqa_q_block(5, q_block, **phi3) == 5
+            assert gqa_q_block(5, q_block, **gemma2) == 5
+        assert gqa_q_block(64, 2, **gemma2) == 64
+        assert gqa_q_block(64, 4, **gemma2) == 4
+        assert gqa_q_block(1, 32, **phi3) == 1
+
+    @pytest.mark.parametrize("q_block,want_qb,rounded", [
+        (0, 5, False),       # sized from the shapes
+        (5, 5, False),       # asked and run
+        (4, 5, True),        # gcd 1: 2 rows a KV head, refused
+        (10, 5, False)])     # gcd 5 is all of Q: nothing degraded
+    def test_kernel_launches_read_from_the_trace(self, q_block, want_qb,
+                                                 rounded):
+        """Every kernel call records its launch; a call under a scan
+        counts once per iteration, and each window is its own launch."""
+        rng = np.random.default_rng(3)
+        s, qn, kh, g, d, page, pages = 2, 5, 2, 2, 16, 4, 3
+        k, v, table, lengths = random_paged_cache(rng, s, kh, d, d, page,
+                                                  pages)
+        q_lens = np.array([qn, 1], np.int32)
+        lengths = np.maximum(lengths, q_lens)
+        q = jnp.asarray(rng.normal(size=(s, qn, kh * g, d)), jnp.float32)
+
+        def attend(x, window):
+            return paged_mixed_attention(
+                x, k, v, table, lengths, q_lens, window=window,
+                q_block=q_block, page_size=page, interpret=True)
+
+        def step(x):
+            x = attend(x, 0)
+            return jax.lax.scan(lambda c, _: (attend(c, 4), None), x,
+                                None, length=3)[0]
+
+        launches = kernel_launches(jax.jit(step).trace(q).jaxpr)
+        base = Launch(qn=qn, qb=want_qb, n_pages=pages, logical=page,
+                      window=0, pages_per_step=1, q_block=q_block)
+        assert launches == {base: 1, base._replace(window=4): 3}
+        assert all(ln.rounded == rounded for ln in launches)
+        assert base.grid_steps(lengths, q_lens) == live_grid_steps(
+            lengths, q_lens, qn=qn, qb=want_qb, n_pages=pages,
+            logical=page)
 
 # ---------------------------------------------------------------------------
 # backend seam: token-identical serving across archs / page sizes
