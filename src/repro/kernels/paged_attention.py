@@ -2,11 +2,12 @@
 
 Attention that consumes the scheduler's paged KV layout *directly*: the
 physical page pool ``(n_pages, page, KH, D)`` plus a per-slot page table
-and per-slot lengths.  Each ``(slot, q_block, page group)`` grid step
-pulls ``pages_per_step`` physical pages into VMEM — the BlockSpec index
-maps read the page table through scalar prefetch, so the DMA engine
-walks the table and never touches pages the slot does not own — applies
-the per-token causal/position mask, and folds the pages into an
+and per-slot lengths.  Each ``(slot, q_block, page)`` grid step pulls
+one physical page into VMEM — the BlockSpec index maps read the page
+table through scalar prefetch, so the DMA engine walks the table and
+never touches pages the slot does not own; Pallas double-buffers every
+input BlockSpec, so page ``j + 1``'s DMA overlaps page ``j``'s compute —
+applies the per-token causal/position mask, and folds the page into an
 online-softmax accumulator held in VMEM scratch.  No contiguous
 per-slot view of the cache is ever materialised, in HBM or anywhere
 else: this is the serving analogue of the paper's in-pipeline decoding
@@ -29,11 +30,7 @@ Layout contract (shared with ``runtime.scheduler.SlotPool``):
     point at it and it is never read as a valid position (every position
     ``< lengths[s]`` has a real page, and everything else is masked);
   * a slot's logical page ``j`` covers absolute positions
-    ``[j * page_size, (j + 1) * page_size)`` where ``page_size`` is the
-    *logical* page length — the pool's physical page dimension may be
-    padded up to a sublane tile (``page_size=0`` means they coincide),
-    and padded rows are masked out of the softmax like any other
-    out-of-range position;
+    ``[j * page, (j + 1) * page)``, ``page`` being the pools' page axis;
   * ``lengths[s]`` = number of valid positions *including* this step's
     tokens (the whole chunk's K/V is written into the pool *before* the
     call; the per-token causal masks preserve write-after-attend
@@ -41,18 +38,6 @@ Layout contract (shared with ``runtime.scheduler.SlotPool``):
   * padded rows/tokens (``i >= q_lens[s]``, including ``q_lens[s] == 0``
     free lanes) attend nothing and produce finite garbage the caller
     discards.
-
-Hardware shaping (``pages_per_step``, tiled pools): with
-``pages_per_step = c > 1`` each grid step carries ``c`` physical pages,
-one BlockSpec per page, indexed ``table[s, j * c + i]``.  Pallas
-double-buffers every input BlockSpec across grid steps, so the ``c``
-page DMAs of step ``j + 1`` overlap the score/softmax compute of step
-``j`` — the same async-copy overlap ``pltpu.make_async_copy`` expresses
-by hand, but driven by the pipeline.  The pages of a group are folded
-into the online softmax one after another, so ``c`` changes the DMA
-shape and not the arithmetic.  Feature dims padded toward the (8, 128)
-sublane/lane tiles by ``SlotPool`` are never read: the kernel slices
-each page to its logical rows and the query's width.
 
 Query heads as rows: the ``g = H / KH`` query heads of one KV head share
 one product per page.  The queries are laid out before the call as a 2-D
@@ -143,8 +128,7 @@ def effective_q_block(qn: int, q_block: int) -> int:
 
     ``q_block=0`` means the whole ``Q`` per grid step; non-divisor
     requests round down to ``gcd(Q, q_block)`` (the same convention as
-    flash_attention's ``q_chunk``).  Exposed so the scheduler can count
-    the silent roundings (``kernel_qblock_rounded``)."""
+    flash_attention's ``q_chunk``)."""
     return math.gcd(qn, q_block) if q_block else qn
 
 
@@ -193,7 +177,7 @@ def gqa_q_block(qn: int, q_block: int, g: int, kh: int, d: int, wv: int,
     return max(step, rows // g // step * step)
 
 
-def _live_pages(length, qlen, first, *, logical: int, window: int,
+def _live_pages(length, qlen, first, *, page: int, window: int,
                 maximum=jnp.maximum):
     """The logical pages ``[lo, hi]`` that hold a key some real query of
     a q block may see, the block's first token being token ``first`` of
@@ -201,63 +185,46 @@ def _live_pages(length, qlen, first, *, logical: int, window: int,
     none that ends before the first query's window opens.  The one rule
     of the kernel's skips and :func:`live_grid_steps`; ``maximum`` is
     ``np.maximum`` on the host."""
-    hi = (length + logical - 1) // logical - 1
+    hi = (length + page - 1) // page - 1
     if not window:
         return 0, hi
     opens = length - qlen + first - window + 1      # earliest key it sees
-    return maximum(opens, 0) // logical, hi
+    return maximum(opens, 0) // page, hi
 
 
 def live_grid_steps(lengths, q_lens, *, qn: int, qb: int, n_pages: int,
-                    logical: int, window: int = 0,
-                    pages_per_step: int = 1) -> tuple[int, int]:
+                    logical: int, window: int = 0) -> tuple[int, int]:
     """(walked, live) grid steps of one :func:`paged_mixed_attention`
     call over slots of ``lengths`` and ``q_lens`` (host arrays), ``Q =
-    qn`` tokens in q blocks of ``qb``, page tables of ``n_pages``
-    logical pages of ``logical`` tokens: all the steps its grid runs, and
-    those that compute -- a q block holding a real query and a group of
-    ``pages_per_step`` pages of which one is live by :func:`_live_pages`.
-    """
+    qn`` tokens in q blocks of ``qb``, page tables of ``n_pages`` pages
+    of ``logical`` tokens: all the steps its grid runs, and those that
+    compute -- a q block holding a real query and a page that is live by
+    :func:`_live_pages`."""
     lengths = np.asarray(lengths, np.int64)[:, None]
     q_lens = np.asarray(q_lens, np.int64)[:, None]
-    c = max(int(pages_per_step), 1)
     nqb = -(-qn // qb)
     first = np.arange(nqb)[None] * qb
-    lo, hi = _live_pages(lengths, q_lens, first, logical=logical,
+    lo, hi = _live_pages(lengths, q_lens, first, page=logical,
                          window=window, maximum=np.maximum)
-    groups = np.maximum(hi // c - np.asarray(lo) // c + 1, 0)
-    live = int(np.where(first < q_lens, groups, 0).sum())
-    return lengths.shape[0] * nqb * -(-n_pages // c), live
+    pages = np.maximum(hi - np.asarray(lo) + 1, 0)
+    live = int(np.where(first < q_lens, pages, 0).sum())
+    return lengths.shape[0] * nqb * n_pages, live
 
 
 class Launch(NamedTuple):
     """One :func:`paged_mixed_attention` call as the kernel resolved it:
-    :func:`live_grid_steps`'s keywords, and the ``q_block`` the caller
-    asked for (0 = sized from the shapes)."""
+    :func:`live_grid_steps`'s keywords (``logical`` is the page
+    length)."""
     qn: int
     qb: int
     n_pages: int
     logical: int
     window: int
-    pages_per_step: int
-    q_block: int
-
-    @property
-    def rounded(self) -> bool:
-        """The asked block did not run as asked: a non-divisor rounded
-        down to ``gcd(Q, q_block)`` below Q, or a block the chip refuses
-        replaced by the one sized from the shapes."""
-        if not self.q_block:
-            return False
-        eff = effective_q_block(self.qn, self.q_block)
-        return eff not in (self.q_block, self.qn) or self.qb != eff
 
     def grid_steps(self, lengths, q_lens) -> tuple[int, int]:
         """(walked, live) grid steps of this call over slots of
         ``lengths`` and ``q_lens`` (:func:`live_grid_steps`)."""
-        kw = self._asdict()
-        del kw["q_block"]
-        return live_grid_steps(lengths, q_lens, **kw)
+        return live_grid_steps(lengths, q_lens, **self._asdict())
 
 
 def kernel_launches(jaxpr) -> collections.Counter:
@@ -288,14 +255,11 @@ def kernel_launches(jaxpr) -> collections.Counter:
     return out
 
 
-def _page_operand(ref, scale_ref, half, kv_head: int, rows: int,
-                  width: int):
-    """One KV head of one page as an f32 (rows, width) operand.
+def _page_operand(ref, scale_ref, half, kv_head: int):
+    """One KV head of one page as an f32 (page, D) operand.
 
     ``ref`` is a page block (1, page, KH, D); indexing the KV head is a
     strided sublane load, so no in-kernel reshape or transpose is needed.
-    Only the first ``rows`` rows and ``width`` feature columns are read:
-    the rest of a tile-padded pool is layout padding.
     Under the codec the int8 codes are looked up in ``half`` -- the
     codebook's non-negative half broadcast to (page, 128), one lane per
     magnitude, so each 128-lane slice of the page is one in-register
@@ -303,7 +267,7 @@ def _page_operand(ref, scale_ref, half, kv_head: int, rows: int,
     codes, which is exact because the codebook is symmetric, and scaled
     by the per-token scale column ``scale_ref`` (1, page, 1).  That is
     ``kv_codec.decode`` bit for bit."""
-    x = ref[0, :rows, kv_head, :width]
+    x = ref[0, :, kv_head, :]
     if scale_ref is None:
         return x.astype(jnp.float32)
     codes = x.astype(jnp.int32)
@@ -313,7 +277,7 @@ def _page_operand(ref, scale_ref, half, kv_head: int, rows: int,
         jnp.take_along_axis(half, mag[:, lo:lo + lanes], axis=1,
                             mode="promise_in_bounds")
         for lo in range(0, mag.shape[-1], lanes)], axis=1)
-    return jnp.where(codes < 0, -vals, vals) * scale_ref[0, :rows]
+    return jnp.where(codes < 0, -vals, vals) * scale_ref[0]
 
 
 def _dot(a, b, contract_b: int):
@@ -342,31 +306,24 @@ def _row_token(r, g: int, n: int):
 
 
 def _kernel(table_ref, len_ref, qlen_ref, q_ref, *rest,
-            logical: int, c: int, kh: int, g: int, qb: int, widths: tuple,
-            window: int, softcap_val: float, scale: float,
-            has_codec: bool, latent: bool):
+            page: int, kh: int, g: int, qb: int, window: int,
+            softcap_val: float, scale: float, has_codec: bool,
+            latent: bool):
     """One grid step: per KV head, its ``qb * g`` query rows (token-major,
-    row ``t * g + i`` is head ``kv * g + i``) against ``c`` pages, one
-    score product and one value product per KV head and page; no
-    arithmetic on a dead q block or page (:func:`_live_pages`).  Under
-    ``latent`` (MLA) the second operand adds ``q2 . k2`` to the scores
-    and the key page is the value page too."""
-    wk, wv, w2 = widths
+    row ``t * g + i`` is head ``kv * g + i``) against one page, one score
+    product and one value product per KV head; no arithmetic on a dead q
+    block or page (:func:`_live_pages`).  Under ``latent`` (MLA) the
+    second operand adds ``q2 . k2`` to the scores and the key page is the
+    value page too."""
     refs = iter(rest)
-
-    def take(n):
-        return tuple(next(refs) for _ in range(n))
-
-    k_refs = take(c)
+    k_ref = next(refs)
     q2_ref = next(refs) if latent else None
-    # the k2 pages under ``latent``, else the value pages
-    x_refs = take(c)
-    ks_refs = xs_refs = (None,) * c
-    half_ref = None
+    # the k2 page under ``latent``, else the value page
+    x_ref = next(refs)
+    ks_ref = xs_ref = half_ref = None
     if has_codec:
-        ks_refs, xs_refs = take(c), take(c)
-        half_ref = next(refs)
-    o_ref, m_ref, l_ref, acc_ref = take(4)
+        ks_ref, xs_ref, half_ref = next(refs), next(refs), next(refs)
+    o_ref, m_ref, l_ref, acc_ref = refs
     s_idx = pl.program_id(0)
     qb_idx = pl.program_id(1)
     j = pl.program_id(2)
@@ -381,58 +338,44 @@ def _kernel(table_ref, len_ref, qlen_ref, q_ref, *rest,
     length = len_ref[s_idx]
     qlen = qlen_ref[s_idx]
     first = qb_idx * qb
-    lo, hi = _live_pages(length, qlen, first, logical=logical, window=window)
+    lo, hi = _live_pages(length, qlen, first, page=page, window=window)
 
-    @pl.when(first < qlen)
-    def _block():
+    @pl.when((first < qlen) & (j >= lo) & (j <= hi))
+    def _page():
         # row r holds token first + r // g of the block, at absolute
         # position lengths[s] - q_lens[s] + that; tokens past q_lens[s]
-        # are ragged padding and attend nothing.  Key row r of page i of
-        # this group is logical position (j * c + i) * logical + r; rows
-        # at or past the logical page length are layout padding and never
-        # read.
-        r = jax.lax.broadcasted_iota(jnp.int32, (rows, logical), 0)
+        # are ragged padding and attend nothing.  Key row r of this page
+        # is position j * page + r.
+        r = jax.lax.broadcasted_iota(jnp.int32, (rows, page), 0)
         qi = first + _row_token(r, g, qb)
         qpos = (length - qlen) + qi
-        col = jax.lax.broadcasted_iota(jnp.int32, (rows, logical), 1)
+        gpos = j * page + jax.lax.broadcasted_iota(jnp.int32, (rows, page), 1)
+        valid = (gpos <= qpos) & (qi < qlen)
+        if window:
+            valid &= gpos > qpos - window
         half = None if half_ref is None else jnp.broadcast_to(
-            half_ref[...], (logical, half_ref.shape[-1]))
-        # each page of the group is folded into the online softmax in
-        # turn, so the result does not depend on how many pages one grid
-        # step carries
-        for pi in range(c):
-            lp = j * c + pi
-
-            @pl.when((lp >= lo) & (lp <= hi))
-            def _page(pi=pi, lp=lp):
-                gpos = lp * logical + col
-                valid = (gpos <= qpos) & (qi < qlen)
-                if window:
-                    valid &= gpos > qpos - window
-                for kv in range(kh):
-                    k = _page_operand(k_refs[pi], ks_refs[pi], half, kv,
-                                      logical, wk)
-                    s = _dot(q_ref[0, kv].astype(jnp.float32), k, 1)
-                    if latent:
-                        k2 = _page_operand(x_refs[pi], xs_refs[pi], half,
-                                           0, logical, w2)
-                        s = s + _dot(q2_ref[0, 0].astype(jnp.float32), k2, 1)
-                        v = k
-                    else:
-                        v = _page_operand(x_refs[pi], xs_refs[pi], half, kv,
-                                          logical, wv)
-                    if scale != 1.0:
-                        s = s * scale
-                    if softcap_val:
-                        s = jnp.tanh(s / softcap_val) * softcap_val
-                    s = jnp.where(valid, s, NEG_INF)
-                    m_prev = m_ref[kv]                       # (rows, 1)
-                    m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-                    alpha = jnp.exp(m_prev - m_new)
-                    p = jnp.exp(s - m_new)
-                    l_ref[kv] = l_ref[kv] * alpha + p.sum(-1, keepdims=True)
-                    acc_ref[kv] = acc_ref[kv] * alpha + _dot(p, v, 0)
-                    m_ref[kv] = m_new
+            half_ref[...], (page, half_ref.shape[-1]))
+        for kv in range(kh):
+            k = _page_operand(k_ref, ks_ref, half, kv)
+            s = _dot(q_ref[0, kv].astype(jnp.float32), k, 1)
+            if latent:
+                k2 = _page_operand(x_ref, xs_ref, half, 0)
+                s = s + _dot(q2_ref[0, 0].astype(jnp.float32), k2, 1)
+                v = k
+            else:
+                v = _page_operand(x_ref, xs_ref, half, kv)
+            if scale != 1.0:
+                s = s * scale
+            if softcap_val:
+                s = jnp.tanh(s / softcap_val) * softcap_val
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_ref[kv]                       # (rows, 1)
+            m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_ref[kv] = l_ref[kv] * alpha + p.sum(-1, keepdims=True)
+            acc_ref[kv] = acc_ref[kv] * alpha + _dot(p, v, 0)
+            m_ref[kv] = m_new
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _done():
@@ -441,7 +384,6 @@ def _kernel(table_ref, len_ref, qlen_ref, q_ref, *rest,
 
 @functools.partial(jax.jit, static_argnames=("window", "softcap_val",
                                              "scale", "q_block",
-                                             "page_size", "pages_per_step",
                                              "interpret"))
 def paged_mixed_attention(
     q: jax.Array,            # (S, Q, H, D)  padded per-slot query blocks
@@ -464,9 +406,6 @@ def paged_mixed_attention(
     #                          latent_q_block); else gcd(Q, q_block), the
     #                          convention of flash_attention's q_chunk,
     #                          where the chip takes its rows
-    page_size: int = 0,      # logical tokens per page; 0 = the pools'
-    #                          physical page dim (i.e. no row padding)
-    pages_per_step: int = 1,  # physical pages DMA'd per grid step
     interpret: bool = False,
 ) -> jax.Array:
     """out (S, Q, H, Dv) float32 — ragged mixed-step paged attention.
@@ -483,14 +422,6 @@ def paged_mixed_attention(
     page is decoded in VMEM (a codebook lookup times its per-token
     scale) before scoring.  Equivalent to decoding the whole pool
     up front, without ever materialising the fp pool.
-
-    Tiled pools: the kernel reads only the first ``D`` (``q``'s width)
-    feature columns of the key pool and ``D2`` of ``k2_pages``; a value
-    pool as wide as the key pool is read at ``D`` too (pools padded
-    together), any other at its own width ``Dv``, which is the output's
-    width.  ``page_size < k_pages.shape[1]`` declares the trailing
-    physical rows of every page to be layout padding.  Padding is thus
-    never computed on, and a padded pool gives the unpadded result.
     """
     s_n, qn, h, d = q.shape
     n_pages, page, kh, dk = k_pages.shape
@@ -500,38 +431,28 @@ def paged_mixed_attention(
         assert kh == 1 and v_pages is None and v_scales is None, (kh,)
         assert not window and not softcap_val, (window, softcap_val)
         w2, d2 = q2.shape[-1], k2_pages.shape[-1]
-        assert d2 >= w2, (d2, w2)
+        assert d2 == w2, (d2, w2)
         wv = d
     else:
-        dv = v_pages.shape[-1]
+        wv = v_pages.shape[-1]
         w2 = 0
-        wv = d if dv == dk else dv    # value columns read and returned
-    logical = page_size or page
-    assert 0 < logical <= page, (logical, page)
-    assert dk >= d, (dk, d)
+    assert dk == d, (dk, d)
     assert h % kh == 0, (h, kh)
     g = h // kh
-    c = max(int(pages_per_step), 1)
-    n_groups = -(-table.shape[1] // c)
-    if n_groups * c != table.shape[1]:
-        # pad the table with dummy-page entries so every grid step walks
-        # exactly c pages; the extra logical pages sit past the slot
-        # capacity, so every row of them is masked.
-        table = jnp.pad(table, ((0, 0), (0, n_groups * c - table.shape[1])))
     if latent:
-        qb = latent_q_block(qn, q_block, h, d, w2, logical)
+        qb = latent_q_block(qn, q_block, h, d, w2, page)
     else:
-        qb = gqa_q_block(qn, q_block, g, kh, d, wv, logical)
+        qb = gqa_q_block(qn, q_block, g, kh, d, wv, page)
     rows = qb * g
 
-    def walk(i, block):
-        # one BlockSpec per page of the group: page i of grid step j is
-        # physical page table[s, j * c + i]; Pallas pipelines the next
-        # step's c DMAs behind this step's compute.  The table is read as
-        # it stands: a clamp to the q block's live pages cost 6-10 % of a
-        # call in scalar work on a v5e, more than the DMAs it saved.
+    def walk(block):
+        # grid step j reads physical page table[s, j]; Pallas pipelines
+        # the next step's DMA behind this step's compute.  The table is
+        # read as it stands: a clamp to the q block's live pages cost
+        # 6-10 % of a call in scalar work on a v5e, more than the DMAs it
+        # saved.
         return pl.BlockSpec(
-            block, lambda s, qi, j, t, ln, ql: (t[s, j * c + i],)
+            block, lambda s, qi, j, t, ln, ql: (t[s, j],)
             + (0,) * (len(block) - 1))
 
     def rows_spec(width):
@@ -545,31 +466,30 @@ def paged_mixed_attention(
         return x.reshape(s_n, qn, kh, g, w).transpose(0, 2, 1, 3, 4) \
             .reshape(s_n, kh, qn * g, w)
 
-    in_specs = [rows_spec(d), *[walk(i, (1, page, kh, dk)) for i in range(c)]]
-    args = [by_kv_head(q), *[k_pages] * c]
+    in_specs = [rows_spec(d), walk((1, page, kh, d))]
+    args = [by_kv_head(q), k_pages]
     if latent:
-        in_specs += [rows_spec(w2),
-                     *[walk(i, (1, page, 1, d2)) for i in range(c)]]
-        args += [by_kv_head(q2), *[k2_pages] * c]
+        in_specs += [rows_spec(w2), walk((1, page, 1, w2))]
+        args += [by_kv_head(q2), k2_pages]
         scales = (k_scales, k2_scales)
     else:
-        in_specs += [walk(i, (1, page, kh, dv)) for i in range(c)]
-        args += [v_pages] * c
+        in_specs += [walk((1, page, kh, wv))]
+        args += [v_pages]
         scales = (k_scales, v_scales)
     if k_scales is not None:
         # one scale column (page, 1) per physical page, walked through the
         # page table exactly like the pools themselves; a column scales
         # the decoded page's rows without an in-kernel transpose
         for sc in scales:
-            in_specs += [walk(i, (1, page, 1)) for i in range(c)]
-            args += [sc.astype(jnp.float32).reshape(n_pages, page, 1)] * c
+            in_specs += [walk((1, page, 1))]
+            args += [sc.astype(jnp.float32).reshape(n_pages, page, 1)]
         in_specs += [pl.BlockSpec((1, kv_codec.ZERO_CODE),
                                   lambda s, qi, j, t, ln, ql: (0, 0))]
         args += [kv_codec.codebook()[None, kv_codec.ZERO_CODE:]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(s_n, -(-qn // qb), n_groups),
+        grid=(s_n, -(-qn // qb), table.shape[1]),
         in_specs=in_specs,
         out_specs=rows_spec(wv),
         scratch_shapes=[
@@ -579,10 +499,10 @@ def paged_mixed_attention(
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, logical=logical, c=c, kh=kh,
-                          g=g, qb=qb, widths=(d, wv, w2), window=window,
-                          softcap_val=softcap_val, scale=scale,
-                          has_codec=k_scales is not None, latent=latent),
+        functools.partial(_kernel, page=page, kh=kh, g=g, qb=qb,
+                          window=window, softcap_val=softcap_val,
+                          scale=scale, has_codec=k_scales is not None,
+                          latent=latent),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_n, kh, qn * g, wv), jnp.float32),
         name="paged_mixed_attention",
@@ -590,9 +510,8 @@ def paged_mixed_attention(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         # what kernel_launches reads back from a traced program
         metadata={k: str(v) for k, v in Launch(
-            qn=qn, qb=qb, n_pages=n_groups * c, logical=logical,
-            window=window, pages_per_step=c, q_block=q_block)
-            ._asdict().items()},
+            qn=qn, qb=qb, n_pages=table.shape[1], logical=page,
+            window=window)._asdict().items()},
         interpret=interpret,
     )(table.astype(jnp.int32), lengths.astype(jnp.int32),
       jnp.asarray(q_lens, jnp.int32), *args)
@@ -615,8 +534,6 @@ def paged_decode_attention(
     window: int = 0,
     softcap_val: float = 0.0,
     scale: float = 1.0,
-    page_size: int = 0,
-    pages_per_step: int = 1,
     interpret: bool = False,
 ) -> jax.Array:
     """out (S, H, Dv) float32 — single-token decode, the ``Q == 1``
@@ -628,6 +545,5 @@ def paged_decode_attention(
         None if q2 is None else q2[:, None], k2_pages,
         k_scales, v_scales, k2_scales,
         window=window, softcap_val=softcap_val, scale=scale,
-        page_size=page_size, pages_per_step=pages_per_step,
         interpret=interpret)
     return out[:, 0]

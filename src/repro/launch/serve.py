@@ -26,11 +26,9 @@ resident codes printed in the summary.  ``--prefix-share`` caches
 completed prefills' KV pages in a refcounted prefix index so requests
 extending a cached prefix (generate them with ``--shared-prefix-len``)
 map the shared pages and skip that prefill work — token-identical, with
-copy-on-write guarding every shared page.  ``--kernel-tune auto``
-hardware-tiles the page pools toward the TPU's (8, 128) register tiles
-and sweeps the kernel's ``(q_block, pages_per_step)`` launch shape on
-the live model/page-size (memoised per ``(arch, page, Q)``), again
-token-identical to ``off``.
+copy-on-write guarding every shared page.  The paged kernel's launch
+shape is worked out inside the kernel from the model's and the step's
+shapes.
 
 Observability: ``--trace-out trace.json`` records every request's
 lifecycle span tree (queued -> admitted -> prefill chunks -> decode ->
@@ -139,14 +137,6 @@ def parse_args(argv=None) -> argparse.Namespace:
                          "gather; ~4x resident-KV compression at a "
                          "bounded reconstruction error; needs "
                          "--kv-page-size)")
-    ap.add_argument("--kernel-tune", type=str, default=None,
-                    help="paged-attention kernel launch shape (needs "
-                         "--attn-backend pallas_paged): 'off' (default, "
-                         "identity layout), 'auto' (sweep (q_block, "
-                         "pages_per_step) on the live model/page shapes, "
-                         "memoised per (arch, page, Q), and serve with "
-                         "hardware-tiled pools), or explicit "
-                         "'QB[,PPS]' — all token-identical")
     ap.add_argument("--prefix-share", action="store_true",
                     help="cache completed prefills' KV pages in a prefix "
                          "index; requests extending a cached prefix map "
@@ -262,7 +252,6 @@ def serve(engine: ServeEngine, args: argparse.Namespace) -> dict:
                           attn_backend=args.attn_backend,
                           kv_codec=args.kv_codec,
                           prefix_share=args.prefix_share,
-                          kernel_tune=args.kernel_tune,
                           speculate=args.speculate,
                           draft_k=args.draft_k,
                           log_every=args.log_every)
@@ -319,14 +308,6 @@ def serve(engine: ServeEngine, args: argparse.Namespace) -> dict:
               f"installing prefilled caches, "
               f"{m.kv_prefill_gather_bytes_avoided} avoided by "
               f"mixed-step in-pool prefill")
-    if sched.kernel_tune != "off" and sched._pool is not None:
-        pool = sched._pool
-        print(f"kernel tune ({sched.kernel_tune}): q_block="
-              f"{pool.q_block or 'from shapes'} pages_per_step="
-              f"{pool.pages_per_step}, hardware-tiled pools "
-              f"({pool.page_size}-token pages padded to "
-              f"{pool.page_rows} rows), {m.kernel_qblock_rounded} "
-              f"q_block roundings")
     if sched.prefix_share:
         pool = sched._pool
         print(f"prefix share: {m.prefix_hits} hits, "

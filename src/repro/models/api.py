@@ -29,8 +29,7 @@ class ModelAPI:
     # when the family cannot resume a prompt mid-cache (encoder-decoder)
     mixed_step: Callable[..., Any] | None = None
     # (cfg, params, paged cache, table, tokens (S, Q), poss (S,),
-    #  q_lens (S,), *, paged_flags, page_size, q_block, pages_per_step,
-    #  interpret)
+    #  q_lens (S,), *, paged_flags, page_size, interpret)
     #   -> (logits (S, Q, V), new cache[, expert pairs if expert_load]);
     # the in-kernel half of the attention-backend seam: one ragged batched
     # trace where every slot contributes q_lens[s] tokens — a prefill
@@ -47,38 +46,6 @@ class ModelAPI:
     expert_load: bool = False
     # mixed_step appends each MoE block's pairs per held expert,
     # (n_moe_blocks, n_held) int32: the model has MoE blocks
-
-
-# TPU register tiles for f32 operands: the memory system moves (sublane,
-# lane) = (8, 128) blocks, so page pools padded toward these shapes DMA
-# at full bandwidth.  SlotPool pads the page (sublane) dim of every
-# pageable leaf to TILE_SUBLANE and its trailing feature (lane) dim to
-# TILE_LANE when hardware tiling is on; the kernel masks the page rows
-# and the zero feature columns fall out of the dot products exactly.
-TILE_SUBLANE = 8
-TILE_LANE = 128
-
-
-def round_up(n: int, tile: int) -> int:
-    return -(-n // tile) * tile
-
-
-def padded_page_dims(shape, len_axis: int, page_size: int,
-                     hw_tiles: bool) -> tuple[int, tuple[int, ...]]:
-    """Physical page layout for one pageable cache leaf.
-
-    ``shape`` is the leaf's spec shape and ``len_axis`` its
-    length-scaling axis (from :func:`cache_layout`).  Returns
-    ``(page_rows, feature_dims)``: the physical rows per page and the
-    (possibly lane-padded) dims trailing the page axis.  With
-    ``hw_tiles=False`` this is the identity layout — ``page_size``
-    logical rows, model-native features."""
-    feat = tuple(shape[len_axis + 1:])
-    if not hw_tiles:
-        return page_size, feat
-    if feat:
-        feat = (*feat[:-1], round_up(feat[-1], TILE_LANE))
-    return round_up(page_size, TILE_SUBLANE), feat
 
 
 # the attention backends the serving stack can decode with: "gathered"

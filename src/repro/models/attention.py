@@ -35,20 +35,11 @@ class PagedContext:
     hosts without a TPU.  Blocks whose leaves stay per-slot lanes
     (rolling-window KV, recurrent state) receive ``paged=None`` and run
     the gathered reference path on their lanes.
-
-    ``page_size`` is the *logical* positions-per-page constant; the pool
-    leaves themselves may carry hardware-tiled padding (page rows padded
-    to the sublane tile, trailing feature dim to the lane tile — see
-    ``api.padded_page_dims``), which :meth:`write` fills with zeros and
-    the kernel masks out.  ``q_block`` / ``pages_per_step`` are the
-    tuned kernel launch parameters (``runtime.autotune.tune_kernel``).
     """
 
     table: jax.Array         # (S, pages_per_slot) int32
     page_size: int
     interpret: bool = False
-    q_block: int = 0         # kernel query-block width (0 = from shapes)
-    pages_per_step: int = 1  # physical pages per kernel grid step
 
     def write(self, pool: jax.Array, values: jax.Array, pos,
               q_lens=None) -> jax.Array:
@@ -63,12 +54,6 @@ class PagedContext:
         kernel walks the table (per-token causal masks keep
         write-after-attend semantics)."""
         s_n, qn = values.shape[:2]
-        if values.shape[2:] != pool.shape[2:]:
-            # hardware-tiled pool: zero-fill the lane padding so padded
-            # feature columns decode/score to exactly 0
-            values = jnp.pad(values, [(0, 0), (0, 0)] + [
-                (0, dp - dv) for dp, dv in
-                zip(pool.shape[2:], values.shape[2:])])
         p = jnp.asarray(pos, jnp.int32)[:, None] \
             + jnp.arange(qn, dtype=jnp.int32)[None]           # (S, Q)
         lidx = jnp.clip(p // self.page_size, 0, self.table.shape[1] - 1)
@@ -450,9 +435,7 @@ def attn_apply(
             (q.astype(jnp.float32) * hd ** -0.5), k_pool, v_pool,
             paged.table, pos + ql, ql, window=window,
             softcap_val=cfg.attn_logit_softcap,
-            page_size=paged.page_size, q_block=paged.q_block,
-            pages_per_step=paged.pages_per_step,
-            interpret=paged.interpret, **kw)[..., :hd]
+            interpret=paged.interpret, **kw)
         y = out.reshape(b, s, -1).astype(x.dtype) @ p["wo"]
         new_cache = {"k": k_pool, "v": v_pool}
         if scales is not None:
@@ -683,9 +666,7 @@ def mla_apply(p, x, cfg, *, cache=None, pos=None, paged=None, q_lens=None,
             q_lat, c_pool[:, :, None], None,
             paged.table, pos + ql, ql,
             q_pe.astype(jnp.float32), pe_pool[:, :, None],
-            scale=scale, page_size=paged.page_size,
-            q_block=paged.q_block, pages_per_step=paged.pages_per_step,
-            interpret=paged.interpret, **kw)[..., :r_kv]
+            scale=scale, interpret=paged.interpret, **kw)
         w_uv = p["w_uv"].reshape(r_kv, h, dv)
         out = jnp.einsum("bshr,rhv->bshv", ctx,
                          w_uv.astype(jnp.float32),

@@ -525,7 +525,6 @@ def decode_step(cfg, params, cache, tokens, pos, *, kv_quant=False):
 
 def mixed_step(cfg, params, cache, table, tokens, poss, q_lens, *,
                paged_flags: tuple, page_size: int,
-               q_block: int = 0, pages_per_step: int = 1,
                interpret: bool = False, scales=None):
     """One mixed serving step for *every* slot straight over the paged KV
     pools: slot ``s`` contributes ``q_lens[s]`` consecutive tokens — a
@@ -573,8 +572,7 @@ def mixed_step(cfg, params, cache, table, tokens, poss, q_lens, *,
     flags = jax.tree_util.tree_unflatten(
         jax.tree_util.tree_structure(specs), list(paged_flags))
     ctx = attn.PagedContext(table=table, page_size=page_size,
-                            interpret=interpret, q_block=q_block,
-                            pages_per_step=pages_per_step)
+                            interpret=interpret)
     x = _embed_step(cfg, params, tokens)
     x, new_cache, new_scales, load = _run_stack(
         cfg, params, cache, x, pos=poss, flags=flags, ctx=ctx,

@@ -81,11 +81,7 @@ Compression on BNNs"), module by module:
   autotune             capacity recommendation: replay the materialize
                        access pattern over a capacity grid, find the
                        hit-rate-cliff knee (the launcher's
-                       ``--cache-mb auto``); kernel launch-shape tuning:
-                       time real paged-attention steps over a
-                       (q_block, pages_per_step) grid on the live
-                       model/page shapes, memoised per (arch, page, Q)
-                       (the launcher's ``--kernel-tune auto``).
+                       ``--cache-mb auto``).
   ===================  ====================================================
 
 The module <-> paper-structure mapping, with the request lifecycle
@@ -98,7 +94,7 @@ bit-identical (tests/test_runtime.py round-trip).
 """
 
 from repro.runtime.autotune import (find_knee, recommend_store_capacity,
-                                    sweep_store, tune_kernel)
+                                    sweep_store)
 from repro.runtime.decode_cache import (DecodeTileCache, EvictionPolicy,
                                         FrequencyWeightedPolicy, LFUPolicy,
                                         LRUPolicy, make_policy)
@@ -139,5 +135,4 @@ __all__ = [
     "parse_prom",
     "recommend_store_capacity",
     "sweep_store",
-    "tune_kernel",
 ]

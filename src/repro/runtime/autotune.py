@@ -1,4 +1,4 @@
-"""Serving-time autotuning: decode-cache capacity and kernel launch shapes.
+"""Serving-time autotuning of the decode-cache capacity.
 
 The paper's §IV working-set threshold reappears at serving time as a
 cliff in the decode-cache hit-rate-vs-capacity curve: below the decoded
@@ -16,19 +16,9 @@ touches every tile of every compressed layer) through fresh
 decoded working set — pure cache accounting, no tensor decodes, so the
 sweep costs microseconds even for models whose real materialize takes
 seconds.
-
-:func:`tune_kernel` does the same for the paged attention kernel's
-launch shape: it times real :func:`paged_mixed_attention` calls on a
-synthetic hardware-tiled pool matching the live model's head layout and
-page size, sweeping ``(q_block, pages_per_step)``, and memoises the
-winner per ``(arch, page, Q)`` key so a fleet of pools resolves the
-sweep once.
 """
 
 from __future__ import annotations
-
-import math
-import time
 
 from repro.runtime.decode_cache import DecodeTileCache
 
@@ -129,102 +119,3 @@ def recommend_store_capacity(store, model_id: str, *, steps: int = 8,
         "capacities": caps,
         "rates": rates,
     }
-
-
-# memoised tune_kernel winners per (arch, page, Q, codec): a fleet of
-# SlotPools (or repeated pool rebuilds on slot_len growth) resolves the
-# sweep once per launch-shape point
-_KERNEL_TUNE_CACHE: dict = {}
-
-DEFAULT_PAGES_PER_STEP = (1, 2, 4)
-
-
-def _divisors(n: int) -> list[int]:
-    return [d for d in range(1, n + 1) if n % d == 0]
-
-
-def tune_kernel(cfg, page_size: int, q: int, *, codec: bool = False,
-                interpret: bool = False, n_slots: int = 4,
-                pages_per_slot: int = 4,
-                q_blocks=None, pages_per_step=DEFAULT_PAGES_PER_STEP,
-                repeats: int = 3, seed: int = 0) -> dict:
-    """Pick ``(q_block, pages_per_step)`` for the paged attention kernel
-    on the live ``(arch, page, Q)`` point -> result dict.
-
-    Builds a synthetic hardware-tiled page pool matching ``cfg``'s head
-    layout (GQA: ``(KH, head_dim)`` pools; MLA: the shared latent /
-    rope-part pools) at ``page_size``, then times one compiled
-    ``paged_mixed_attention`` mixed step per candidate — real kernel,
-    real shapes, stand-in values — and returns the fastest launch
-    shape.  ``q_blocks`` defaults to the divisors of ``q`` (the kernel
-    rounds non-divisors down to a gcd, so sweeping them would double
-    count) whose ``qb * H / KH`` query rows the chip's block rule takes:
-    a multiple of the 8-row sublane tile, or all of Q.  Candidates are
-    timed best-of-``repeats`` after a warmup call that eats the
-    compile.
-
-    Returns ``q_block`` / ``pages_per_step`` (the winner), ``best_ms``,
-    the full ``timings`` list of ``(q_block, pages_per_step, ms)``,
-    ``key`` — the ``(arch, page, Q, codec)`` memoisation key — and
-    ``cached`` (True when a previous call already resolved this key).
-    """
-    import jax.numpy as jnp
-    import numpy as np
-
-    from repro.kernels import kv_codec
-    from repro.kernels.paged_attention import paged_mixed_attention
-    from repro.models.api import padded_page_dims
-
-    key = (getattr(cfg, "name", cfg.family), int(page_size), int(q),
-           bool(codec))
-    hit = _KERNEL_TUNE_CACHE.get(key)
-    if hit is not None:
-        return {**hit, "cached": True}
-
-    mla = bool(getattr(cfg, "kv_lora_rank", 0))
-    h = cfg.num_heads
-    kh, d = (1, cfg.kv_lora_rank) if mla else \
-        (max(cfg.num_kv_heads, 1), cfg.head_dim)
-    rows, (kh_p, d_p) = padded_page_dims((1, page_size, kh, d), 1,
-                                         page_size, True)
-    n_pages = n_slots * pages_per_slot + 1
-    rng = np.random.default_rng(seed)
-    pool = rng.normal(size=(n_pages, rows, kh_p, d_p)).astype(np.float32)
-    table = rng.permutation(np.arange(1, n_pages))[
-        :n_slots * pages_per_slot].reshape(n_slots, pages_per_slot)
-    table = table.astype(np.int32)
-    lengths = np.full((n_slots,), pages_per_slot * page_size, np.int32)
-    q_lens = np.full((n_slots,), q, np.int32)
-    qs = rng.normal(size=(n_slots, q, h, d)).astype(np.float32)
-    kw = {}
-    if codec:
-        codes, scales = kv_codec.encode(jnp.asarray(pool), axes=(-2, -1))
-        kw = dict(k_scales=scales, v_scales=scales)
-        pool = codes
-    pool = jnp.asarray(pool)
-
-    def run(qb, pps):
-        out = paged_mixed_attention(
-            qs, pool, pool, jnp.asarray(table), jnp.asarray(lengths),
-            jnp.asarray(q_lens), page_size=page_size, q_block=qb,
-            pages_per_step=pps, interpret=interpret, **kw)
-        out.block_until_ready()
-
-    timings = []
-    if q_blocks is None:
-        g = h // kh
-        q_blocks = [qb for qb in _divisors(q) if qb == q or qb * g % 8 == 0]
-    for qb in q_blocks:
-        for pps in pages_per_step:
-            run(qb, pps)                       # warmup: compile
-            best = math.inf
-            for _ in range(repeats):
-                t0 = time.perf_counter()
-                run(qb, pps)
-                best = min(best, time.perf_counter() - t0)
-            timings.append((qb, pps, best * 1e3))
-    qb, pps, ms = min(timings, key=lambda t: t[2])
-    res = {"q_block": qb, "pages_per_step": pps, "best_ms": ms,
-           "timings": timings, "key": key, "cached": False}
-    _KERNEL_TUNE_CACHE[key] = res
-    return res

@@ -113,11 +113,6 @@ class ServeMetrics:
     #                                    codec kept out of the pool
     kv_codec_error_bound: float = 0.0  # worst elementwise reconstruction
     #                                    error bound seen (max scale / 254)
-    kernel_qblock_rounded: int = 0     # mixed steps whose tuned q_block
-    #                                    the kernel could not run as
-    #                                    asked: a non-divisor of the
-    #                                    step's Q rounded to gcd(Q, qb),
-    #                                    or rows the chip refuses
     prefix_hits: int = 0               # admissions that mapped a cached
     #                                    prefix (prefix_share only)
     prefix_tokens_reused: int = 0      # prompt tokens served straight
@@ -229,12 +224,6 @@ class ServeMetrics:
         self.kv_codec_bytes_fp += fp_bytes
         self.kv_codec_bytes_resident += resident_bytes
         self.kv_bytes_avoided += fp_bytes - resident_bytes
-
-    def record_kernel_qblock_rounded(self) -> None:
-        """One mixed step served with a rounded ``q_block`` (the tuned
-        block width did not divide this step's ``Q``, or its rows were
-        a block the chip refuses)."""
-        self.kernel_qblock_rounded += 1
 
     def record_prefix_hit(self, tokens: int, chunks_avoided: int) -> None:
         """One admission that mapped a cached prefix: ``tokens`` prompt
@@ -431,9 +420,6 @@ class ServeMetrics:
             parts.append(
                 f"kv codec {self.kv_capacity_multiplier():.2f}x "
                 f"(avoided {_fmt_bytes(self.kv_bytes_avoided)})")
-        if self.kernel_qblock_rounded:
-            parts.append(
-                f"qblock rounded {self.kernel_qblock_rounded}")
         if self.prefix_hits:
             parts.append(
                 f"prefix {self.prefix_hits} hits "
@@ -492,8 +478,6 @@ class ServeMetrics:
                  "resident KV page bytes compressed (codec step sum)"),
                 ("kv_bytes_avoided",
                  "KV pool bytes the codec kept out of HBM"),
-                ("kernel_qblock_rounded",
-                 "mixed steps run with a rounded q_block"),
                 ("prefix_hits",
                  "admissions that mapped a cached prefix"),
                 ("prefix_tokens_reused",

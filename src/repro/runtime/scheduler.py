@@ -84,7 +84,7 @@ from repro.kernels.kv_codec import KV_CODECS
 from repro.kernels.paged_attention import kernel_launches
 from repro.kernels.platform import interpret_mode
 from repro.models.api import (ATTN_BACKENDS, cache_layout, get_model,
-                              padded_page_dims, supports_chunked_prefill,
+                              supports_chunked_prefill,
                               supports_paged_attention,
                               supports_prefix_share, supports_speculation)
 from repro.runtime import weight_store as ws_mod
@@ -111,26 +111,6 @@ def _warn_fallback(family: str, capability: str, message: str) -> None:
         return
     _FALLBACK_WARNED.add(key)
     warnings.warn(message, RuntimeWarning, stacklevel=3)
-
-
-# the kernel rounds a q_block that does not divide this step's Q down to
-# gcd(Q, q_block), and replaces one whose rows the chip refuses with the
-# block sized from the shapes; every rounded step bumps
-# kernel_qblock_rounded, the first one per (Q, q_block) also warns so the
-# degraded launch shape is impossible to miss
-_QBLOCK_WARNED: set = set()
-
-
-def _warn_qblock_rounded(qn: int, q_block: int, qb: int) -> None:
-    key = (qn, q_block)
-    if key in _QBLOCK_WARNED:
-        return
-    _QBLOCK_WARNED.add(key)
-    warnings.warn(
-        f"kernel q_block={q_block} does not divide this step's Q={qn} "
-        f"into blocks the kernel takes; running {qb}-token blocks "
-        "(counted in kernel_qblock_rounded)", RuntimeWarning,
-        stacklevel=3)
 
 
 @dataclasses.dataclass
@@ -358,22 +338,14 @@ class ServeEngine:
             supports_paged_attention(self.cfg)
 
     def mixed_step(self, params, kcache, table, toks, poss, q_lens, *,
-                   paged_flags: tuple, page_size: int, q_block: int = 0,
-                   pages_per_step: int = 1, kv_scales=None):
+                   paged_flags: tuple, page_size: int, kv_scales=None):
         """One ragged mixed step for every slot straight over the paged
         pools: toks (S, Q) int32, poss (S,) int32 start positions, q_lens
         (S,) int32 real token counts (0 = free lane) -> (logits (S, Q, V),
         new cache tree).  ``kcache`` is donated — the page-pool update
         happens in place, with no gather/scatter anywhere on the prefill
-        or decode path.
-
-        ``q_block`` / ``pages_per_step`` are the tuned kernel launch
-        parameters (``runtime.autotune.tune_kernel``); a ``q_block``
-        the kernel cannot run at this step's ``Q`` (a non-divisor, or
-        rows the chip refuses) is rounded inside the kernel, so the
-        rounding is counted (``kernel_qblock_rounded``) and warned once
-        here, from the launches the traced step records
-        (:meth:`paged_launches`).
+        or decode path.  The kernel launches of the step's first trace
+        are kept for :meth:`paged_launches`.
 
         ``kv_scales`` (``kv_codec="cluster"``): the scale-pool tree
         riding alongside int8 code pools; it is donated too and the
@@ -382,45 +354,34 @@ class ServeEngine:
         expert, (n_moe_blocks, n_held) int32, last."""
         codec = kv_scales is not None
         qn = int(toks.shape[1])
-        key = (paged_flags, page_size, qn, codec, q_block, pages_per_step)
+        key = (paged_flags, page_size, qn, codec)
         fn = self.mixed_step_fn(*key)
         args = (params, kcache, table, toks, poss, q_lens) + \
             ((kv_scales,) if codec else ())
-        launches = self._mixed_launches.get(key)
-        if launches is None:
+        if key not in self._mixed_launches:
             # the step's one trace, which the call below reuses
-            launches = self._mixed_launches[key] = kernel_launches(
+            self._mixed_launches[key] = kernel_launches(
                 fn.trace(*args).jaxpr)
-        rounded = [ln for ln in launches if ln.rounded]
-        if rounded:
-            # a whole-Q block (e.g. decode's Q=1) is nothing degraded;
-            # only a launch that ran another block than asked counts
-            self.metrics.record_kernel_qblock_rounded()
-            _warn_qblock_rounded(qn, q_block, rounded[0].qb)
         return fn(*args)
 
     def paged_launches(self, paged_flags: tuple, page_size: int, qn: int,
-                       codec: bool, q_block: int = 0,
-                       pages_per_step: int = 1):
+                       codec: bool):
         """Counter of the paged-attention kernel launches one mixed step
         of this key makes (``kernels.paged_attention.kernel_launches``),
         read from its trace; None before the step has first run."""
-        return self._mixed_launches.get(
-            (paged_flags, page_size, qn, codec, q_block, pages_per_step))
+        return self._mixed_launches.get((paged_flags, page_size, qn, codec))
 
     def mixed_step_fn(self, paged_flags: tuple, page_size: int, qn: int,
-                      codec: bool, q_block: int = 0,
-                      pages_per_step: int = 1):
-        """The jitted mixed step for one (layout, block width, codec,
-        launch shape) key, built on first use.  Its arguments are
-        ``mixed_step``'s, with the scale tree last under the codec."""
-        key = (paged_flags, page_size, qn, codec, q_block, pages_per_step)
+                      codec: bool):
+        """The jitted mixed step for one (layout, block width, codec) key,
+        built on first use.  Its arguments are ``mixed_step``'s, with the
+        scale tree last under the codec."""
+        key = (paged_flags, page_size, qn, codec)
         fn = self._mixed_jits.get(key)
         if fn is None:
             step = functools.partial(
                 self.api.mixed_step, self.cfg,
                 paged_flags=paged_flags, page_size=page_size,
-                q_block=q_block, pages_per_step=pages_per_step,
                 interpret=self.kernel_interpret)
             if codec:
                 fn = jax.jit(
@@ -631,20 +592,12 @@ class SlotPool:
                  backend: str = "gathered",
                  page_capacity: int | None = None,
                  kv_codec: str = "none",
-                 prefix_share: bool = False,
-                 q_block: int = 0,
-                 pages_per_step: int = 1,
-                 hw_tiles: bool = False):
+                 prefix_share: bool = False):
         if backend not in ATTN_BACKENDS:
             raise ValueError(f"unknown attention backend {backend!r}")
         if kv_codec not in KV_CODECS:
             raise ValueError(f"unknown kv codec {kv_codec!r}; "
                              f"choose from {KV_CODECS}")
-        if (hw_tiles or pages_per_step != 1 or q_block) and \
-                backend != "pallas_paged":
-            raise ValueError("hw_tiles / pages_per_step / q_block shape "
-                             "the pallas_paged kernel launch; the "
-                             f"{backend!r} backend does not consume them")
         self.engine = engine
         self.n_slots = n_slots
         self.page_size = page_size
@@ -652,9 +605,6 @@ class SlotPool:
         self.backend = backend
         self.kv_codec = kv_codec
         self.codec = kv_codec == "cluster"
-        self.q_block = q_block
-        self.pages_per_step = max(int(pages_per_step), 1)
-        self.hw_tiles = hw_tiles
         self.prefix_share = prefix_share
         self.prefix: PrefixIndex | None = None
         if backend == "pallas_paged" and not self.paged:
@@ -756,14 +706,6 @@ class SlotPool:
             # place with the batch-1 axis dropped; lane leaves carry the
             # slot axis where batch sat, so the paged decode runs all
             # slots in one batched trace
-            # hardware tiling pads each pool's page (sublane) dim and
-            # trailing feature (lane) dim toward the (8, 128) register
-            # tiles; the padding is layout-only — write() zero-fills it,
-            # the kernel masks the extra rows, and zero feature columns
-            # drop out of every dot product exactly
-            self.page_rows = padded_page_dims(
-                (page_size,), 0, page_size, hw_tiles)[0] \
-                if self.paged else page_size
             kleaves, sleaves = [], []
             # lane leaves under this backend are rolling-window KV: the
             # slot axis sits where batch sat (bax) and the W rolling rows
@@ -780,13 +722,12 @@ class SlotPool:
                 if ax is not None:
                     assert bax == ax - 1 and sa.shape[bax] == 1, \
                         (sa.shape, ax, bax)
-                    rows, feat = padded_page_dims(sa.shape, ax, page_size,
-                                                  hw_tiles)
                     kleaves.append(jnp.zeros(
-                        (*sa.shape[:ax - 1], cap, rows, *feat),
+                        (*sa.shape[:ax - 1], cap, page_size,
+                         *sa.shape[ax + 1:]),
                         jnp.int8 if self.codec else sa.dtype))
                     sleaves.append(jnp.zeros(
-                        (*sa.shape[:ax - 1], cap, rows), jnp.float32)
+                        (*sa.shape[:ax - 1], cap, page_size), jnp.float32)
                         if self.codec else None)
                 else:
                     kleaves.append(jnp.zeros(
@@ -946,22 +887,10 @@ class SlotPool:
                                     *src.shape[ax + 1:])
                     idx = (slice(None),) * (ax - 1) + (row,)
                     if codec:
-                        # page axis sits at ax, features trail it; encode
-                        # before padding so zero-padded codes decode to
-                        # exactly 0 under the zero-centred codebook
+                        # page axis sits at ax, features trail it
                         v, sc = kv_codec_mod.encode(
                             v, tuple(range(ax + 1, v.ndim)))
-                        if sc.shape[-1] != sleaf.shape[-1]:
-                            sc = jnp.pad(sc, [(0, 0)] * (sc.ndim - 1)
-                                         + [(0, sleaf.shape[-1]
-                                             - sc.shape[-1])])
                         sleaf = sleaf.at[idx].set(sc)
-                    if v.shape[ax:] != leaf.shape[ax:]:
-                        # hardware-tiled pool: zero-fill the sublane (page
-                        # row) and lane (trailing feature) padding
-                        target = (*v.shape[:ax], *leaf.shape[ax:])
-                        v = jnp.pad(v, [(0, dp - dv) for dp, dv
-                                        in zip(target, v.shape)])
                 else:
                     v = jnp.squeeze(src, axis=bax)
                     idx = (slice(None),) * bax + (i,)
@@ -1411,8 +1340,7 @@ class SlotPool:
             params, self.kcache, jnp.asarray(self.table),
             jnp.asarray(toks, dtype=jnp.int32), jnp.asarray(poss),
             jnp.asarray(q_lens), paged_flags=self.paged_flags,
-            page_size=self.page_size, q_block=self.q_block,
-            pages_per_step=self.pages_per_step, **kw)
+            page_size=self.page_size, **kw)
         load = out[-1] if self.engine.api.expert_load else None
         logits, self.kcache = out[:2]
         if self.codec:
@@ -1425,8 +1353,7 @@ class SlotPool:
         with ``q_lens`` real tokens, summed over the kernel calls its
         trace records (``ServeEngine.paged_launches``)."""
         launches = self.engine.paged_launches(
-            self.paged_flags, self.page_size, width, self.codec,
-            self.q_block, self.pages_per_step)
+            self.paged_flags, self.page_size, width, self.codec)
         lengths = np.asarray(poss) + np.asarray(q_lens)
         walked = live = 0
         for launch, n in launches.items():
@@ -1441,8 +1368,7 @@ class SlotPool:
         caller inspects to see which kernels the step holds."""
         assert self.backend == "pallas_paged"
         fn = self.engine.mixed_step_fn(self.paged_flags, self.page_size,
-                                       width, self.codec, self.q_block,
-                                       self.pages_per_step)
+                                       width, self.codec)
         blk = jnp.zeros((self.n_slots, width), jnp.int32)
         lens = jnp.zeros((self.n_slots,), jnp.int32)
         args = (params, self.kcache, jnp.asarray(self.table), blk, lens,
@@ -1557,7 +1483,6 @@ class Scheduler:
                  attn_backend: str = "gathered",
                  kv_codec: str = "none",
                  prefix_share: bool = False,
-                 kernel_tune: str | None = None,
                  speculate: str = "off", draft_k: int = 4,
                  log_every: int = 0, emit: Callable[[str], None] = print):
         if mode not in ("continuous", "wave"):
@@ -1585,19 +1510,6 @@ class Scheduler:
         if prefix_share and prefill_chunk is None:
             raise ValueError("prefix_share skips prefill chunk by chunk; "
                              "set prefill_chunk")
-        kernel_tune = kernel_tune or "off"
-        if kernel_tune != "off" and attn_backend != "pallas_paged":
-            raise ValueError("kernel_tune shapes the pallas_paged kernel "
-                             "launch; set attn_backend='pallas_paged' or "
-                             "leave it 'off'")
-        if kernel_tune not in ("auto", "off"):
-            try:
-                parts = [int(p) for p in kernel_tune.split(",")]
-                assert 1 <= len(parts) <= 2 and min(parts) >= 0
-            except (ValueError, AssertionError):
-                raise ValueError(
-                    f"unknown kernel_tune {kernel_tune!r}; choose 'auto', "
-                    "'off', or explicit 'Q_BLOCK[,PAGES_PER_STEP]'")
         self.engine = engine
         self.batch_size = batch_size
         self.buckets = tuple(sorted(buckets))
@@ -1611,7 +1523,6 @@ class Scheduler:
         self.attn_backend = attn_backend
         self.kv_codec = kv_codec
         self.prefix_share = prefix_share
-        self.kernel_tune = kernel_tune
         self.speculate = speculate or "off"
         self.draft_k = int(draft_k)
         self.drafter = None
@@ -1647,7 +1558,6 @@ class Scheduler:
         if attn_backend == "pallas_paged" and \
                 not engine.supports_paged_attention:
             self.attn_backend = "gathered"
-            self.kernel_tune = "off"
             _warn_fallback(
                 engine.cfg.family, "paged_attention",
                 f"{engine.cfg.family} arch downgraded to the gathered "
@@ -1711,46 +1621,14 @@ class Scheduler:
                 self._pool.n_slots != self.batch_size:
             slot_len = max(slot_len, self._pool.slot_len if self._pool
                            else 0)
-            q_block, pages_per_step, hw_tiles = \
-                self._resolve_kernel_tune(slot_len)
             self._pool = SlotPool(eng, self.batch_size, slot_len,
                                   page_size=self.kv_page_size,
                                   n_pages=self.kv_pages,
                                   backend=self.attn_backend,
                                   page_capacity=self.kv_page_capacity,
                                   kv_codec=self.kv_codec,
-                                  prefix_share=self.prefix_share,
-                                  q_block=q_block,
-                                  pages_per_step=pages_per_step,
-                                  hw_tiles=hw_tiles)
+                                  prefix_share=self.prefix_share)
         return self._pool
-
-    def _resolve_kernel_tune(self, slot_len: int) -> tuple[int, int, bool]:
-        """``kernel_tune`` -> (q_block, pages_per_step, hw_tiles) for the
-        pool about to be built.
-
-        ``"off"`` keeps the identity layout (no padding, one page per
-        grid step, q blocks sized from the shapes); any other value
-        turns hardware tiling on.  ``"auto"`` sweeps the live ``(arch,
-        page, Q)`` point through :func:`runtime.autotune.tune_kernel`
-        (memoised per key);
-        ``"QB[,PPS]"`` pins the launch shape explicitly."""
-        if self.kernel_tune == "off" or self.attn_backend != "pallas_paged":
-            return 0, 1, False
-        if self.kernel_tune != "auto":
-            parts = [int(p) for p in self.kernel_tune.split(",")]
-            return parts[0], parts[1] if len(parts) > 1 else 1, True
-        from repro.runtime.autotune import tune_kernel
-        width = min(self.prefill_chunk, slot_len) \
-            if self.prefill_chunk else 1
-        res = tune_kernel(self.engine.cfg, self.kv_page_size, width,
-                          codec=self.kv_codec == "cluster",
-                          interpret=self.engine.kernel_interpret)
-        self.emit(f"kernel autotune {res['key']}: q_block={res['q_block']} "
-                  f"pages_per_step={res['pages_per_step']} "
-                  f"({res['best_ms']:.3f} ms/step"
-                  f"{', cached' if res['cached'] else ''})")
-        return res["q_block"], res["pages_per_step"], True
 
     # -- serving -----------------------------------------------------------
     def run(self) -> list[Request]:

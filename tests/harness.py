@@ -24,11 +24,13 @@ MIXED = [(5, 7), (12, 2), (20, 5), (6, 9), (3, 1), (9, 4)]
 
 
 def make_engine(arch="minitron-8b", seed=0, **engine_kw):
-    """ServeEngine over a reduced config with compressed MLPs."""
+    """ServeEngine over a reduced float32 config, with compressed MLPs
+    unless ``compress=False``."""
     cfg = reduced(arch)
     params = jax.tree_util.tree_map(
         np.asarray, get_model(cfg).init_params(cfg, jax.random.PRNGKey(seed)))
-    return ServeEngine(cfg, params, compress=True, **engine_kw)
+    engine_kw.setdefault("compress", True)
+    return ServeEngine(cfg, params, **engine_kw)
 
 
 def mixed_requests(engine, trace=MIXED, seed=7):

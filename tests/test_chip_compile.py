@@ -74,7 +74,7 @@ def test_paged_kernel_compiles_at_gemma2_widths(one_chip, qn, codec):
             _sds((SLOTS, PAGES_PER_SLOT), jnp.int32, one_chip),
             _sds((SLOTS,), jnp.int32, one_chip),
             _sds((SLOTS,), jnp.int32, one_chip)]
-    kw = dict(softcap_val=cfg.attn_logit_softcap, page_size=PAGE)
+    kw = dict(softcap_val=cfg.attn_logit_softcap)
     if codec == "cluster":
         args += [_sds((N_PAGES, PAGE), jnp.float32, one_chip)] * 2
 
@@ -111,10 +111,10 @@ def test_paged_kernel_compiles_at_phi3_medium_widths(one_chip, qn, codec):
 
         def fn(q, k, v, t, ln, ql, ks, vs):
             return paged_mixed_attention(q, k, v, t, ln, ql, k_scales=ks,
-                                         v_scales=vs, page_size=PAGE)
+                                         v_scales=vs)
     else:
         def fn(q, k, v, t, ln, ql):
-            return paged_mixed_attention(q, k, v, t, ln, ql, page_size=PAGE)
+            return paged_mixed_attention(q, k, v, t, ln, ql)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -122,10 +122,10 @@ def test_paged_kernel_compiles_at_phi3_medium_widths(one_chip, qn, codec):
 @pytest.mark.parametrize("q_block", [4, 32])
 @pytest.mark.parametrize("arch", ["phi3-medium-14b", "gemma2-2b"])
 def test_explicit_q_block_compiles_at_verify_width(one_chip, arch, q_block):
-    """A q block pinned for the chunk width (``--kernel-tune``) applied
-    at a 1 + 4 speculative verify: ``gcd(5, q_block) = 1`` token is 4 or
-    2 query rows a KV head, which the chip refuses, so the kernel runs
-    the block sized from the shapes instead."""
+    """A q block pinned for the chunk width applied at a 1 + 4
+    speculative verify: ``gcd(5, q_block) = 1`` token is 4 or 2 query
+    rows a KV head, which the chip refuses, so the kernel runs the block
+    sized from the shapes instead."""
     cfg = get_config(arch)
     h, kh, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     qn = 5
@@ -139,7 +139,7 @@ def test_explicit_q_block_compiles_at_verify_width(one_chip, arch, q_block):
     def fn(q, k, v, t, ln, ql):
         return paged_mixed_attention(
             q, k, v, t, ln, ql, softcap_val=cfg.attn_logit_softcap,
-            window=cfg.window, q_block=q_block, page_size=PAGE)
+            window=cfg.window, q_block=q_block)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -195,8 +195,8 @@ def test_full_mixed_step_compiles_and_fits(one_chip, qn, codec):
 @pytest.mark.parametrize("qn", [1, 16])
 def test_latent_kernel_compiles_at_deepseek_v2_widths(one_chip, qn):
     """MLA's shared latent head at DeepSeek-V2's widths: 128 query heads
-    over one 512-wide latent (the value pool too) and a 64-wide rope key
-    padded to a lane tile, 64-token pages, 128 slots of 8 pages -- every
+    over one 512-wide latent (the value pool too) and a 64-wide rope key,
+    64-token pages, 128 slots of 8 pages -- every
     head of a q block in one product per page, within scoped VMEM."""
     cfg = get_config("deepseek-v2-236b")
     h, r, dr = cfg.num_heads, cfg.kv_lora_rank, cfg.rope_head_dim
@@ -208,10 +208,10 @@ def test_latent_kernel_compiles_at_deepseek_v2_widths(one_chip, qn):
             _sds((slots,), jnp.int32, one_chip),
             _sds((slots,), jnp.int32, one_chip),
             _sds((slots, qn, h, dr), jnp.float32, one_chip),
-            _sds((n_pages, page, 1, 128), jnp.bfloat16, one_chip)]
+            _sds((n_pages, page, 1, dr), jnp.bfloat16, one_chip)]
 
     def fn(q, c, t, ln, ql, q2, pe):
         return paged_mixed_attention(q, c, None, t, ln, ql, q2, pe,
-                                     scale=0.1, page_size=page)
+                                     scale=0.1)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()

@@ -194,8 +194,7 @@ class TestMixedStepHotPath:
             walked += paged_layers * 2 * pool.pages_per_slot
             live += paged_layers * _brute_live_steps(
                 poss + q_lens, q_lens, qn=qn, qb=qn,
-                n_pages=pool.pages_per_slot, logical=page, window=0,
-                pages_per_step=1)
+                n_pages=pool.pages_per_slot, logical=page, window=0)
         m = engine.metrics
         assert (m.attn_grid_steps, m.attn_grid_steps_live) == (walked, live)
         assert 0 < live < walked

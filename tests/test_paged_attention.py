@@ -12,11 +12,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.paged_attention import (Launch, gqa_q_block,
-                                           kernel_launches, live_grid_steps,
+from repro.configs.base import get_config
+from repro.kernels import kv_codec
+from repro.kernels.paged_attention import (VMEM_BUDGET, Launch,
+                                           effective_q_block, gqa_q_block,
+                                           kernel_launches, latent_q_block,
+                                           live_grid_steps,
                                            paged_decode_attention,
-                                           paged_mixed_attention)
-from repro.models.api import supports_paged_attention
+                                           paged_mixed_attention,
+                                           q_row_bytes)
+from repro.models.api import (cache_layout, get_model,
+                              supports_paged_attention)
 from repro.models.attention import decode_attention
 from repro.runtime import Scheduler
 from tests.harness import MIXED, make_engine, mixed_requests
@@ -125,18 +131,18 @@ class TestKernelVsOracle:
             np.testing.assert_allclose(np.asarray(out[i]), p @ c,
                                        rtol=2e-5, atol=2e-5)
 
-    @pytest.mark.parametrize("g,qn,window,softcap,codec,pps", [
-        (1, 1, 0, 0.0, False, 1),        # decode, one query head a KV head
-        (2, 6, 0, 0.0, False, 1),        # chunk program, whole-Q block
-        (4, 40, 0, 0.0, False, 1),       # 160 rows: blocks of 32, Q % 32
-        (8, 20, 0, 0.0, False, 2),       # 160 rows: blocks of 16, 2 pages
-        (2, 6, 5, 3.0, False, 1),        # gemma2-like window and softcap
-        (4, 40, 9, 0.0, False, 3),       # window over two q blocks
-        (4, 6, 0, 0.0, True, 1),         # KV codec
-        (8, 20, 7, 2.0, True, 2),        # everything at once
+    @pytest.mark.parametrize("g,qn,window,softcap,codec", [
+        (1, 1, 0, 0.0, False),        # decode, one query head a KV head
+        (2, 6, 0, 0.0, False),        # chunk program, whole-Q block
+        (4, 40, 0, 0.0, False),       # 160 rows: blocks of 32, Q % 32
+        (8, 20, 0, 0.0, False),       # 160 rows: blocks of 16
+        (2, 6, 5, 3.0, False),        # gemma2-like window and softcap
+        (4, 40, 9, 0.0, False),       # window over two q blocks
+        (4, 6, 0, 0.0, True),         # KV codec
+        (8, 20, 7, 2.0, True),        # everything at once
     ])
     def test_gqa_blocks_vs_gathered_reference(self, g, qn, window, softcap,
-                                              codec, pps):
+                                              codec):
         """Every KV head's query heads in one product per page, against
         the gathered reference token by token: a full chunk beside decode
         slots (q_lens 1), a free slot (q_lens 0), a partial chunk, and
@@ -161,7 +167,6 @@ class TestKernelVsOracle:
         q = rng.standard_normal((s_n, qn, h, d)).astype(np.float32)
         kw = {}
         if codec:
-            from repro.kernels import kv_codec
             k_in, ks = kv_codec.encode(jnp.asarray(k_pages), axes=(-2, -1))
             v_in, vs = kv_codec.encode(jnp.asarray(v_pages), axes=(-2, -1))
             k_pages = np.asarray(kv_codec.decode(k_in, ks[..., None, None]))
@@ -172,7 +177,7 @@ class TestKernelVsOracle:
         out = np.asarray(paged_mixed_attention(
             jnp.asarray(q) * d ** -0.5, k_in, v_in, jnp.asarray(table),
             jnp.asarray(lengths), jnp.asarray(q_lens), window=window,
-            softcap_val=softcap, pages_per_step=pps, interpret=True, **kw))
+            softcap_val=softcap, interpret=True, **kw))
         assert np.isfinite(out).all()
         smax = pages_per_slot * page
         k_view = jnp.asarray(k_pages[table].reshape(s_n, smax, kh, d))
@@ -215,48 +220,75 @@ class TestKernelVsOracle:
         np.testing.assert_array_equal(clean, poisoned)
 
 
+class TestDequantGather:
+    """The in-kernel half-codebook lane gather vs the full table."""
 
-def _brute_live_steps(lengths, q_lens, *, qn, qb, n_pages, logical, window,
-                      pages_per_step):
+    def test_gather_bitwise_matches_onehot(self):
+        """The codec kernel equals the fp kernel over the pool decoded
+        through the full 256-entry codebook, bit for bit: the kernel's
+        magnitude lookup in the non-negative half, negated for negative
+        codes, is the same table entry."""
+        rng = np.random.default_rng(5)
+        s, kh, d, dv, page, pages = 3, 2, 16, 16, 4, 4
+        q_lens = np.array([2, 4, 1], np.int32)
+        k, v, table, lengths = random_paged_cache(rng, s, kh, d, dv, page,
+                                                  pages)
+        lengths = np.maximum(lengths, q_lens)
+        ck, ks = kv_codec.encode(jnp.asarray(k), axes=(-2, -1))
+        cv, vs = kv_codec.encode(jnp.asarray(v), axes=(-2, -1))
+        q = rng.normal(size=(s, 4, 4, d)).astype(np.float32)
+        cb = np.asarray(kv_codec.codebook())
+
+        def table_decode(c, sc):
+            return cb[np.asarray(c, np.int32) + kv_codec.ZERO_CODE] \
+                * np.asarray(sc)[:, :, None, None]
+
+        a = np.asarray(paged_mixed_attention(
+            q, ck, cv, table, lengths, q_lens, k_scales=ks, v_scales=vs,
+            interpret=True))
+        b = np.asarray(paged_mixed_attention(
+            q, table_decode(ck, ks), table_decode(cv, vs), table, lengths,
+            q_lens, interpret=True))
+        np.testing.assert_array_equal(a, b)
+
+
+def _brute_live_steps(lengths, q_lens, *, qn, qb, n_pages, logical,
+                      window):
     """Grid steps that compute, cell by cell: a q block holding a real
-    query, and a page of its group holding a key at a position below
-    the slot's length that the block's first query's window (if any)
-    reaches."""
-    c = pages_per_step
+    query, and a page holding a key at a position below the slot's
+    length that the block's first query's window (if any) reaches."""
     live = 0
     for length, qlen in zip(lengths, q_lens):
         for qi in range(-(-qn // qb)):
             if qi * qb >= qlen:
                 continue
             first = length - qlen + qi * qb
-            for j in range(-(-n_pages // c)):
-                keys = [p for lp in range(j * c, j * c + c)
-                        for p in range(lp * logical, (lp + 1) * logical)
+            for j in range(n_pages):
+                keys = [p for p in range(j * logical, (j + 1) * logical)
                         if p < length and (not window or p > first - window)]
                 live += bool(keys)
     return live
 
 
 class TestLiveGridSteps:
-    @pytest.mark.parametrize("window,pps,qb", [(0, 1, 1), (0, 3, 4),
-                                               (5, 1, 2), (9, 2, 3),
-                                               (4, 4, 8)])
-    def test_matches_brute_force(self, window, pps, qb):
-        rng = np.random.default_rng(window * 10 + pps)
+    @pytest.mark.parametrize("window,qb", [(0, 1), (0, 4), (5, 2), (9, 3),
+                                           (4, 8)])
+    def test_matches_brute_force(self, window, qb):
+        rng = np.random.default_rng(window * 10 + qb)
         qn, logical, n_pages = 8, 4, 10
         q_lens = rng.integers(0, qn + 1, 64)
         q_lens[:4] = [0, 1, qn, 1]
         lengths = q_lens + rng.integers(0, n_pages * logical - qn + 1, 64)
         lengths[:4] = [0, 12, 40, 1]
         kw = dict(qn=qn, qb=qb, n_pages=n_pages, logical=logical,
-                  window=window, pages_per_step=pps)
+                  window=window)
         walked, live = live_grid_steps(lengths, q_lens, **kw)
-        assert walked == 64 * -(-qn // qb) * -(-n_pages // pps)
+        assert walked == 64 * -(-qn // qb) * n_pages
         assert live == _brute_live_steps(lengths, q_lens, **kw)
         assert 0 < live < walked
 
-    @pytest.mark.parametrize("window,pps", [(0, 1), (6, 1), (6, 2)])
-    def test_kernel_folds_exactly_the_live_pages(self, window, pps):
+    @pytest.mark.parametrize("window", [0, 6, 3])
+    def test_kernel_folds_exactly_the_live_pages(self, window):
         """The kernel computes on the pages the rule calls live and on no
         other.  Two readings show it.  A padding row of a live q block
         masks every key, so each page folded into it adds weight one to
@@ -264,7 +296,7 @@ class TestLiveGridSteps:
         pages computed.  And NaN values in every page the rule leaves
         dead for all of a slot's q blocks (the dummy sink, pages a window
         has passed) reach no output: neither read nor computed on."""
-        rng = np.random.default_rng(window + pps)
+        rng = np.random.default_rng(window + 1)
         kh, g, d, page, pages_per_slot, qn = 2, 2, 8, 4, 8, 3
         q_lens = np.array([1, 2, 0, 1, 3], np.int32)
         lengths = np.array([30, 8, 0, 17, 21], np.int32)
@@ -286,8 +318,7 @@ class TestLiveGridSteps:
             return np.asarray(paged_mixed_attention(
                 q, jnp.asarray(k_pages), jnp.asarray(vp),
                 jnp.asarray(table), jnp.asarray(lengths),
-                jnp.asarray(q_lens), window=window, pages_per_step=pps,
-                interpret=True))
+                jnp.asarray(q_lens), window=window, interpret=True))
 
         clean = run(v_pages)
         poisoned = v_pages.copy()
@@ -298,7 +329,7 @@ class TestLiveGridSteps:
                 if table[s, j] and window and (j + 1) * page <= opens:
                     poisoned[table[s, j]] = np.nan
         assert np.isnan(poisoned).any(axis=(1, 2, 3)).sum() == \
-            (12 if window else 1)
+            {0: 1, 6: 12, 3: 15}[window]
         np.testing.assert_array_equal(run(poisoned), clean)
         checked = 0
         for s in np.flatnonzero((q_lens > 0) & (q_lens < qn)):
@@ -339,13 +370,12 @@ class TestLiveGridSteps:
         assert gqa_q_block(64, 4, **gemma2) == 4
         assert gqa_q_block(1, 32, **phi3) == 1
 
-    @pytest.mark.parametrize("q_block,want_qb,rounded", [
-        (0, 5, False),       # sized from the shapes
-        (5, 5, False),       # asked and run
-        (4, 5, True),        # gcd 1: 2 rows a KV head, refused
-        (10, 5, False)])     # gcd 5 is all of Q: nothing degraded
-    def test_kernel_launches_read_from_the_trace(self, q_block, want_qb,
-                                                 rounded):
+    @pytest.mark.parametrize("q_block,want_qb", [
+        (0, 5),       # sized from the shapes
+        (5, 5),       # asked and run
+        (4, 5),       # gcd 1: 2 rows a KV head, refused
+        (10, 5)])     # gcd 5 is all of Q
+    def test_kernel_launches_read_from_the_trace(self, q_block, want_qb):
         """Every kernel call records its launch; a call under a scan
         counts once per iteration, and each window is its own launch."""
         rng = np.random.default_rng(3)
@@ -359,7 +389,7 @@ class TestLiveGridSteps:
         def attend(x, window):
             return paged_mixed_attention(
                 x, k, v, table, lengths, q_lens, window=window,
-                q_block=q_block, page_size=page, interpret=True)
+                q_block=q_block, interpret=True)
 
         def step(x):
             x = attend(x, 0)
@@ -368,12 +398,63 @@ class TestLiveGridSteps:
 
         launches = kernel_launches(jax.jit(step).trace(q).jaxpr)
         base = Launch(qn=qn, qb=want_qb, n_pages=pages, logical=page,
-                      window=0, pages_per_step=1, q_block=q_block)
+                      window=0)
         assert launches == {base: 1, base._replace(window=4): 3}
-        assert all(ln.rounded == rounded for ln in launches)
         assert base.grid_steps(lengths, q_lens) == live_grid_steps(
             lengths, q_lens, qn=qn, qb=want_qb, n_pages=pages,
             logical=page)
+
+
+# every LM config with a layer the kernel pages (h2o-danube and mixtral
+# are windowed on every layer, so their caches stay lanes)
+PAGED_ARCHS = ("gemma2-2b", "phi3-medium-14b", "minitron-8b",
+               "paligemma-3b", "deepseek-v2-236b")
+
+
+class TestQBlockFromShapes:
+    def test_effective_q_block(self):
+        assert effective_q_block(8, 0) == 8
+        assert effective_q_block(8, 4) == 4
+        assert effective_q_block(6, 4) == 2
+        assert effective_q_block(5, 4) == 1
+
+    @pytest.mark.parametrize("page", [16, 64])
+    @pytest.mark.parametrize("qn", [1, 5, 64])
+    @pytest.mark.parametrize("arch", PAGED_ARCHS)
+    def test_block_fits_published_widths(self, arch, qn, page):
+        """The q block the kernel sizes from the shapes at a config's
+        published widths, for decode, a 1 + 4 verify and a 64-token
+        chunk at both cells' page sizes: its rows are whole sublane
+        tiles or all of ``Q * g``, its scoped VMEM (the block's rows and
+        one page of each pool) fits the budget, and its q blocks cover Q
+        with none wholly past it."""
+        cfg = get_config(arch)
+        api = get_model(cfg)
+        slot_len = 2 * max(cfg.window, 4096)     # windowed leaves: lanes
+        leaves = jax.tree_util.tree_leaves(
+            api.init_cache_specs(cfg, 1, slot_len))
+        _, len_axes = cache_layout(api, cfg, slot_len)
+        feats = {tuple(leaf.shape[ax + 1:])
+                 for leaf, ax in zip(leaves, len_axes) if ax is not None}
+        h = cfg.num_heads
+        if cfg.kv_lora_rank:
+            kh, d, w2 = 1, cfg.kv_lora_rank, cfg.rope_head_dim
+            assert feats == {(d,), (w2,)}
+            g, wv, pools = h, d, (d, w2)
+            qb = latent_q_block(qn, 0, h, d, w2, page)
+        else:
+            kh, d, w2 = cfg.num_kv_heads, cfg.head_dim, 0
+            assert feats == {(kh, d)}
+            g, wv, pools = h // kh, d, (kh * d, kh * d)
+            qb = gqa_q_block(qn, 0, g, kh, d, wv, page)
+        rows = qb * g
+        assert rows % 8 == 0 or rows == qn * g, (qb, rows)
+        itemsize = jnp.dtype(cfg.jnp_dtype).itemsize
+        scoped = q_row_bytes(kh, d, wv, w2, page) * rows \
+            + sum(page * w * itemsize for w in pools)
+        assert scoped <= VMEM_BUDGET, (qb, scoped)
+        n_blocks = -(-qn // qb)
+        assert (n_blocks - 1) * qb < qn <= n_blocks * qb, (qb, n_blocks)
 
 # ---------------------------------------------------------------------------
 # backend seam: token-identical serving across archs / page sizes
@@ -488,8 +569,7 @@ class TestKernelBackendHotPath:
         sched.submit(prompts[0], 6)
         out1 = sched.run()
         assert len(out1) == 1
-        key = (sched._pool.paged_flags, sched._pool.page_size, 1, False,
-               0, 1)
+        key = (sched._pool.paged_flags, sched._pool.page_size, 1, False)
         c0 = engine._mixed_jits[key]._cache_size()
         sched._pool.grow_pages(9)
         sched.submit(prompts[1], 6)
