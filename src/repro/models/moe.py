@@ -19,7 +19,9 @@ steps) is dropless: the (token, expert) pairs that land on held experts
 are sorted by expert and run as grouped products
 (``jax.lax.ragged_dot``), with no capacity; rows the caller marks as
 padding are routed nowhere, so a real row's output never depends on its
-batch mates.
+batch mates.  The products of a scanned MoE layer read its experts in
+place from the stack of every scanned layer's, at the layer's group
+offset.
 
 Training (:func:`moe_apply`, every expert held) uses sort-based
 capacity-bounded dispatch (GShard/Switch style): tokens are sorted by
@@ -128,20 +130,19 @@ def _shared(p: dict, x: jax.Array) -> jax.Array:
 
 
 @jax.custom_batching.custom_vmap
-def _held_experts(x, gate, eid, real, w_gate, w_up, w_down, start):
-    """out (T, D): each real row's gate-weighted sum over its pairs that
-    land on held experts, every pair one row of a grouped product over
-    the pairs sorted by expert (``ragged_dot``); padding and other
-    chips' pairs sort past the last group and are never computed.
-    ``start`` is the first held expert's global id (a 0-d array: a
-    custom-vmap function takes only arrays)."""
-    t, k = eid.shape
+def _held_experts(x, gate, grp, w_gate, w_up, w_down):
+    """out (T, D): each row's gate-weighted sum over its pairs' expert
+    outputs.  ``grp`` (T, k) is a pair's group, the index of its expert
+    in the weights' leading axis, or that axis' length for a pair not
+    computed; every pair is one row of a grouped product over the pairs
+    sorted by group (``ragged_dot``), and pairs not computed sort past
+    the last group."""
+    t, k = grp.shape
     n = w_gate.shape[0]
-    local = eid - start
-    take = (local >= 0) & (local < n) & real[:, None]
-    grp = jnp.where(take, local, n).reshape(-1)              # (T*k,)
-    order = jnp.argsort(grp, stable=True)
-    sizes = jnp.bincount(grp, length=n + 1)[:n].astype(jnp.int32)
+    take = grp < n
+    flat = grp.reshape(-1)                                   # (T*k,)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.bincount(flat, length=n + 1)[:n].astype(jnp.int32)
     ys = _swiglu(x[order // k], w_gate, w_up, w_down,
                  functools.partial(jax.lax.ragged_dot, group_sizes=sizes))
     y = jnp.zeros_like(ys).at[order].set(ys).reshape(t, k, -1)
@@ -154,16 +155,16 @@ def _held_experts(x, gate, eid, real, w_gate, w_up, w_down, start):
 
 
 @_held_experts.def_vmap
-def _held_experts_vmap(axis_size, in_batched, x, gate, eid, real, w_gate,
-                       w_up, w_down, start):
+def _held_experts_vmap(axis_size, in_batched, x, gate, grp, w_gate, w_up,
+                       w_down):
     """Mapped lanes (the gathered backend's per-slot decode) are more
     rows of one grouped product: rows never interact."""
-    assert not any(in_batched[4:]), "held experts' weights are not mapped"
+    assert not any(in_batched[3:]), "held experts' weights are not mapped"
     lead = [a if b else jnp.broadcast_to(a, (axis_size, *a.shape))
-            for a, b in zip((x, gate, eid, real), in_batched[:4])]
+            for a, b in zip((x, gate, grp), in_batched[:3])]
     t = lead[0].shape[1]
     flat = [a.reshape(axis_size * t, *a.shape[2:]) for a in lead]
-    out = _held_experts(*flat, w_gate, w_up, w_down, start)
+    out = _held_experts(*flat, w_gate, w_up, w_down)
     return out.reshape(axis_size, t, -1), True
 
 
@@ -172,17 +173,27 @@ def moe_serve(p: dict, x: jax.Array, cfg, real=None):
     -> (out (B, S, D), pairs per held expert (n_held,) int32).
 
     Dropless: every pair a held expert receives is computed, whatever
-    the batch, and padding rows are routed nowhere."""
+    the batch, and padding rows are routed nowhere.
+
+    The expert weights may stack the held experts of R layers,
+    ``(R * n_held, ...)``, with ``p["layer"]`` this layer's index among
+    them (the scanned MoE blocks of ``transformer._run_stack``): a pair
+    then groups at ``layer * n_held`` past its held expert, so the
+    grouped products read the layer's experts in place and the other
+    layers' groups stay empty."""
     b, s, d = x.shape
+    n = cfg.n_experts_held
     x2 = x.reshape(b * s, d)
     real = (jnp.ones((b * s,), bool) if real is None
             else jnp.asarray(real, bool).reshape(b * s))
     gate, eid, _ = route(p, x2, cfg)
     local = eid - cfg.expert_start
-    pairs = jnp.sum((local[..., None] == jnp.arange(cfg.n_experts_held))
+    pairs = jnp.sum((local[..., None] == jnp.arange(n))
                     & real[:, None, None], axis=(0, 1), dtype=jnp.int32)
-    out = _held_experts(x2, gate, eid, real, p["w_gate"], p["w_up"],
-                        p["w_down"], jnp.asarray(cfg.expert_start, jnp.int32))
+    take = (local >= 0) & (local < n) & real[:, None]
+    grp = jnp.where(take, p.get("layer", 0) * n + local,
+                    p["w_gate"].shape[0])
+    out = _held_experts(x2, gate, grp, p["w_gate"], p["w_up"], p["w_down"])
     if "shared" in p:
         out = out + _shared(p, x2)
     return out.reshape(b, s, d), pairs

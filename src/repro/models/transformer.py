@@ -31,6 +31,7 @@ from repro.models.layers import (chunked_cross_entropy, cross_entropy,
 ATTN_KINDS = ("attn", "swa", "local", "global", "attn_local", "bidir")
 MOE_KINDS = ("swa_moe", "mla_moe", "moe")
 MLA_KINDS = ("mla_dense", "mla_moe")
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 
 def remat_wrap(cfg, fn):
@@ -396,15 +397,33 @@ def _run_stack(cfg, params, cache, x, *, pos=None, prefix_len: int = 0,
     if cfg.scan_repeats:
         pgs = [block_ctx(flags["scan"][f"b{i}"] if flags else None)
                for i in range(len(cfg.scan_pattern))]
+        # each MoE block's expert stacks (R, n_held, ., .) stay out of the
+        # scanned inputs: a grouped product cannot fuse a slice of its
+        # weights, so a scanned slice would copy every layer's experts each
+        # step.  The body gets them whole, as (R * n_held, ., .) (a
+        # bitcast), with the layer's index to find its own groups.
+        xs_params, held = dict(params["scan"]), {}
+        for i, kind in enumerate(cfg.scan_pattern):
+            if kind in MOE_KINDS:
+                blk = xs_params[f"b{i}"]
+                held[f"b{i}"] = {w: blk["moe"][w].reshape(
+                    -1, *blk["moe"][w].shape[2:]) for w in EXPERT_WEIGHTS}
+                xs_params[f"b{i}"] = {**blk, "moe": {
+                    k: v for k, v in blk["moe"].items()
+                    if k not in EXPERT_WEIGHTS}}
 
         def body(x, xs):
-            layer_params, layer_cache, layer_scales = xs
+            layer, layer_params, layer_cache, layer_scales = xs
             ncs, nscs, lds = {}, {}, []
             for i, kind in enumerate(cfg.scan_pattern):
                 sc_blk = (layer_scales[f"b{i}"]
                           if layer_scales is not None else None)
+                p = layer_params[f"b{i}"]
+                if f"b{i}" in held:
+                    p = {**p, "moe": {**p["moe"], **held[f"b{i}"],
+                                      "layer": layer}}
                 x, nc, nsc, aux = apply(
-                    x, kind, layer_params[f"b{i}"], layer_cache[f"b{i}"],
+                    x, kind, p, layer_cache[f"b{i}"],
                     pgs[i], norm_sc(sc_blk))
                 ncs[f"b{i}"] = nc
                 nscs[f"b{i}"] = nsc if nsc is not None else sc_blk
@@ -413,7 +432,8 @@ def _run_stack(cfg, params, cache, x, *, pos=None, prefix_len: int = 0,
             return x, (ncs, nscs, jnp.stack(lds) if lds else None)
 
         x, (scan_cache, scan_scales, scan_loads) = jax.lax.scan(
-            body, x, (params["scan"], cache["scan"],
+            body, x, (jnp.arange(cfg.scan_repeats) if held else None,
+                      xs_params, cache["scan"],
                       scales["scan"] if scales is not None else None))
         if scan_loads is not None:
             # (repeats, blocks of the pattern, n_held) -> layer order

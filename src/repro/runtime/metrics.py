@@ -144,6 +144,9 @@ class ServeMetrics:
     #                                    of its busiest held expert, summed
     expert_busiest_share_sum: float = 0.0  # per step, its busiest pairs
     #                                    over its pairs, summed over steps
+    expert_groups_live: int = 0        # per step and MoE block, the held
+    #                                    experts given a pair (whose weights
+    #                                    its grouped products read), summed
     attn_grid_steps: int = 0           # paged-attention kernel grid steps
     #                                    walked, every paged layer call,
     #                                    while the engine keeps telemetry
@@ -300,6 +303,7 @@ class ServeMetrics:
         self.expert_block_steps += load.shape[0]
         self.expert_pairs += pairs
         self.expert_busiest_pairs += busiest
+        self.expert_groups_live += int((load > 0).sum())
         if pairs:
             self.expert_busiest_share_sum += busiest / pairs
         return pairs
@@ -505,6 +509,8 @@ class ServeMetrics:
                  "token-expert pairs the held experts computed"),
                 ("expert_busiest_pairs",
                  "pairs of each MoE block's busiest held expert"),
+                ("expert_groups_live",
+                 "held experts given a pair, per step and MoE block"),
                 ("attn_grid_steps",
                  "paged-attention kernel grid steps walked"),
                 ("attn_grid_steps_live",

@@ -9,8 +9,9 @@ gemma2-2b's published widths for decode (Q = 1), a 128-token prefill
 chunk and a 1 + 4 speculative verify, and at phi3-medium's for decode
 and a 64-token chunk, each with the KV codec off and on; at both
 widths for a verify under a q block pinned for the chunk; one whole mixed
-step of the full 26-layer gemma2-2b; and the shared-latent kernel at
-DeepSeek-V2's widths.  Nothing runs; results
+step of the full 26-layer gemma2-2b; the shared-latent kernel at
+DeepSeek-V2's widths; and a scan of DeepSeek-V2 MoE layers, whose
+grouped products must read each layer's experts in place.  Nothing runs; results
 and times need the chip (``chip_smoke.py``).
 
 The topology is described inside a module fixture, never at import: only
@@ -20,6 +21,7 @@ imports this file.
 
 import functools
 import os
+import re
 
 import pytest
 
@@ -215,3 +217,39 @@ def test_latent_kernel_compiles_at_deepseek_v2_widths(one_chip, qn):
                                      scale=0.1)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_scanned_moe_layers_read_experts_in_place(one_chip):
+    """Two scanned DeepSeek-V2 MoE layers at the published widths (MLA,
+    20 held experts of 5120 x 1536 in bf16, top-6 of 160) for a 128-slot
+    decode tick, 768 pairs: the grouped products read each layer's
+    experts from the whole stack, so no instruction makes a copy of a
+    layer's expert stack, and the step's temporaries stay under one."""
+    from repro.models import transformer
+    from repro.models.api import get_model
+
+    cfg = get_config("deepseek-v2-236b").scaled(
+        num_layers=2, prefix_kinds=(), scan_repeats=2, experts_held=20,
+        expert_start=0)
+    api = get_model(cfg)
+    slots = 128
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(
+            lambda x: _sds(x.shape, x.dtype, one_chip), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda: api.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = shaped(transformer.init_cache_specs(cfg, slots, 64))
+
+    def fn(p, c, x):
+        out, _, _, load = transformer._run_stack(cfg, p, c, x, pos=0)
+        return out, load
+    compiled = jax.jit(fn).lower(
+        params, cache, _sds((slots, 1, cfg.d_model), jnp.bfloat16,
+                            one_chip)).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" in text
+    one_stack = 20 * 5120 * 1536 * 2
+    assert not re.search(r"= bf16\[20,5120,1536\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < one_stack

@@ -20,6 +20,7 @@ from repro.configs.base import get_config
 from repro.kernels.paged_attention import (VMEM_BUDGET, latent_q_block,
                                            q_row_bytes)
 from repro.models import moe as moe_mod
+from repro.models import transformer
 from repro.models.api import get_model
 from repro.models.attention import mla_softmax_scale
 from repro.models.layers import rope_tables, yarn_frequencies, yarn_mscale
@@ -267,6 +268,82 @@ class TestDropless:
         assert int(np.asarray(pairs).sum()) == \
             int(held[np.asarray(real)].sum())
 
+    @pytest.mark.parametrize("case", ["share", "padding", "idle_expert",
+                                      "lanes", "mixtral"])
+    def test_scan_reads_each_layers_experts_in_place(self, case):
+        """Three scanned MoE layers whose grouped products read the
+        layer's experts from the whole stack give, bit for bit, the
+        output and per-layer loads of a loop that applies each layer with
+        its own sliced weights: with other chips' experts routed to
+        (``expert_start`` 4), padding rows, a held expert idle in a layer,
+        the gathered backend's vmapped lanes, and a Mixtral block holding
+        every expert.
+
+        Both run op by op, and each expert's column of each projection
+        has one nonzero of +-1: a product then reads one weight, and its
+        bits do not hang on how the CPU's grouped product (a dense
+        contraction over every group) orders its sums, while an expert of
+        the wrong layer or slot reads other weights."""
+        if case == "mixtral":
+            cfg = get_config("mixtral-8x22b").scaled(
+                num_layers=3, scan_repeats=3, d_model=64, num_heads=4,
+                num_kv_heads=2, head_dim=16, d_ff=64, moe_d_ff=64,
+                num_experts=4, top_k=2, window=16, vocab_size=256,
+                dtype="float32")
+            assert not cfg.experts_held
+        else:
+            cfg = small_cfg(num_layers=3, prefix_kinds=(), scan_repeats=3,
+                            dtype="float32")
+        params = get_model(cfg).init_params(cfg, jax.random.PRNGKey(0))
+        rng = np.random.default_rng(7)
+        moe = params["scan"]["b0"]["moe"]
+        for w in transformer.EXPERT_WEIGHTS:
+            *lead, rows, cols = moe[w].shape
+            one = np.zeros((*lead, rows, cols), np.float32)
+            at = np.indices((*lead, cols))
+            one[(*at[:-1], rng.integers(0, rows, (*lead, cols)), at[-1])] = \
+                rng.choice([-1.0, 1.0], (*lead, cols))
+            moe[w] = jnp.asarray(one)
+        lanes, b, s = {"lanes": (3, 1, 4), "idle_expert": (None, 1, 3)}.get(
+            case, (None, 2, 4))
+        q_lens = jnp.array([4, 1]) if case == "padding" else None
+        shape = (b, s, cfg.d_model) if lanes is None else \
+            (lanes, b, s, cfg.d_model)
+        x = jax.random.normal(jax.random.PRNGKey(5), shape)
+        cache = get_model(cfg).init_cache(cfg, b, 8)
+
+        def in_place(x):
+            out, _, _, load = transformer._run_stack(cfg, params, cache, x,
+                                                     pos=0, q_lens=q_lens)
+            return out, load
+
+        def by_layer(x):
+            loads = []
+            for r in range(cfg.scan_repeats):
+                p, c = jax.tree_util.tree_map(
+                    lambda a: a[r], (params["scan"], cache["scan"]))
+                x, _, load = transformer.block_apply(
+                    cfg.scan_pattern[0], cfg, p["b0"], x, cache=c["b0"],
+                    pos=0, q_lens=q_lens, dropless=True)
+                loads.append(load)
+            return x, jnp.stack(loads)
+
+        if lanes is not None:
+            in_place, by_layer = jax.vmap(in_place), jax.vmap(by_layer)
+        with jax.disable_jit():
+            got, want = in_place(x), by_layer(x)
+        np.testing.assert_array_equal(np.asarray(got[0]),
+                                      np.asarray(want[0]))
+        np.testing.assert_array_equal(np.asarray(got[1]),
+                                      np.asarray(want[1]))
+        load = np.asarray(got[1]).reshape(-1, cfg.scan_repeats,
+                                          cfg.n_experts_held).sum(0)
+        assert (load.sum(-1) > 0).all()
+        if case == "idle_expert":      # idle in a layer, busy in another
+            assert ((load == 0) & (load > 0).any(0)).any()
+        if case == "padding":
+            assert load.sum() < cfg.scan_repeats * b * s * cfg.top_k
+
 
 # ---------------------------------------------------------------------------
 # the shared-latent kernel's q block
@@ -378,11 +455,19 @@ def test_expert_counters_span_and_prometheus():
     sched = Scheduler(engine, batch_size=2, slot_len=64, prefill_chunk=4,
                       kv_page_size=8, attn_backend="pallas_paged",
                       buckets=(64,))
+    fed = []
+    record = engine.metrics.record_expert_load
+    engine.metrics.record_expert_load = \
+        lambda load: fed.append(np.asarray(load)) or record(load)
     for p, g in reqs:
         sched.submit(p, g)
     sched.run()
     m = engine.metrics
     steps = tel.phases["mixed_step"].n
+    assert len(fed) == steps
+    assert m.expert_groups_live == sum(int((f > 0).sum()) for f in fed)
+    assert 0 < m.expert_groups_live < steps * cfg.scan_repeats * \
+        cfg.n_experts_held
     assert m.expert_steps == steps
     assert m.expert_block_steps == steps * cfg.scan_repeats
     assert 0 < m.expert_busiest_pairs <= m.expert_pairs
@@ -394,3 +479,4 @@ def test_expert_counters_span_and_prometheus():
     prom = engine.render_prom()
     assert f"repro_expert_pairs_total {m.expert_pairs}" in prom
     assert f"repro_expert_steps_total {steps}" in prom
+    assert f"repro_expert_groups_live_total {m.expert_groups_live}" in prom
